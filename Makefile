@@ -39,7 +39,7 @@ ci: ci-lint docs
 	@echo "ci: all passes green"
 
 ci-lint:
-	python -m compileall -q petastorm_tpu tests tools examples bench.py __graft_entry__.py
+	python -m compileall -q petastorm_tpu tests tools examples bench.py chip_smoke.py __graft_entry__.py
 	python tools/check_monotonic.py
 	python tools/check_backoff.py
 	python tools/check_knobs.py

@@ -96,6 +96,8 @@ def main():
     parser.add_argument("--steps", type=int, default=30)
     parser.add_argument("--per-device-batch", type=int, default=8)
     args = parser.parse_args()
+    from petastorm_tpu.jax.compile_cache import ensure_compile_cache
+    ensure_compile_cache()
     import os
     if not os.path.exists(args.url.replace("file://", "") + "/_common_metadata"):
         print("writing synthetic imagenet store...")

@@ -110,6 +110,8 @@ def main():
     parser.add_argument("--steps", type=int, default=40)
     parser.add_argument("--vocab", type=int, default=256)
     args = parser.parse_args()
+    from petastorm_tpu.jax.compile_cache import ensure_compile_cache
+    ensure_compile_cache()
     import os
     if not os.path.exists(args.url.replace("file://", "") + "/_common_metadata"):
         print("writing token stream store...")

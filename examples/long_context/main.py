@@ -164,6 +164,8 @@ def main():
                         choices=["ring", "ring-chunked", "ring-flash",
                                  "ulysses", "ulysses-flash"])
     args = parser.parse_args()
+    from petastorm_tpu.jax.compile_cache import ensure_compile_cache
+    ensure_compile_cache()
     import os
     if not os.path.exists(args.url.replace("file://", "") + "/_common_metadata"):
         write_token_stream(args.url, args.chunks, args.vocab)

@@ -67,6 +67,8 @@ def main():
     parser.add_argument("--rows", type=int, default=10000)
     parser.add_argument("--epochs", type=int, default=3)
     args = parser.parse_args()
+    from petastorm_tpu.jax.compile_cache import ensure_compile_cache
+    ensure_compile_cache()
 
     images, labels = synthetic_mnist(args.rows)
     write_dataset(args.url, images, labels)

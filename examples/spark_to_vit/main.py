@@ -95,6 +95,8 @@ def main():
     parser.add_argument("--steps", type=int, default=40)
     parser.add_argument("--batch-size", type=int, default=64)
     args = parser.parse_args()
+    from petastorm_tpu.jax.compile_cache import ensure_compile_cache
+    ensure_compile_cache()
     import os
     classes, image = 10, 16
     if not os.path.exists(args.url.replace("file://", "") + "/_common_metadata"):

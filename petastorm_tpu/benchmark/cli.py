@@ -64,6 +64,10 @@ def main(argv=None):
         return subprocess.call(
             [sys.executable, "-m", "petastorm_tpu.benchmark.cli", *argv])
 
+    if args.read_method == "jax":
+        # The jax read path jits its calibrated device step.
+        from petastorm_tpu.jax.compile_cache import ensure_compile_cache
+        ensure_compile_cache()
     from petastorm_tpu.benchmark.throughput import reader_throughput
     result = reader_throughput(
         args.dataset_url, field_regex=args.field_regex,

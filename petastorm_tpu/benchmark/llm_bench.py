@@ -5,7 +5,7 @@ This is the end-to-end counterpart to :mod:`.imagenet_bench` for the
 sequence path: the reference's only sequence feature is NGram windowed
 readout (``/root/reference/petastorm/ngram.py:225`` ``form_ngram``), and
 the BASELINE LLM config feeds token windows to a decoder. Here the whole
-chain runs on real hardware: rows decode in reader workers, NGram
+chain runs on the local device(s): rows decode in reader workers, NGram
 assembles timestamp-ordered windows per row group, the loader stacks
 windows into a dense ``(batch, window)`` int32 array staged into HBM,
 and a real AdamW llama step consumes it. Metrics mirror
@@ -19,6 +19,8 @@ built for: when the single-host reader cannot feed the step rate,
 the stall comparison echo=1 vs echo>1 is the feature's measurement.
 """
 from __future__ import annotations
+
+import time
 
 import numpy as np
 
@@ -71,13 +73,17 @@ def run_llm_bench(url: str, steps: int = 20, batch_size: int = 8,
     carries the loader's ``mesh_report`` (per-host stall/skew/reshard).
     Requires ``dense=True`` (windows need the fixed-shape layout) and
     ``batch_size`` divisible by the data-axis size.
+
+    ``flash=True`` asks for the Pallas kernel: a ``window`` its tiles
+    cannot divide raises here, before anything is traced, instead of
+    quietly training through dense attention.
     """
     import jax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from petastorm_tpu.benchmark.imagenet_bench import (_flops_of_compiled,
-                                                        pipelined_window,
-                                                        utilization_metrics)
+    from petastorm_tpu.benchmark.imagenet_bench import (pipelined_window,
+                                                        recording_layouts,
+                                                        window_result)
     from petastorm_tpu.jax import DataLoader
     from petastorm_tpu.models import llama
     from petastorm_tpu.ngram import NGram
@@ -85,6 +91,20 @@ def run_llm_bench(url: str, steps: int = 20, batch_size: int = 8,
 
     devices = jax.devices()
     mesh = Mesh(np.array(devices).reshape(len(devices)), ("data",))
+    # flash=True swaps the Pallas flash kernel in for XLA dense attention
+    # (the win regime is window >= 8k — the long-context pipeline config).
+    # GSPMD cannot partition a Mosaic call by itself ("wrap the call in a
+    # shard_map"): each device runs the kernel on its own rows of the batch.
+    attn_fn = None
+    if flash:
+        from petastorm_tpu.ops.flash_attn import (make_flash_attention,
+                                                  require_flash_tiles)
+        require_flash_tiles(window, window, causal=True)
+        rows = P("data")
+        attn_fn = jax.shard_map(make_flash_attention(causal=True), mesh=mesh,
+                                in_specs=(rows, rows, rows), out_specs=rows,
+                                check_vma=False)
+        attn_fn.supports_gqa = True
     kw = dict(vocab=32000, dim=1024, n_layers=8, n_heads=8, n_kv_heads=4,
               hidden=2816)
     kw.update(model_kwargs or {})
@@ -92,12 +112,6 @@ def run_llm_bench(url: str, steps: int = 20, batch_size: int = 8,
 
     params = jax.device_put(llama.init_params(jax.random.PRNGKey(0), cfg),
                             NamedSharding(mesh, P()))
-    # flash=True swaps the Pallas flash kernel in for XLA dense attention
-    # (the win regime is window >= 8k — the long-context pipeline config).
-    attn_fn = None
-    if flash:
-        from petastorm_tpu.ops.flash_attn import make_flash_attention
-        attn_fn = make_flash_attention(causal=True)
     init_opt, raw_step = llama.make_train_step(cfg, shift="roll",
                                                attn_fn=attn_fn,
                                                xent_chunk=xent_chunk,
@@ -139,12 +153,16 @@ def run_llm_bench(url: str, steps: int = 20, batch_size: int = 8,
             reader.stop()
             reader.join()
             raise
+    layouts = set()
     with loader:  # closes the underlying reader(s) on exit
-        it = iter(loader)
-        tokens = next(it)["token"]
-        assert tokens.shape == (batch_size, window), tokens.shape
+        it = (b["token"] for b in recording_layouts(iter(loader), layouts))
+        tokens = next(it)
+        if tokens.shape != (batch_size, window):
+            raise ValueError(f"staged tokens are {tokens.shape}, expected "
+                             f"{(batch_size, window)}")
+        t0 = time.perf_counter()
         step = step.lower(params, opt, tokens).compile()
-        flops_per_step = _flops_of_compiled(step)
+        compile_s = time.perf_counter() - t0
         params, opt, loss = step(params, opt, tokens)
 
         def run_step(toks):
@@ -152,17 +170,15 @@ def run_llm_bench(url: str, steps: int = 20, batch_size: int = 8,
             params, opt, loss = step(params, opt, toks)
             return loss
 
-        loss_first, loss_last, wait_s, total_wall, resident_s = (
-            pipelined_window(run_step, lambda: next(it)["token"], steps,
-                             resident_steps, warm_loss=loss))
+        window_obs = pipelined_window(run_step, lambda: next(it), steps,
+                                      resident_steps, warm_loss=loss)
+        result = window_result(window_obs, steps, devices, step, compile_s,
+                               layouts)
         mesh_report = loader.mesh_report() if mesh_ingest else None
 
     tokens_per_step = batch_size * window
-    step_time_s = (total_wall - wait_s) / steps
-    result = {
-        "tokens_per_sec": tokens_per_step * steps / total_wall,
-        "input_stall_pct": 100.0 * wait_s / total_wall,
-        "step_time_ms": 1000.0 * step_time_s,
+    result.update({
+        "tokens_per_sec": tokens_per_step * steps / window_obs["total_wall_s"],
         "tokens_per_step": tokens_per_step,
         "echo": echo,
         "dense": dense,
@@ -170,13 +186,9 @@ def run_llm_bench(url: str, steps: int = 20, batch_size: int = 8,
         "xent_chunk": xent_chunk,
         "remat_layers": remat_layers,
         "window": window,
-        "devices": len(devices),
-        "loss_first": loss_first,
-        "loss_last": loss_last,
-        "device_kind": devices[0].device_kind,
-    }
+    })
+    resident_s = window_obs["resident_s"]
     if resident_s is not None:
-        result["step_time_ms_resident"] = 1000.0 * resident_s
         result["tokens_per_sec_resident"] = tokens_per_step / resident_s
         result["tokens_per_sec_per_chip_resident"] = (
             tokens_per_step / resident_s / len(devices))
@@ -184,28 +196,27 @@ def run_llm_bench(url: str, steps: int = 20, batch_size: int = 8,
         result["mesh_ingest"] = True
         result["mesh_hosts"] = mesh_report["hosts"]
         result["mesh_report"] = mesh_report
-    utilization_metrics(result, flops_per_step, step_time_s, resident_s,
-                        devices[0].device_kind)
     return result
 
 
 def _ctx_label(window: int) -> str:
-    """32768 -> "32k" (the BENCH_TPU_EVIDENCE key convention)."""
+    """32768 -> "32k" (the ``ctx<N>k_`` key prefix of :func:`main`)."""
     return f"{window // 1024}k" if window % 1024 == 0 else str(window)
 
 
 def main(argv=None) -> int:
-    """Long-context llama phase CLI — the ctx32k/ctx64k capture, now with
-    ``--mesh`` scaling ingestion from one chip to the whole slice::
+    """Long-context llama phase CLI; ``--mesh`` scales ingestion from one
+    device to every device of the host::
 
         python -m petastorm_tpu.benchmark.llm_bench --ctx 32768 --mesh \
             --flash --xent-chunk 2048 --out MULTICHIP_r06.json
 
-    ``--out`` writes MULTICHIP_r0*.json-shape evidence: the driver wrapper
-    keys (``n_devices``/``rc``/``ok``/``tail``) plus ``parsed`` carrying
-    ``ctx<N>k_``-prefixed metrics — the same keys bench.py's
-    ``tpu_evidence`` block and ``tools/bench_compare.py --prefix
-    MULTICHIP`` consume.
+    ``--out`` writes a MULTICHIP_r0*.json-shape record: the wrapper keys
+    (``n_devices``/``rc``/``ok``/``tail``), the ``platform`` and
+    ``device_kind`` it ran on, and ``parsed`` carrying ``ctx<N>k_``-prefixed
+    metrics — the keys ``tools/bench_compare.py --prefix MULTICHIP``
+    consumes. It runs on whatever backend JAX finds and says which;
+    ``chip_smoke.py`` is the entry that refuses a CPU.
     """
     import argparse
     import json
@@ -243,11 +254,13 @@ def main(argv=None) -> int:
                         default=os.environ.get("BENCH_DATA_DIR",
                                                "/tmp/pt_bench"))
     parser.add_argument("--out", default=None,
-                        help="write MULTICHIP-shape evidence JSON here")
+                        help="write a MULTICHIP-shape record JSON here")
     args = parser.parse_args(argv)
 
     import jax
 
+    from petastorm_tpu.jax.compile_cache import ensure_compile_cache
+    ensure_compile_cache()
     n_devices = jax.device_count()
     batch = args.batch_size
     if batch is None:
@@ -273,7 +286,7 @@ def main(argv=None) -> int:
                            mesh_ingest=args.mesh, mesh_hosts=args.hosts)
 
     parsed = {f"ctx{label}_{k}": v for k, v in result.items()
-              if not isinstance(v, dict)}
+              if not isinstance(v, (dict, list))}
     parsed[f"ctx{label}_mesh"] = bool(args.mesh)
     if "mesh_report" in result:
         rep = result["mesh_report"]
@@ -284,7 +297,7 @@ def main(argv=None) -> int:
             (h["input_stall_pct"] for h in rep["per_host"].values()),
             default=0.0)
     tail = (f"llm ctx{label} {'mesh' if args.mesh else 'single-reader'} "
-            f"ingestion on {n_devices} device(s): "
+            f"ingestion on {n_devices} {result['platform']} device(s): "
             f"{result['tokens_per_sec']:.1f} tok/s, input stall "
             f"{result['input_stall_pct']:.2f}%, step "
             f"{result['step_time_ms']:.1f} ms, loss "
@@ -292,12 +305,13 @@ def main(argv=None) -> int:
     print(tail)
     print(json.dumps(parsed))
     if args.out:
-        evidence = {"n_devices": n_devices, "rc": 0, "ok": True,
-                    "device_kind": result.get("device_kind"),
-                    "parsed": parsed, "tail": tail + "\n"}
+        record = {"n_devices": n_devices, "rc": 0, "ok": True,
+                  "platform": result["platform"],
+                  "device_kind": result["device_kind"],
+                  "parsed": parsed, "tail": tail + "\n"}
         with open(args.out, "w") as f:
-            json.dump(evidence, f, indent=1)
-        print(f"evidence -> {args.out}", file=sys.stderr)
+            json.dump(record, f, indent=1)
+        print(f"record -> {args.out}", file=sys.stderr)
     return 0
 
 

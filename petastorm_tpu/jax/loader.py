@@ -306,14 +306,12 @@ class LoaderBase:
         host->host memcpy disappears (docs/zero_copy.md). Resolved once:
         the target backend cannot change mid-loader."""
         if self._cpu_dlpack is None:
-            try:
-                import jax
-                platform = (self._device.platform if self._device is not None
-                            else jax.default_backend())
-                self._cpu_dlpack = (platform == "cpu"
-                                    and self._sharding is None)
-            except Exception:  # noqa: BLE001 - backend probe failed
-                self._cpu_dlpack = False
+            import jax
+            # A backend that fails to come up raises here, on the staging
+            # path, rather than reading as "not a CPU".
+            platform = (self._device.platform if self._device is not None
+                        else jax.default_backend())
+            self._cpu_dlpack = platform == "cpu" and self._sharding is None
         return self._cpu_dlpack
 
     #: Columns below this size stay on the ONE batched ``device_put`` call:
@@ -325,9 +323,9 @@ class LoaderBase:
 
     @staticmethod
     def _dlpack_adoptable(value: np.ndarray) -> bool:
-        """C-contiguous, writeable (numpy refuses to export read-only
-        buffers pre-DLPack-1.0), natively-typed, and big enough that
-        skipping the memcpy beats the per-array dispatch.
+        """C-contiguous, writeable (a read-only buffer is a zero-copy Arrow
+        view — see the ownership invariant below), natively-typed, and big
+        enough that skipping the memcpy beats the per-array dispatch.
 
         Ownership invariant (why adoption is safe): every column reaching
         ``_stage`` is a per-batch allocation — a shuffle-buffer
@@ -367,8 +365,10 @@ class LoaderBase:
                     self._commit_cache.clear()
                 self._commit_cache[sig] = compiled
             return dict(compiled(cols))
-        except Exception:  # noqa: BLE001 - odd leaf (pre-committed array,
-            # unhashable aval): the per-leaf walk still stages correctly
+        except (TypeError, ValueError):
+            # Odd leaf (pre-committed array, unhashable aval): the per-leaf
+            # walk still stages correctly. A runtime failure on the device
+            # (XlaRuntimeError) is not this case and surfaces.
             return dict(jax.device_put(cols))
 
     def _stage(self, host_batch: Dict[str, np.ndarray]) -> dict:

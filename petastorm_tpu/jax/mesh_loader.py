@@ -1477,13 +1477,12 @@ class MeshDataLoader(LoaderBase):
         None on accelerator backends where device_put is the real
         host->HBM copy."""
         if self._adopt_device is None:
-            try:
-                import jax
-                self._adopt_device = (jax.local_devices()[0]
-                                      if jax.default_backend() == "cpu"
-                                      else False)
-            except Exception:  # noqa: BLE001 - backend probe failed
-                self._adopt_device = False
+            import jax
+            # A backend that fails to come up raises here, on the staging
+            # path, rather than reading as "not a CPU".
+            self._adopt_device = (jax.local_devices()[0]
+                                  if jax.default_backend() == "cpu"
+                                  else False)
         return self._adopt_device or None
 
     def _make_global(self, value: np.ndarray):

@@ -99,11 +99,10 @@ def apply(params: Params, images, train: bool = False, compute_dtype=jnp.bfloat1
 
     ``remat=True`` wraps each bottleneck in :func:`jax.checkpoint` so the
     backward pass recomputes block activations instead of storing them —
-    the standard FLOPs-for-HBM trade. Measured via XLA memory analysis,
-    the train step's temp memory scales ~83 MiB/image without remat
-    (21 GiB at batch 256), which overflows a 16 GiB-class chip and forces
-    involuntary spilling — the batch-256 throughput cliff in
-    docs/performance.md; remat keeps large batches inside HBM.
+    the standard FLOPs-for-HBM trade: the train step's temp memory scales
+    with the batch (every benchmark result carries XLA's own account as
+    ``compiled_hbm_bytes``), and remat keeps per-device batches that would
+    overflow a 16 GiB-class chip inside HBM.
     """
     block_fn = jax.checkpoint(_bottleneck, static_argnums=(2, 3)) if remat \
         else _bottleneck

@@ -23,10 +23,12 @@ HBM; this kernel never does. Design (flash-attention-2 style, TPU-first):
   dK/dV — with grouped-query head gradients summed inside the kernel by
   walking every (group head, q block) pair over one K/V tile. No
   O(seq^2) or O(block*seq) tensors touch HBM in training either;
-* off-TPU the kernel runs in Pallas interpret mode (tests), and shapes
-  that don't tile cleanly (seq not divisible by an 8-aligned block, or
-  ``causal`` with ``sq != sk``) fall back to the dense path —
-  numerically identical either way.
+* on the ``cpu`` backend the kernel runs in Pallas interpret mode
+  (tests); every other backend compiles it or raises. Shapes that don't
+  tile cleanly (seq not divisible by an 8-aligned block, or ``causal``
+  with ``sq != sk``) take the dense path in :func:`flash_attention` —
+  numerically identical — and are an error in the ``attn_fn`` built by
+  :func:`make_flash_attention`, whose caller asked for the kernel.
 
 Used as a drop-in ``attn_fn`` for :mod:`petastorm_tpu.models.llama` via
 :func:`make_flash_attention` (``supports_gqa`` — K/V stay at kv-head
@@ -59,11 +61,54 @@ _DEFAULT_BLOCK_K = 1024
 
 def _pick_block(requested: int, seq: int) -> int:
     """Clamp ``requested`` to ``seq``; if it doesn't divide, retry the
-    128 granule before the caller's dense-fallback guard rejects it."""
+    128 granule before :func:`_tiles` rejects it."""
     blk = min(requested, seq)
     if seq % blk and not seq % _DEFAULT_BLOCK:
         blk = _DEFAULT_BLOCK
     return blk
+
+
+def _tiles(sq: int, sk: int, causal: bool, block_q: int, block_k: int):
+    """``(block_q, block_k)`` the kernels launch with, or None when the
+    shape cannot tile onto the hardware: seq not divisible by the
+    (clamped) block, a clamped block not a multiple of 8 (Mosaic's
+    second-minor tile granule — catches e.g. seq=100), or ``causal`` with
+    ``sq != sk`` (the mask diagonal would straddle blocks)."""
+    block_q = _pick_block(block_q, sq)
+    block_k = _pick_block(block_k, sk)
+    if (sq % block_q or sk % block_k or block_q % 8 or block_k % 8
+            or (causal and sq != sk)):
+        return None
+    return block_q, block_k
+
+
+def require_flash_tiles(sq: int, sk: int, causal: bool,
+                        block_q: int = _DEFAULT_BLOCK_Q,
+                        block_k: int = _DEFAULT_BLOCK_K):
+    """:func:`_tiles` for a caller that asked for the kernel: an untileable
+    shape raises instead of quietly taking the O(seq^2) dense route (at 32k
+    that is a 34 GB score tensor)."""
+    tiles = _tiles(sq, sk, causal, block_q, block_k)
+    if tiles is None:
+        raise ValueError(
+            f"flash attention cannot tile sq={sq}, sk={sk}, causal={causal} "
+            f"with blocks ({block_q}, {block_k}): seq must divide into "
+            f"8-aligned blocks and causal needs sq == sk")
+    return tiles
+
+
+def _resolve_interpret(interpret) -> bool:
+    """Pallas interpret mode exists for the ``cpu`` backend only: ``None``
+    selects it there and nowhere else, and asking for it on any other
+    backend is an error — an accelerator compiles the kernel or raises."""
+    on_cpu = jax.default_backend() == "cpu"
+    if interpret is None:
+        return on_cpu
+    if interpret and not on_cpu:
+        raise ValueError(
+            f"interpret=True on the {jax.default_backend()!r} backend: the "
+            f"Pallas interpreter is for cpu only")
+    return bool(interpret)
 
 
 def _causal_live(causal: bool, q_off, k_off, block_q: int):
@@ -214,6 +259,7 @@ def _flash_launch(q, k, v, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q, 1), jnp.float32),      # normalizer l
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
       v.transpose(0, 2, 1, 3))
 
@@ -382,6 +428,7 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, block_q: int,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qT, kT, vT, doT, lseT, ddT)
 
     n_q = sq // block_q
@@ -407,6 +454,7 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, block_q: int,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(kT, vT, qT, doT, lseT, ddT)
     return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
             dv.transpose(0, 2, 1, 3))
@@ -467,14 +515,10 @@ def flash_attention_stats(q, k, v, causal: bool = False,
     sk, kv_h = k.shape[1], k.shape[2]
     if h % kv_h:
         raise ValueError(f"heads ({h}) must be a multiple of kv_heads ({kv_h})")
-    block_q = _pick_block(block_q, sq)
-    block_k = _pick_block(block_k, sk)
-    if (sq % block_q or sk % block_k or block_q % 8 or block_k % 8
-            or (causal and sq != sk)):
-        return _dense_stats(q, k, v, causal, block_q)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return _flash_stats_vjp(causal, block_q, block_k, bool(interpret),
+    tiles = _tiles(sq, sk, causal, block_q, block_k)
+    if tiles is None:
+        return _dense_stats(q, k, v, causal, _pick_block(block_q, sq))
+    return _flash_stats_vjp(causal, *tiles, _resolve_interpret(interpret),
                             q, k, v)
 
 
@@ -515,31 +559,28 @@ def flash_attention(q, k, v, causal: bool = False,
     ``(b, sq, heads, d)``, grouped-query native.
 
     Falls back to the dense path when the shape can't tile onto the
-    hardware: seq not divisible by the (clamped) block, a clamped block
-    not a multiple of 8 (Mosaic's second-minor tile granule — catches
-    e.g. seq=100), or ``causal`` with ``sq != sk`` (the mask diagonal
-    would straddle blocks). ``interpret=None`` auto-enables the Pallas
-    interpreter off-TPU so tests run on CPU.
+    hardware (:func:`_tiles`). ``interpret=None`` selects the Pallas
+    interpreter on the ``cpu`` backend only, so tests run there
+    (:func:`_resolve_interpret`).
     """
     b, sq, h, d = q.shape
     sk, kv_h = k.shape[1], k.shape[2]
     if h % kv_h:
         raise ValueError(f"heads ({h}) must be a multiple of kv_heads ({kv_h})")
-    block_q = _pick_block(block_q, sq)
-    block_k = _pick_block(block_k, sk)
-    if (sq % block_q or sk % block_k or block_q % 8 or block_k % 8
-            or (causal and sq != sk)):
+    tiles = _tiles(sq, sk, causal, block_q, block_k)
+    if tiles is None:
         return _dense(q, k, v, causal)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return _flash_vjp(causal, block_q, block_k, bool(interpret), q, k, v)
+    return _flash_vjp(causal, *tiles, _resolve_interpret(interpret), q, k, v)
 
 
 def make_flash_attention(causal: bool = True, block_q: int = _DEFAULT_BLOCK_Q,
                          block_k: int = _DEFAULT_BLOCK_K, interpret=None):
     """An ``attn_fn`` for :func:`petastorm_tpu.models.llama.apply`
-    (``supports_gqa``: K/V arrive at native kv-head width)."""
+    (``supports_gqa``: K/V arrive at native kv-head width). Its caller
+    asked for the kernel, so a shape the tiles cannot divide raises
+    (:func:`require_flash_tiles`) instead of taking the dense route."""
     def attn(q, k, v):
+        require_flash_tiles(q.shape[1], k.shape[1], causal, block_q, block_k)
         return flash_attention(q, k, v, causal=causal, block_q=block_q,
                                block_k=block_k, interpret=interpret)
     attn.supports_gqa = True

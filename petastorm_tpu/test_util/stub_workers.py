@@ -54,6 +54,15 @@ class WorkerIdWorker(WorkerBase):
         self.publish_func((self.worker_id, value))
 
 
+class EnvReportWorker(WorkerBase):
+    """Publishes the worker process's value of ``os.environ[args['name']]``
+    (tests what a spawned worker inherits and what the pool pins)."""
+
+    def process(self, value):
+        import os
+        self.publish_func(os.environ.get(self.args["name"], "unset"))
+
+
 class BlobWorker(WorkerBase):
     """Publishes ``args['size']`` bytes per item (fills transport buffers —
     used to test shutdown while producers are blocked on backpressure)."""

@@ -2,6 +2,7 @@
 reference pyarrow_helpers/tests/test_batch_buffer.py, benchmark/cli.py,
 tools/spark_session_cli.py)."""
 import json
+import os
 import subprocess
 import sys
 
@@ -122,7 +123,9 @@ def test_throughput_cli_spawn_new_process(synthetic_dataset):
          "--json", "--spawn-new-process"],
         capture_output=True, text=True, timeout=240,
         env={"PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu",
-             "HOME": "/root", "PYTHONPATH": "/root/repo"})
+             "HOME": "/root",
+             "PYTHONPATH": os.path.dirname(os.path.dirname(
+                 os.path.abspath(__file__)))})
     assert proc.returncode == 0, proc.stderr[-2000:]
     out = json.loads(proc.stdout.strip().splitlines()[-1])
     assert out["samples_per_second"] > 0

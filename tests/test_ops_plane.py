@@ -714,7 +714,14 @@ class TestReaderWiring:
                 for _ in loader:
                     pass
                 assert loader.telemetry is r.telemetry
+                # The one-batch epoch can finish inside the sampler's first
+                # 50 ms interval: wait (bounded) for it to close a window
+                # instead of racing it.
+                deadline = time.monotonic() + 10.0
                 tl = loader.timeline_report()
+                while not tl["windows"] and time.monotonic() < deadline:
+                    time.sleep(0.05)
+                    tl = loader.timeline_report()
         assert tl["windows"]
 
     def test_exporter_atexit_flush_on_abandoned_reader(self, tmp_path):

@@ -1,5 +1,7 @@
 """Parallelism tests on the virtual 8-device CPU mesh: ring attention
 exactness, mesh helpers, TP-sharded model equivalence, driver dryrun."""
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -164,11 +166,15 @@ def test_llama_tp_sharded_matches_unsharded():
     assert loss_tp == pytest.approx(loss_plain, rel=2e-2)
 
 
+_GRAFT_ENTRY = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "__graft_entry__.py")
+
+
 @pytest.mark.slow
 def test_graft_entry_dryrun_multichip():
     import importlib.util
     spec = importlib.util.spec_from_file_location(
-        "graft_entry", "/root/repo/__graft_entry__.py")
+        "graft_entry", _GRAFT_ENTRY)
     m = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(m)
     m.dryrun_multichip(8)
@@ -177,7 +183,7 @@ def test_graft_entry_dryrun_multichip():
 def test_graft_entry_forward_compiles():
     import importlib.util
     spec = importlib.util.spec_from_file_location(
-        "graft_entry2", "/root/repo/__graft_entry__.py")
+        "graft_entry2", _GRAFT_ENTRY)
     m = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(m)
     fn, args = m.entry()

@@ -37,7 +37,10 @@ def test_exec_in_new_process_is_fresh_interpreter(tmp_path):
         del petastorm_tpu._spawn_test_canary
 
 
-def test_exec_in_new_process_pins_cpu(tmp_path):
+def test_exec_in_new_process_pins_cpu(tmp_path, monkeypatch):
+    """One process for each chip: a parent that exported JAX_PLATFORMS=tpu
+    (it holds the chip) must still spawn children that cannot reach it."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
     marker = str(tmp_path / "platform.txt")
     p = exec_in_new_process(report_jax_platform_env, marker)
     assert p.wait(timeout=60) == 0

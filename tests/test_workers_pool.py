@@ -122,6 +122,23 @@ def test_thread_pool_work_distribution():
 
 
 @pytest.mark.process_pool
+def test_process_pool_workers_are_pinned_to_cpu(monkeypatch):
+    """One process for each chip: the parent holds it, so every spawned
+    worker sees JAX_PLATFORMS=cpu even when the parent exported tpu."""
+    from petastorm_tpu.test_util.stub_workers import EnvReportWorker
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    pool = ProcessPool(2)
+    pool.start(EnvReportWorker, {"name": "JAX_PLATFORMS"})
+    try:
+        for i in range(4):
+            pool.ventilate(value=i)
+        assert [pool.get_results() for _ in range(4)] == ["cpu"] * 4
+    finally:
+        pool.stop()
+        pool.join()
+
+
+@pytest.mark.process_pool
 def test_process_pool_stop_with_full_ring_is_fast():
     """Early shutdown while workers are blocked writing into a full shm ring:
     stop() closes the rings so blocked writers fail out immediately instead of
