@@ -1,0 +1,6 @@
+"""Process CPU time (all threads) per token delivered in the window."""
+from chipbench.layer_metrics._shared import host_cpu_s_per_item
+
+
+def read(run):
+    return 1e6 * host_cpu_s_per_item(run)

@@ -1,0 +1,2 @@
+"""`input_stall_pct` of the tokens cells (body and meaning: _shared.input_stall_pct)."""
+from chipbench.layer_metrics._shared import input_stall_pct as read  # noqa: F401

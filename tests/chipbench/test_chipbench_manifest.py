@@ -1,0 +1,117 @@
+"""BENCHMARK.json and the files it names: everything a later PR adds is a
+new file and a new entry, so every entry must resolve by name alone."""
+import json
+import os
+import re
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    BENCH = json.load(_f)
+CELLS = {c["name"]: c for c in BENCH["workloads"]}
+METRICS = BENCH["end_to_end"] + BENCH["per_layer"]
+
+
+def reporting(metric: dict) -> set:
+    return set(metric.get("workloads", CELLS))
+
+
+def test_top_level_keys_and_limits():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    assert BENCH["paths"] == ["chipbench", "tests/chipbench"]
+    assert 1 <= BENCH["run_seconds"] <= 51
+    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 * 1024
+    four = [c for c in CELLS.values() if c["chips"] == 4]
+    assert len(four) <= max(1, len(CELLS) // 4)
+    assert all(c["chips"] in (1, 4) for c in CELLS.values())
+
+
+@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda e: e["name"])
+def test_config_file_loads_and_states_its_cut(entry):
+    assert set(entry) == {"name", "source", "file", "reduced", "why"}
+    assert entry["file"].startswith("chipbench/configs/")
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        config = json.load(f)
+    assert config["name"] == entry["name"]
+    assert config["source"] == entry["source"]
+    assert config["reduced"] == entry["reduced"]
+    for key in entry["reduced"]:
+        assert NAME.match(key) and key in config and key in config["published"]
+        assert not re.search(r"(_dim|_rank|_size|width)$", key)
+    assert config["guarantees"] and config["assumed"]
+    assert os.path.exists(os.path.join(
+        ROOT, "chipbench", "pipelines", config["pipeline"] + ".py"))
+    assert any(c["config"] == entry["name"] for c in CELLS.values())
+
+
+@pytest.mark.parametrize("cell", CELLS.values(), ids=lambda c: c["name"])
+def test_cell_resolves_by_name(cell):
+    assert set(cell) == {"name", "config", "traffic", "chips", "why"}
+    assert NAME.match(cell["name"]) and NAME.match(cell["traffic"])
+    assert len(cell["why"]) <= 200 and "\n" not in cell["why"]
+    assert cell["config"] in {c["name"] for c in BENCH["configs"]}
+    for sub, ext in (("traffic", cell["traffic"]), ("limits", cell["name"])):
+        with open(os.path.join(ROOT, "chipbench", sub, ext + ".json")) as f:
+            assert isinstance(json.load(f), dict)
+    mine = [m for m in BENCH["end_to_end"] if cell["name"] in reporting(m)]
+    assert {"setup_s"} < {m["name"] for m in mine}
+    assert any(cell["name"] in reporting(m) for m in BENCH["per_layer"])
+
+
+def test_cells_are_distinct_pairs():
+    pairs = [(c["config"], c["traffic"]) for c in CELLS.values()]
+    assert len(set(pairs)) == len(pairs)
+
+
+@pytest.mark.parametrize("metric", METRICS, ids=lambda m: m["name"])
+def test_metric_entry(metric):
+    assert NAME.match(metric["name"]) and UNIT.match(metric["unit"])
+    assert metric["better"] in ("lower", "higher")
+    assert metric["source"] in ("device_trace", "program_span",
+                                "program_counter", "host_clock")
+    assert reporting(metric) <= set(CELLS)
+    end_to_end = metric in BENCH["end_to_end"]
+    allowed = {"name", "unit", "better", "source", "workloads"} | (
+        {"bound"} if end_to_end else {"layer", "moves"})
+    assert set(metric) <= allowed
+    if end_to_end:
+        assert metric["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= metric["bound"] <= 0.1
+
+
+@pytest.mark.parametrize("metric", BENCH["per_layer"], ids=lambda m: m["name"])
+def test_per_layer_metric_has_reader_and_moves_what_its_cells_report(metric):
+    from chipbench.run import layer_metric_reader
+    assert callable(layer_metric_reader(metric["name"]))
+    moved = next(m for m in BENCH["end_to_end"] if m["name"] == metric["moves"])
+    assert metric["workloads"], "an explicit list, so a new cell edits nothing"
+    assert set(metric["workloads"]) <= reporting(moved)
+    if "roofline" in metric["name"] or "mfu" in metric["name"]:
+        assert metric["unit"] == "%"
+
+
+def test_every_cell_reports_a_whole_step_share_of_peak():
+    for cell in CELLS:
+        assert any("mfu" in re.split(r"[_.]", m["name"])
+                   and cell in m["workloads"] for m in BENCH["per_layer"])
+
+
+def test_names_are_unique():
+    for group in (METRICS, BENCH["workloads"], BENCH["configs"]):
+        names = [g["name"] for g in group]
+        assert len(set(names)) == len(names)
+
+
+def test_files_under_paths_have_plain_names():
+    for path in BENCH["paths"]:
+        for folder, _, files in os.walk(os.path.join(ROOT, path)):
+            if "__pycache__" in folder:
+                continue
+            for name in files:
+                assert re.match(r"^[A-Za-z0-9_.\-]+$", name), name
