@@ -457,20 +457,58 @@ class LoaderBase:
         stop = threading.Event()
         self._stage_stop = stop
         _END, _ERR = object(), object()
+        tele = self.telemetry
 
         # Consumer notifies after every get, so the producer wakes the
         # moment a slot frees (the bounded wait only bounds how late a
         # stop/knob change is noticed, it is not the delivery latency).
         space = threading.Condition()
 
-        def _put(item) -> bool:
+        def _put(item, batch_trace=None) -> bool:
+            parked = None
             with space:
-                while not stop.is_set():
-                    if q.qsize() < max(1, self._prefetch):
-                        q.put(item)
-                        return True
-                    space.wait(0.05)
+                try:
+                    while not stop.is_set():
+                        if q.qsize() < max(1, self._prefetch):
+                            q.put(item)
+                            return True
+                        if parked is None:
+                            # `prefetch` batches are staged ahead of the
+                            # consumer: the staging thread is idle, not slow.
+                            parked = traced_span(
+                                "petastorm_tpu.queue_full", tele,
+                                trace=batch_trace, track="stager")
+                            parked.__enter__()
+                        space.wait(0.05)
+                finally:
+                    if parked is not None:
+                        parked.close()
             return False
+
+        # Host-to-device transfer, measured off the staging thread: the
+        # stager hands each staged batch's arrays to this watcher and goes
+        # on collating the next batch exactly as before; the watcher holds
+        # the arrays only until they are ready.
+        watched: queue_mod.SimpleQueue = queue_mod.SimpleQueue()
+
+        def _watch():
+            while True:
+                job = watched.get()  # timeout-ok: the stager's finally always sends the None that ends this daemon
+                if job is None:
+                    return
+                batch_trace, staged_at, arrays, extra, landed = job
+                with traced_span("petastorm_tpu.h2d", tele, extra,
+                                 trace=batch_trace, track="h2d",
+                                 start_s=staged_at):
+                    for arr in arrays:
+                        try:
+                            arr.block_until_ready()
+                        except RuntimeError:
+                            # Deleted or donated: the step that took it
+                            # could only run on a ready buffer.
+                            extra["deleted"] = True
+                    landed.set()
+                del job, arrays
 
         def _produce():
             try:
@@ -479,36 +517,47 @@ class LoaderBase:
                 while not stop.is_set():
                     batch_seq += 1
                     batch_trace = f"b{batch_seq}"
-                    t0 = time.perf_counter()
-                    with traced_span("petastorm_tpu.host_batch",
-                                     self.telemetry, trace=batch_trace,
-                                     track="stager"):
+                    with traced_span("petastorm_tpu.host_batch", tele,
+                                     trace=batch_trace,
+                                     track="stager") as produced:
                         try:
                             hb = next(it)
                         except StopIteration:
                             break
-                    # Input-state snapshot BETWEEN reader pulls: it covers
-                    # exactly the rows assembled so far, so a checkpoint at
-                    # delivery of batch i resumes at batch i+1 — prefetched
-                    # but UNDELIVERED batches are re-read, not skipped (the
-                    # raw reader watermark would already have confirmed
-                    # them: data loss on resume).
-                    snap = self._snapshot_input_state()
-                    t1 = time.perf_counter()
-                    with traced_span("petastorm_tpu.stage", self.telemetry,
+                        # Input-state snapshot BETWEEN reader pulls: it
+                        # covers exactly the rows assembled so far, so a
+                        # checkpoint at delivery of batch i resumes at batch
+                        # i+1 — prefetched but UNDELIVERED batches are
+                        # re-read, not skipped (the raw reader watermark
+                        # would already have confirmed them: data loss on
+                        # resume).
+                        snap = self._snapshot_input_state()
+                    with traced_span("petastorm_tpu.stage", tele,
                                      trace=batch_trace, stage="stage",
-                                     track="stager"):
+                                     track="stager") as staging:
                         staged = self._stage(hb)
-                    t2 = time.perf_counter()
                     n = len(next(iter(hb.values()))) if hb else 0
+                    # One clock pair per site: the spans' own.
                     self.metrics.record_batch(n, self._last_staged_bytes,
-                                              t1 - t0, t2 - t1)
-                    if not _put((None, staged, snap)):
+                                              produced.duration_s,
+                                              staging.duration_s)
+                    arrays = [v for v in staged.values()
+                              if hasattr(v, "block_until_ready")]
+                    landed = threading.Event()
+                    watched.put((
+                        batch_trace, staging.start_s + staging.duration_s,
+                        arrays,
+                        {"bytes": self._last_staged_bytes,
+                         "shards": (len(arrays[0].sharding.device_set)
+                                    if arrays else 0)}, landed))
+                    if not _put((None, (batch_trace, staged, landed), snap),
+                                batch_trace):
                         return
             except BaseException as e:  # noqa: BLE001 - re-raised on consumer
                 _put((_ERR, e, None))
             finally:
                 _put((_END, None, None))
+                watched.put(None)
                 # Exhausted generators close cleanly; an abandoned one (early
                 # consumer exit) closes here, on the thread that was running
                 # it, so reader teardown doesn't race the consumer.
@@ -517,7 +566,10 @@ class LoaderBase:
 
         thread = threading.Thread(target=_produce, daemon=True,
                                   name="petastorm-tpu-stage")
+        watcher = threading.Thread(target=_watch, daemon=True,
+                                   name="petastorm-tpu-h2d")
         thread.start()
+        watcher.start()
         # The reader's autotune controller (when enabled) tunes this
         # iteration's prefetch depth; registration is dynamic so the knob
         # exists exactly while a staging pipeline does.
@@ -526,42 +578,66 @@ class LoaderBase:
         if autotune is not None:
             from petastorm_tpu.autotune import PrefetchDepthActuator
             prefetch_actuator = autotune.register(PrefetchDepthActuator(self))
+        # Stall attribution: a delivery is everything the consumer pays
+        # inside next(loader) — the q.get() AND the bookkeeping up to the
+        # yield — and one `deliver` span brackets it (the "device_put wait"
+        # a training step sees); time between a yield and the next resume
+        # is the consumer's device step. Delivery i is observed when the
+        # consumer comes back for i+1, so it is paired with the step that
+        # consumed it and the observation itself sits inside a bracket.
+        # The first delivery is pipeline spin-up, not a steady-state stall
+        # — skip it (same exclusion as
+        # benchmark.throughput.training_input_stall).
+        delivered_at = wait_s = None
+        # The bracket opens at the consumer's ask: stamped first thing
+        # after each resume, before the span object exists.
+        asked_at = time.perf_counter()
         try:
-            # Stall attribution: time blocked in q.get() is the input
-            # pipeline failing to keep ahead (the "device_put wait" a
-            # training step sees); time between our yields is the
-            # consumer's device step. The first delivery is pipeline
-            # spin-up, not a steady-state stall — skip it (same exclusion
-            # as benchmark.throughput.training_input_stall).
-            last_resume = None
             while True:
-                t0 = time.perf_counter()
-                kind, item, snap = _get_staged(q, thread)
-                with space:
-                    space.notify()
-                t1 = time.perf_counter()
-                if kind is _END:
-                    break
-                if kind is _ERR:
-                    raise item
-                if last_resume is not None:
-                    self.stall.observe(wait_s=t1 - t0,
-                                       busy_s=t0 - last_resume)
-                # Critical-path attribution per delivered batch: which
-                # producer edge accrued the most self-time since the last
-                # delivery (a handful of counter reads — always on).
-                self.critical_path.observe_batch()
-                self._last_input_state = snap
-                # Timestamp BEFORE yielding: the consumer's device step runs
-                # while this generator is suspended in the yields below, so
-                # the next iteration's t0 - last_resume spans exactly that
-                # step (taking it after resume would measure microseconds of
-                # generator overhead and misclassify every step host_bound).
-                last_resume = time.perf_counter()
+                extra = {"depth": q.qsize()}
+                with traced_span("petastorm_tpu.deliver", tele, extra,
+                                 track="consumer",
+                                 start_s=asked_at) as deliver:
+                    if wait_s is not None:
+                        self.stall.observe(wait_s=wait_s,
+                                           busy_s=asked_at - delivered_at)
+                        wait_s = None
+                    kind, item, snap = _get_staged(q, thread)
+                    with space:
+                        space.notify()
+                    if kind is _END:
+                        extra["end"] = True
+                        break
+                    if kind is _ERR:
+                        raise item
+                    deliver.trace, item, landed = item
+                    # The watcher had seen every array ready at hand-over:
+                    # the transfer hid behind the consumer's step. False:
+                    # the step that takes this batch may wait for it on
+                    # the device. (A flag, not is_ready(): no call into the
+                    # runtime on the training loop's thread.)
+                    extra["ready"] = landed.is_set()
+                    # Critical-path attribution per delivered batch: which
+                    # producer edge accrued the most self-time since the
+                    # last delivery (a handful of counter reads).
+                    self.critical_path.observe_batch()
+                    self._last_input_state = snap
+                # The span's clock pair is the delivery's: the consumer's
+                # device step runs while this generator is suspended in
+                # the yields below, from delivered_at to the next ask.
+                if delivered_at is not None:
+                    wait_s = deliver.duration_s
+                delivered_at = deliver.start_s + deliver.duration_s
                 yield item
                 for _ in range(self._echo - 1):
                     yield self._echo_copy(item)
+                asked_at = time.perf_counter()
         finally:
+            if wait_s is not None:
+                # The consumer left without coming back for another batch.
+                self.stall.observe(
+                    wait_s=wait_s,
+                    busy_s=time.perf_counter() - delivered_at)
             stop.set()
             with space:
                 space.notify_all()  # a depth-parked producer exits now
@@ -586,6 +662,9 @@ class LoaderBase:
                 logger.warning(
                     "Staging thread still busy after stop (reader stalled "
                     "mid-batch?); it will exit when the reader stops.")
+                # The stager's finally wakes the watcher when it does exit.
+            else:
+                watcher.join(5.0)
 
     def _finalize_tail(self, cols: Dict[str, np.ndarray], count: int,
                        target_rows: Optional[int] = None):
@@ -1343,24 +1422,31 @@ class DataLoader(LoaderBase):
                         # BatchedDataLoader's rebatch buffer).
                         self._pending_safe_state = self._snapshot_live_state()
                     try:
-                        cols = self._lazy_columns(reader.next_batch())
+                        batch = reader.next_batch()
                     except StopIteration:
                         exhausted = True
                         buf.finish()
                         break
+                    with traced_span("petastorm_tpu.collate", self.telemetry,
+                                     track="stager"):
+                        cols = self._lazy_columns(batch)
                     if cols:
                         buffered_rows += len(next(iter(cols.values())))
-                        t0 = time.perf_counter()
-                        buf.add_many(cols)
-                        shuffle_time.add(time.perf_counter() - t0)
+                        with traced_span("petastorm_tpu.shuffle_add",
+                                         self.telemetry, stage="shuffle",
+                                         track="stager") as span:
+                            buf.add_many(cols)
+                        shuffle_time.add(span.duration_s)
                 if buf.can_retrieve:
-                    t0 = time.perf_counter()
-                    if shuffled:
-                        piece = buf.retrieve_batch(
-                            self._batch_size - part_rows)
-                    else:
-                        piece = buf.retrieve()
-                    shuffle_time.add(time.perf_counter() - t0)
+                    with traced_span("petastorm_tpu.shuffle_retrieve",
+                                     self.telemetry, stage="shuffle",
+                                     track="stager") as span:
+                        if shuffled:
+                            piece = buf.retrieve_batch(
+                                self._batch_size - part_rows)
+                        else:
+                            piece = buf.retrieve()
+                    shuffle_time.add(span.duration_s)
                     n = len(next(iter(piece.values())))
                     buffered_rows = max(0, buffered_rows - n)
                     parts.append(piece)
@@ -1392,12 +1478,19 @@ class DataLoader(LoaderBase):
         for row in self._row_iterator():  # rowloop-ok: eager compat path (byte-identical to pre-round-11 streams)
             rows.append(row)
             if len(rows) == self._batch_size:
-                yield self._collate(rows)
+                yield self._collated(rows)
                 rows = []
         if rows:
-            tail = self._finalize_tail(self._collate(rows), len(rows))
+            tail = self._finalize_tail(self._collated(rows), len(rows))
             if tail is not None:
                 yield tail
+
+    def _collated(self, rows) -> Dict[str, np.ndarray]:
+        """One batch's rows -> stacked columns, under the ``collate`` span
+        (once a batch, outside the row walk that gathered ``rows``)."""
+        with traced_span("petastorm_tpu.collate", self.telemetry,
+                         track="stager"):
+            return self._collate(rows)
 
 
 class BatchedDataLoader(LoaderBase):
@@ -1472,22 +1565,21 @@ class BatchedDataLoader(LoaderBase):
                         cols = self._next_group_columns()
                         if cols:
                             buffered_rows += len(next(iter(cols.values())))
-                            t0 = time.perf_counter()
-                            with self.telemetry.span(
+                            with traced_span(
                                     "petastorm_tpu.shuffle_add",
-                                    stage="shuffle", track="shuffler"):
+                                    self.telemetry, stage="shuffle",
+                                    track="shuffler") as span:
                                 buf.add_many(cols)
-                            shuffle_time.add(time.perf_counter() - t0)
+                            shuffle_time.add(span.duration_s)
                     except StopIteration:
                         exhausted = True
                         buf.finish()
                 if buf.can_retrieve:
-                    t0 = time.perf_counter()
-                    with self.telemetry.span("petastorm_tpu.shuffle_retrieve",
-                                             stage="shuffle",
-                                             track="shuffler"):
+                    with traced_span("petastorm_tpu.shuffle_retrieve",
+                                     self.telemetry, stage="shuffle",
+                                     track="shuffler") as span:
                         batch = buf.retrieve()
-                    shuffle_time.add(time.perf_counter() - t0)
+                    shuffle_time.add(span.duration_s)
                     n = len(next(iter(batch.values())))
                     buffered_rows = max(0, buffered_rows - n)
                     if n == self._batch_size:

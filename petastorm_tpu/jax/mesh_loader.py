@@ -65,6 +65,7 @@ import numpy as np
 from petastorm_tpu.errors import NoDataAvailableError
 from petastorm_tpu.jax.dtypes import sanitize_batch
 from petastorm_tpu.jax.loader import LoaderBase
+from petastorm_tpu.metrics import traced_span
 from petastorm_tpu.reader_impl.batch_plane import ColumnarBatch
 
 logger = logging.getLogger(__name__)
@@ -785,13 +786,19 @@ class MeshDataLoader(LoaderBase):
                         except StopIteration:
                             return
                 it = _batches()
+                per_group = True
             else:
                 it = iter(reader)
+                per_group = bool(reader.batched_output)
+            # The ring is on by default and a span site fires per row group,
+            # never per row: a row/windowed source (one item a row) spans
+            # its pulls in trace mode only, where the operator asked.
+            span_pulls = per_group or rec.trace_enabled
             while True:
                 if feed.killed.is_set():
                     raise _HostKilled(f"host {feed.idx} killed")
                 try:
-                    if rec.enabled:
+                    if span_pulls:
                         # Per-host pull span: per-host reader epochs are
                         # single-epoch (e0), so the lineage id matches the
                         # reader's own spans for this global ordinal.
@@ -804,8 +811,8 @@ class MeshDataLoader(LoaderBase):
                         # other flavors are group-granular approximations.
                         ordinal = src.ordinals[min(src.counted,
                                                    len(src.ordinals) - 1)]
-                        with self.telemetry.span(
-                                "petastorm_tpu.mesh_pull",
+                        with traced_span(
+                                "petastorm_tpu.mesh_pull", self.telemetry,
                                 trace=f"e0:g{ordinal}", stage="pull",
                                 track=f"h{feed.idx}:pull"):
                             item = next(it)
@@ -897,12 +904,9 @@ class MeshDataLoader(LoaderBase):
         rt = getattr(reader, "telemetry", None)
         if rt is None:
             return
-        # Decode has two same-work sources (max, never sum): the
-        # in-process pools' histogram and — process-pool host readers in
-        # trace mode — the spawned workers' piggybacked spans accruing
-        # trace.span.decode_s (mirrors CriticalPathAttributor._cumulative).
-        cur = {"decode": max(rt.peek_histogram_sum("worker.decode_s"),
-                             rt.peek_counter("trace.span.decode_s")),
+        # Decode is one measurement on every pool flavor: worker.decode_s
+        # (mirrors CriticalPathAttributor._cumulative).
+        cur = {"decode": rt.peek_histogram_sum("worker.decode_s"),
                "fetch": rt.peek_counter("io.readahead.fetch_s"),
                "transport": rt.peek_counter("transport.deserialize_s")}
         for key, value in cur.items():
@@ -1321,13 +1325,13 @@ class MeshDataLoader(LoaderBase):
                         continue
                 while pool_rows >= self._step_rows:
                     self._batch_seq += 1
-                    t0 = time.perf_counter()
-                    with self.telemetry.span("petastorm_tpu.mesh_assemble",
-                                             trace=f"b{self._batch_seq}",
-                                             stage="assemble",
-                                             track="assemble"):
+                    with traced_span("petastorm_tpu.mesh_assemble",
+                                     self.telemetry,
+                                     trace=f"b{self._batch_seq}",
+                                     stage="assemble",
+                                     track="assemble") as span:
                         batch = self._assemble(pool, self._step_rows, epoch)
-                    self._c_assemble.add(time.perf_counter() - t0)
+                    self._c_assemble.add(span.duration_s)
                     pool_rows -= self._step_rows
                     yield batch
             if pool_rows:

@@ -12,8 +12,8 @@ attribution, Prometheus/JSON export) lives in
 """
 from __future__ import annotations
 
+import sys
 import threading
-from contextlib import contextmanager
 
 
 class PipelineMetrics:
@@ -140,58 +140,59 @@ class PipelineMetrics:
 _TRACE_ANNOTATION = None  # resolved once; False = jax unavailable
 
 
-@contextmanager
-def trace(name: str):
-    """``jax.profiler.TraceAnnotation`` when jax is importable, no-op
-    otherwise — safe to use in worker processes pinned off the TPU. The
-    import is attempted once (failed imports are not cached by python, and
-    this sits on the per-batch hot path)."""
+class _NoTrace:
+    """Shared stand-in where there is no profiler to annotate for."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NO_TRACE = _NoTrace()
+
+
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)``, or None where there is no
+    profiler to annotate for: jax is not loaded in this process (a
+    reader-only job, a worker pinned off the TPU — no profiler can be
+    tracing it, and its spans must not import jax), or its profiler is
+    unimportable. The import is attempted once (failed imports are not
+    cached by python, and this sits on the per-batch hot path)."""
     global _TRACE_ANNOTATION
     if _TRACE_ANNOTATION is None:
+        if "jax" not in sys.modules:
+            return None
         try:
             from jax.profiler import TraceAnnotation
             _TRACE_ANNOTATION = TraceAnnotation
         except ImportError:  # pragma: no cover
             _TRACE_ANNOTATION = False
-    if _TRACE_ANNOTATION is False:
-        yield
-        return
-    with _TRACE_ANNOTATION(name):
-        yield
+    return _TRACE_ANNOTATION(name) if _TRACE_ANNOTATION else None
 
 
-def traced_span(name: str, telemetry=None, **span_kw):
-    """Context manager pairing a ``jax.profiler`` trace annotation with a
-    telemetry recorder span of the SAME name, so the profiler timeline and
-    the telemetry snapshot attribute time to identical labels. Extra
-    keyword args (``trace=``/``stage=``/``track=``) pass through to the
-    recorder span — lineage provenance in trace mode."""
+def trace(name: str):
+    """A ``jax.profiler`` annotation context manager, or a shared no-op
+    (see :func:`_annotation`)."""
+    return _annotation(name) or _NO_TRACE
+
+
+def traced_span(name: str, telemetry=None, extra=None, **span_kw):
+    """THE span entry point of the hot path: one context manager, two
+    sinks. It records ``name`` into ``telemetry``'s span ring and emits a
+    ``jax.profiler`` annotation of the same name over the same interval,
+    so an operator's Perfetto / XProf view shows the loader and worker
+    lanes beside ``XLA Ops`` by construction, and the ring's readers and
+    the profiler attribute time to identical labels. ``extra`` and the
+    keyword args (``trace=`` / ``stage=`` / ``track=`` / ``start_s=``)
+    pass through to :meth:`SpanRecorder.span`. Without a registry it is
+    the bare annotation."""
     if telemetry is None:
         return trace(name)
-    return _TracedSpan(name, telemetry, span_kw)
-
-
-class _TracedSpan:
-    __slots__ = ("_name", "_telemetry", "_span_kw", "_trace_cm", "_span_cm")
-
-    def __init__(self, name: str, telemetry, span_kw=None):
-        self._name = name
-        self._telemetry = telemetry
-        self._span_kw = span_kw or {}
-
-    def __enter__(self):
-        self._trace_cm = trace(self._name)
-        self._span_cm = self._telemetry.span(self._name, **self._span_kw)
-        self._trace_cm.__enter__()
-        self._span_cm.__enter__()
-        return self
-
-    def __exit__(self, *exc):
-        try:
-            self._span_cm.__exit__(*exc)
-        finally:
-            self._trace_cm.__exit__(*exc)
-        return False
+    return telemetry.recorder.span(name, extra, annotation=_annotation(name),
+                                   **span_kw)
 
 
 __all__ = ["PipelineMetrics", "trace", "traced_span"]

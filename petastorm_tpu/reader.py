@@ -37,6 +37,7 @@ from petastorm_tpu.errors import MetadataError, NoDataAvailableError
 from petastorm_tpu.etl.dataset_metadata import (DatasetContext, get_schema,
                                                 infer_or_load_unischema,
                                                 load_row_groups)
+from petastorm_tpu.metrics import traced_span
 from petastorm_tpu.ngram import NGram
 from petastorm_tpu.reader_impl.batch_plane import ColumnarBatch
 from petastorm_tpu.reader_impl.batch_reader_worker import (BatchReaderWorker,
@@ -3210,11 +3211,10 @@ class _PoolWaitTimer:
             return self._pool.get_results()
         inline0 = (self._inline_decode_pool.inline_decode_s
                    if self._inline_decode_pool is not None else 0.0)
-        t0 = time.perf_counter()
-        with self._telemetry.span("petastorm_tpu.pool_wait",
-                                  stage="deliver", track="consumer"):
+        with traced_span("petastorm_tpu.pool_wait", self._telemetry,
+                         stage="deliver", track="consumer") as span:
             result = self._pool.get_results()
-        wait = time.perf_counter() - t0
+        wait = span.duration_s
         if self._inline_decode_pool is not None:
             wait -= self._inline_decode_pool.inline_decode_s - inline0
         self._wait_hist.observe(max(0.0, wait))

@@ -53,9 +53,10 @@ from __future__ import annotations
 
 import logging
 import threading
-import time
 from collections import deque
 from typing import Optional
+
+from petastorm_tpu.metrics import traced_span
 
 logger = logging.getLogger(__name__)
 
@@ -316,23 +317,21 @@ class ReadaheadFetcher:
                 self._inflight[key] = self._inflight.get(key, 0) + 1
                 self._ahead += 1
             table = None
-            t0 = time.perf_counter()
+            # First-class fetch provenance: stage="fetch" on the fetcher's
+            # own track, carrying the item's lineage id. The span's clock
+            # pair feeds io.readahead.fetch_s too (a failed fetch counts).
+            span = traced_span("petastorm_tpu.fetch", self._telemetry,
+                               trace=trace, stage="fetch",
+                               track=f"fetch:{idx}")
             try:
-                if self._telemetry is not None:
-                    # First-class fetch provenance: stage="fetch" on the
-                    # fetcher's own track, carrying the item's lineage id.
-                    with self._telemetry.span("petastorm_tpu.fetch",
-                                              trace=trace, stage="fetch",
-                                              track=f"fetch:{idx}"):
-                        table = self._fetch(rowgroup, idx)
-                else:
+                with span:
                     table = self._fetch(rowgroup, idx)
             except Exception as e:  # noqa: BLE001 - inline read owns retries
                 self._count("fetch_errors")
                 logger.debug("readahead fetch of %s failed (inline read "
                              "will retry): %s", key, e)
             if self._fetch_s is not None:
-                self._fetch_s.add(time.perf_counter() - t0)
+                self._fetch_s.add(span.duration_s)
             nbytes = int(table.nbytes) if table is not None else 0
             with self._cv:
                 self._inflight[key] -= 1

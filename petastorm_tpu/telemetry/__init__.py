@@ -55,15 +55,11 @@ from petastorm_tpu.telemetry.stall import StallAttributor
 #: watch <path>`` consumes.
 TELEMETRY_EXPORT_ENV = "PETASTORM_TPU_TELEMETRY_EXPORT"
 
-#: Environment variable: any non-empty value enables span recording on every
-#: new registry (spans default off — gauges/counters/histograms are always
-#: on, they are cheap).
-TELEMETRY_SPANS_ENV = "PETASTORM_TPU_TELEMETRY_SPANS"
-
 #: Environment variable: any non-empty value puts every new registry in
-#: TRACE mode — spans on, lineage (trace/stage/track) fields recorded, ring
-#: capacity grown so a whole epoch survives for ``python -m
-#: petastorm_tpu.telemetry trace`` export. Implies TELEMETRY_SPANS_ENV.
+#: TRACE mode — row-group lineage ids minted at ventilation, raw spans in
+#: snapshots, ring capacity grown so a whole epoch survives for ``python -m
+#: petastorm_tpu.telemetry trace`` export. (Spans themselves are recorded
+#: from construction; ``registry.recorder.disable()`` turns them off.)
 TELEMETRY_TRACE_ENV = "PETASTORM_TPU_TELEMETRY_TRACE"
 
 #: Environment variable: start an :class:`~petastorm_tpu.telemetry.slo.
@@ -74,11 +70,9 @@ SLO_WATCH_ENV = "PETASTORM_TPU_SLO_WATCH"
 
 
 def make_registry() -> TelemetryRegistry:
-    """A registry honoring :data:`TELEMETRY_SPANS_ENV` and
-    :data:`TELEMETRY_TRACE_ENV`."""
+    """A registry honoring :data:`TELEMETRY_TRACE_ENV`."""
     import os
-    registry = TelemetryRegistry(
-        spans_enabled=bool(os.environ.get(TELEMETRY_SPANS_ENV)))
+    registry = TelemetryRegistry()
     if os.environ.get(TELEMETRY_TRACE_ENV):
         registry.recorder.enable_trace()
     return registry
@@ -123,8 +117,8 @@ __all__ = [
     "PeriodicExporter", "SIZE_BOUNDS", "SLO_WATCH_ENV",
     "SNAPSHOT_SCHEMA_VERSION", "SeriesSpec", "SloRule", "SloWatcher",
     "Span", "SpanRecorder", "StallAttributor", "StreamingHistogram",
-    "TELEMETRY_EXPORT_ENV", "TELEMETRY_PUBLISH_ENV", "TELEMETRY_SPANS_ENV",
-    "TELEMETRY_TRACE_ENV", "TIMELINE_ENV", "TelemetryAggregator",
+    "TELEMETRY_EXPORT_ENV", "TELEMETRY_PUBLISH_ENV", "TELEMETRY_TRACE_ENV",
+    "TIMELINE_ENV", "TelemetryAggregator",
     "TelemetryPublisher", "TelemetryRegistry", "TimelineSampler",
     "TraceContext", "accounting_totals", "blackbox_dir_from_env",
     "complete_lineages", "default_anomaly_rules", "detect_over_timeline",

@@ -386,6 +386,9 @@ class TelemetryAggregator:
         self._on_silent = on_silent
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
+        # A zmq socket belongs to one thread at a time: a caller's own
+        # poll_once() beside the started thread's must take turns.
+        self._poll_lock = threading.Lock()
         self._last_tick: Optional[float] = None
         self._c_received = self.registry.counter("fabric.windows_received")
         self._c_joined = self.registry.counter("fabric.members_joined")
@@ -424,14 +427,15 @@ class TelemetryAggregator:
         wait_ms = int(1000 * (timeout_s if timeout_s is not None
                               else min(self._interval / 2, 0.2)))
         handled = 0
-        if self._sock.poll(max(wait_ms, 1)):
-            while True:
-                try:
-                    raw = self._sock.recv(zmq.NOBLOCK)
-                except zmq.Again:
-                    break
-                self._handle_raw(raw)
-                handled += 1
+        with self._poll_lock:
+            if self._sock.poll(max(wait_ms, 1)):
+                while True:
+                    try:
+                        raw = self._sock.recv(zmq.NOBLOCK)
+                    except zmq.Again:
+                        break
+                    self._handle_raw(raw)
+                    handled += 1
         now = time.perf_counter()
         if self._last_tick is None or now - self._last_tick >= self._interval:
             self.tick(now)

@@ -2,9 +2,18 @@
 
 A :class:`SpanRecorder` collects named, monotonic-clock spans with thread and
 process provenance into a bounded ring buffer (old spans are evicted, the
-pipeline never grows without bound). The disabled hot path is a single
-attribute check returning a shared no-op context manager — cheap enough to
-leave ``recorder.span(...)`` permanently inlined on per-batch paths.
+pipeline never grows without bound). It records from construction: span
+sites fire per row group and per batch, never per row, so the ring costs a
+few hundred appends a second (``disable()`` is the operator's switch: a
+span then still times itself for its site's counters, and skips the ring).
+
+A span has two sinks. The ring is one; the other is an optional
+``annotation`` context manager (``jax.profiler.TraceAnnotation`` of the
+same name, supplied by :func:`petastorm_tpu.metrics.traced_span`) entered
+and left around the same interval, so a device trace shows the span in its
+thread's lane. Spans opened on one thread nest: the innermost open span is
+the parent of the next one opened there (``parent_id``), which is what a
+reader needs for self time (duration minus children).
 
 Trace mode (docs/observability.md "Trace plane") layers batch lineage on
 top: spans may carry a ``trace`` id (``e{epoch}:g{ordinal}`` — the work
@@ -19,6 +28,8 @@ cross the boundary as compact tuples via :meth:`record_remote`.
 Clock discipline: spans use ``time.perf_counter()`` exclusively.
 ``time.time()`` is wall-clock and can step backwards under NTP slew — it is
 banned from hot paths repo-wide (enforced by ``tools/check_monotonic.py``).
+:meth:`SpanRecorder.anchor` pairs the two clocks once, so a reader can place
+a span on Unix nanoseconds (the profiler's clock).
 """
 from __future__ import annotations
 
@@ -30,7 +41,13 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
-__all__ = ["Span", "SpanRecorder", "TRACE_SPAN_CAPACITY"]
+__all__ = ["Span", "SpanRecorder", "SPAN_CAPACITY", "TRACE_SPAN_CAPACITY"]
+
+#: Default ring capacity. One measured window of four chips at the image
+#: cell's per-chip rate is ~650 spans/s x 30 s = 19.5k spans (row groups x
+#: 3 + batches x 6); the ring must hold such a window whole, because a
+#: reader refuses a window the ring dropped spans of.
+SPAN_CAPACITY = 32768
 
 #: Ring capacity :meth:`SpanRecorder.enable_trace` grows to: large enough
 #: that an 8-host simulated mesh epoch (hundreds of row groups x ~6 stages)
@@ -58,7 +75,7 @@ if hasattr(os, "register_at_fork"):  # pragma: no branch
     os.register_at_fork(after_in_child=_refresh_pid)
 
 
-@dataclass(frozen=True)
+@dataclass(slots=True)
 class Span:
     """One completed span. ``start_s`` is a ``perf_counter`` timestamp —
     meaningful only relative to other spans from the same process (remote
@@ -99,48 +116,63 @@ class Span:
         return d
 
 
-class _NoopSpan:
-    """Shared disabled-path context manager: no allocation per call."""
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NOOP_SPAN = _NoopSpan()
+#: Innermost open span per thread (``.span`` attribute): the parent of
+#: the next span opened on that thread.
+_OPEN = threading.local()
 
 
 class _LiveSpan:
-    __slots__ = ("_recorder", "_name", "_extra", "_t0", "_trace", "_stage",
-                 "_track", "_parent_id", "span_id")
+    """One open span: the ring's record and (optionally) the profiler's
+    annotation over the same interval. ``start_s`` / ``duration_s`` stay
+    readable after the span closed, so the one clock pair also feeds the
+    site's counters. ``close()`` ends it early (idempotent), for a span
+    whose end is not the end of a ``with`` block."""
+    __slots__ = ("_recorder", "_name", "extra", "trace", "_stage",
+                 "_track", "_parent", "_annotation", "_open", "span_id",
+                 "parent_id", "start_s", "duration_s")
 
     def __init__(self, recorder, name, extra, trace=None, stage=None,
-                 track=None, parent_id=0):
+                 track=None, parent_id=0, annotation=None, start_s=None):
         self._recorder = recorder
         self._name = name
-        self._extra = extra
-        self._trace = trace
+        self.extra = extra
+        self.trace = trace
         self._stage = stage
         self._track = track
-        self._parent_id = parent_id
+        self._annotation = annotation
+        self._open = False
+        self.parent_id = parent_id
         self.span_id = 0
+        self.start_s = start_s
+        self.duration_s = 0.0
 
     def __enter__(self):
-        if self._trace is not None or self._stage is not None:
-            self.span_id = next(_SPAN_IDS)
-        self._t0 = time.perf_counter()
+        # Stamped first and (in close) last: a span covers its own
+        # bookkeeping, so the spans of one thread tile its wall time and
+        # what a caller pays around a span is a few bytecodes.
+        if self.start_s is None:
+            self.start_s = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self._parent = getattr(_OPEN, "span", None)
+        if not self.parent_id and self._parent is not None:
+            self.parent_id = self._parent.span_id
+        _OPEN.span = self
+        self.span_id = next(_SPAN_IDS)
+        self._open = True
         return self
 
+    def close(self):
+        if not self._open:
+            return
+        self._open = False
+        _OPEN.span = self._parent
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        self._recorder._close(self)
+
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
-        self._recorder.record(self._name, self._t0, t1 - self._t0,
-                              extra=self._extra, trace=self._trace,
-                              stage=self._stage, track=self._track,
-                              span_id=self.span_id,
-                              parent_id=self._parent_id)
+        self.close()
         return False
 
 
@@ -148,11 +180,11 @@ class SpanRecorder:
     """Ring-buffer bounded span sink.
 
     :param capacity: max retained spans (oldest evicted first)
-    :param enabled: record spans when True; when False ``span()`` returns a
-        shared no-op context manager (sub-microsecond)
+    :param enabled: record spans when True (the default); when False a
+        span still times itself and annotates, and the ring stays empty
     """
 
-    def __init__(self, capacity: int = 4096, enabled: bool = False):
+    def __init__(self, capacity: int = SPAN_CAPACITY, enabled: bool = True):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self._spans: deque = deque(maxlen=capacity)
@@ -187,14 +219,29 @@ class SpanRecorder:
         self.enabled = True
         self.trace_enabled = True
 
+    @staticmethod
+    def anchor() -> tuple:
+        """A fresh ``(perf_counter_ns, time_ns)`` pair taken back to back:
+        ``start_s * 1e9 - perf_ns + unix_ns`` places a span on Unix
+        nanoseconds, the clock of a profiler trace's events."""
+        perf_ns = time.perf_counter_ns()
+        unix_ns = time.time_ns()  # wall-clock-ok: one-shot stamp pairing the two clocks, never a duration
+        return perf_ns, unix_ns
+
     def span(self, name: str, extra: Optional[dict] = None, *,
              trace: Optional[str] = None, stage: Optional[str] = None,
-             track: Optional[str] = None, parent_id: int = 0):
-        """Context manager timing one span; no-op while disabled. ``trace``
-        / ``stage`` / ``track`` attach lineage provenance (trace mode)."""
-        if not self.enabled:
-            return _NOOP_SPAN
-        return _LiveSpan(self, name, extra, trace, stage, track, parent_id)
+             track: Optional[str] = None, parent_id: int = 0,
+             annotation=None, start_s: Optional[float] = None):
+        """Context manager timing one span. ``trace`` / ``stage`` /
+        ``track`` attach lineage provenance; ``annotation`` is the second
+        sink, entered and left with the span; ``start_s`` backdates the
+        record to a
+        ``perf_counter`` stamp taken on another thread (a span handed over
+        to the thread that closes it). While the recorder is disabled the
+        span still times itself (``start_s`` / ``duration_s`` feed the
+        site's counters) and still annotates; only the ring is skipped."""
+        return _LiveSpan(self, name, extra, trace, stage, track, parent_id,
+                         annotation, start_s)
 
     def record(self, name: str, start_s: float, duration_s: float,
                extra: Optional[dict] = None, trace: Optional[str] = None,
@@ -209,6 +256,25 @@ class SpanRecorder:
         self._append((sp,))
         if stage is not None and self.on_stage is not None:
             self.on_stage(stage, duration_s)
+
+    def _close(self, live: "_LiveSpan") -> None:
+        """End ``live``: its end stamp is the last thing taken before the
+        ring append, under the lock (see :meth:`_LiveSpan.__enter__`)."""
+        if not self.enabled:
+            live.duration_s = time.perf_counter() - live.start_s
+            return
+        t = threading.current_thread()
+        sp = Span(live._name, live.start_s, 0.0, t.name, t.ident or 0, _PID,
+                  live.extra, live.trace, live._stage, live._track,
+                  live.span_id, live.parent_id)
+        with self._lock:
+            if len(self._spans) == self._spans.maxlen:
+                self._dropped += 1
+            live.duration_s = sp.duration_s = (time.perf_counter()
+                                               - live.start_s)
+            self._spans.append(sp)
+        if sp.stage is not None and self.on_stage is not None:
+            self.on_stage(sp.stage, sp.duration_s)
 
     def record_event(self, name: str, extra: Optional[dict] = None, *,
                      trace: Optional[str] = None,
@@ -245,9 +311,9 @@ class SpanRecorder:
         self._append(spans)
 
     def _append(self, spans) -> None:
-        """The single ring-append path (one lock hold for the whole
-        sequence): capacity eviction and the dropped count live here and
-        nowhere else."""
+        """The ring-append path of completed spans (one lock hold for the
+        whole sequence): capacity eviction and the dropped count live here
+        and in :meth:`_close`, which appends one live span."""
         with self._lock:
             for sp in spans:
                 if len(self._spans) == self._spans.maxlen:

@@ -18,7 +18,7 @@ from collections import deque
 from typing import Callable, Dict, Optional, Sequence
 
 from petastorm_tpu.telemetry.histogram import StreamingHistogram
-from petastorm_tpu.telemetry.recorder import SpanRecorder
+from petastorm_tpu.telemetry.recorder import SPAN_CAPACITY, SpanRecorder
 
 __all__ = ["Counter", "Gauge", "TelemetryRegistry", "SNAPSHOT_SCHEMA_VERSION"]
 
@@ -104,16 +104,16 @@ class TelemetryRegistry:
     #: stack dump).
     EVENTS_PER_NAME = 16
 
-    def __init__(self, span_capacity: int = 4096,
-                 spans_enabled: bool = False):
+    def __init__(self, span_capacity: int = SPAN_CAPACITY):
         self._lock = threading.Lock()
         self._counters: Dict[str, Counter] = {}
         self._gauges: Dict[str, Gauge] = {}
         self._histograms: Dict[str, StreamingHistogram] = {}
         self._events: Dict[str, deque] = {}
         self._event_seq = 0
-        self.recorder = SpanRecorder(capacity=span_capacity,
-                                     enabled=spans_enabled)
+        # Records from construction into the bounded ring;
+        # ``recorder.disable()`` is the operator's switch.
+        self.recorder = SpanRecorder(capacity=span_capacity)
         # Every recorded span carrying a stage also accrues the stage's
         # span-time counter (trace.span.{stage}_s) — the span-derived view
         # next to the always-on counters the critical-path attributor reads.
@@ -186,8 +186,9 @@ class TelemetryRegistry:
             return h
 
     def span(self, name: str, extra: Optional[dict] = None, **kw):
-        """Shortcut for ``registry.recorder.span(...)`` (``trace=`` /
-        ``stage=`` / ``track=`` attach lineage provenance in trace mode)."""
+        """Shortcut for ``registry.recorder.span(...)``: the ring alone.
+        Hot-path sites go through :func:`petastorm_tpu.metrics.traced_span`,
+        which adds the profiler annotation of the same name."""
         return self.recorder.span(name, extra, **kw)
 
     # ------------------------------------------------------------- peeking

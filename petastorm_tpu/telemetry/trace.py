@@ -261,16 +261,13 @@ class CriticalPathAttributor:
         for stage in CRITICAL_STAGES:
             cname = _STAGE_COUNTERS[stage]
             if cname is None:
-                # Decode self-time has two sources that cover the SAME
-                # work: the in-process pools' worker.decode_s histogram,
-                # and (process pools in trace mode) the spawned workers'
-                # piggybacked spans accruing trace.span.decode_s. Take the
-                # max — never the sum, which would double-count thread
-                # pools with spans on — plus the mesh loader's per-host
-                # sync (host readers keep private registries). Max of
-                # monotonic counters stays monotonic, so deltas are sound.
-                out[stage] = (max(self._histogram_sum("worker.decode_s"),
-                                  self._counter_value("trace.span.decode_s"))
+                # Decode self-time is ONE measurement on every pool: the
+                # worker.decode_s histogram, fed by the decode span's own
+                # clock pair in the thread and dummy pools and by the
+                # processed marker's busy_s in the process pool — plus the
+                # mesh loader's per-host sync (host readers keep private
+                # registries).
+                out[stage] = (self._histogram_sum("worker.decode_s")
                               + self._counter_value("mesh.host_decode_s"))
             else:
                 out[stage] = self._counter_value(cname)

@@ -12,6 +12,7 @@ from __future__ import annotations
 import time
 from collections import deque
 
+from petastorm_tpu.metrics import traced_span
 from petastorm_tpu.resilience.quarantine import (RowGroupSkipped,
                                                  RowGroupSkippedMessage)
 from petastorm_tpu.workers_pool import (EmptyResultError,
@@ -118,11 +119,15 @@ class DummyPool:
                             f"pool.w{wid}.items")
                         self._c_w_busy = self.telemetry.counter(
                             f"pool.w{wid}.busy_s")
-                    with self.telemetry.span("petastorm_tpu.worker_decode",
-                                             trace=trace, stage="decode",
-                                             track="worker:0"):
+                    # Publishing is a deque append here: nothing blocks, so
+                    # the whole item is decode. One clock pair (the span's)
+                    # feeds the histogram and the counters.
+                    with traced_span("petastorm_tpu.worker_decode",
+                                     self.telemetry, trace=trace,
+                                     stage="decode",
+                                     track="worker:0") as decode:
                         self._process_item(args, kwargs)
-                    dt = time.perf_counter() - t0
+                    dt = decode.duration_s
                     self._decode_hist.observe(dt)
                     self.inline_decode_s += dt
                     self._c_w_busy.add(dt)

@@ -46,6 +46,7 @@ import time
 import uuid
 from traceback import format_exc
 
+from petastorm_tpu.metrics import traced_span
 from petastorm_tpu.reader_impl.epoch_plan import OrderedUnit
 from petastorm_tpu.reader_impl.pickle_serializer import PickleSerializer
 from petastorm_tpu.resilience.quarantine import (RowGroupSkipped,
@@ -211,6 +212,7 @@ class ProcessPool:
         # Lazily-resolved transport.deserialize_s counter (telemetry is
         # assigned by the Reader after construction).
         self._c_deser = None
+        self._h_decode = None
         # Per-worker federation counters, cached per worker id (the
         # registry lock is not for per-item paths).
         self._c_w_items = {}
@@ -342,6 +344,9 @@ class ProcessPool:
                     busy = getattr(msg, "busy_s", None)
                     if busy:
                         self._worker_busy(wid).add(busy)
+                        # The same number is the pipeline's decode time:
+                        # one measurement, whichever pool decoded.
+                        self._decode_hist().observe(busy)
                 if self.recovery is not None:
                     self.recovery.on_processed(msg.item_context)
                 if self._ventilator:
@@ -372,6 +377,12 @@ class ProcessPool:
             c = self._c_w_items[worker_id] = self.telemetry.counter(
                 f"pool.w{worker_id}.items")
         return c
+
+    def _decode_hist(self):
+        h = self._h_decode
+        if h is None:
+            h = self._h_decode = self.telemetry.histogram("worker.decode_s")
+        return h
 
     def _worker_busy(self, worker_id: int):
         c = self._c_w_busy.get(worker_id)
@@ -505,12 +516,11 @@ class ProcessPool:
         if c is None:
             c = self._c_deser = tele.counter("transport.deserialize_s")
         track = "transport" if idx is None else f"transport:{idx}"
-        t0 = time.perf_counter()
-        with tele.span("petastorm_tpu.transport", stage="transport",
-                       track=track):
+        with traced_span("petastorm_tpu.transport", tele, stage="transport",
+                         track=track) as span:
             result = self._serializer.deserialize(buf)
             result = self._apply_transform(result)
-        c.add(time.perf_counter() - t0)
+        c.add(span.duration_s)
         return result
 
     def _apply_transform(self, result):

@@ -29,6 +29,7 @@ import threading
 from traceback import format_exc
 from typing import Optional
 
+from petastorm_tpu.metrics import traced_span
 from petastorm_tpu.resilience.quarantine import (RowGroupSkipped,
                                                  RowGroupSkippedMessage)
 from petastorm_tpu.workers_pool import (EmptyResultError,
@@ -126,11 +127,14 @@ class ConcurrencyGate:
 
 
 class _WorkerThread(threading.Thread):
-    def __init__(self, worker_impl, input_queue, result_queue, stop_event,
-                 put_fn, prof=None, telemetry=None, gate=None,
-                 heartbeats=None, straggler=None):
-        super().__init__(name=f"pt-worker-{worker_impl.worker_id}", daemon=True)
-        self._worker_impl = worker_impl
+    def __init__(self, worker_class, worker_id, worker_args, input_queue,
+                 result_queue, stop_event, put_fn, prof=None, telemetry=None,
+                 gate=None, heartbeats=None, straggler=None):
+        super().__init__(name=f"pt-worker-{worker_id}", daemon=True)
+        # The worker impl publishes through this thread, so the thread
+        # sees where an item's decode ends and its publish begins.
+        self._worker_impl = worker_impl = worker_class(
+            worker_id, self._publish, worker_args)
         self._input_queue = input_queue
         self._result_queue = result_queue
         self._stop_event = stop_event
@@ -155,10 +159,15 @@ class _WorkerThread(threading.Thread):
         # `pool.w{id}.busy_frac` per worker and the fleet-level
         # `pool.utilization` series from them on BOTH pool backends.
         wid = worker_impl.worker_id
+        self._track = f"worker:{wid}"
         self._c_items = (telemetry.counter(f"pool.w{wid}.items")
                          if telemetry is not None else None)
         self._c_busy = (telemetry.counter(f"pool.w{wid}.busy_s")
                         if telemetry is not None else None)
+        # The open item: its lineage id and its decode span, which the
+        # first publish closes.
+        self._trace = None
+        self._decode = None
 
     def _beat(self):
         if self._heartbeats is not None:
@@ -189,7 +198,6 @@ class _WorkerThread(threading.Thread):
                 self.prof.disable()
 
     def _loop(self):
-        wid = self._worker_impl.worker_id
         while not self._stop_event.is_set():
             try:
                 args, kwargs = self._input_queue.get(block=True, timeout=_IO_TIMEOUT_S)
@@ -197,7 +205,7 @@ class _WorkerThread(threading.Thread):
                 continue
             # Lineage id the reader's ventilate wrapper injected (trace
             # mode); popped so the worker impl's signature never sees it.
-            trace = kwargs.pop("trace_context", None)
+            self._trace = kwargs.pop("trace_context", None)
             # Admission gate: park until a processing slot frees. The item
             # stays ours (round-robin assignment is fixed), so determinism
             # holds; a stop while parked drops the item like any other stop.
@@ -206,26 +214,49 @@ class _WorkerThread(threading.Thread):
             self._beat()
             t0 = time.perf_counter()
             try:
-                if self._decode_hist is not None:
-                    with self._telemetry.span("petastorm_tpu.worker_decode",
-                                              trace=trace, stage="decode",
-                                              track=f"worker:{wid}"):
+                if self._telemetry is not None:
+                    # Item taken -> the item's first publish (_publish
+                    # closes it there): decode WITHOUT the blocked put.
+                    self._decode = traced_span(
+                        "petastorm_tpu.worker_decode", self._telemetry,
+                        trace=self._trace, stage="decode", track=self._track)
+                    with self._decode:
                         self._process_item(args, kwargs)
-                    self._decode_hist.observe(time.perf_counter() - t0)
+                    # One clock pair: the span's, for the histogram and
+                    # the per-worker busy counter too.
+                    self._decode_hist.observe(self._decode.duration_s)
+                    self._c_busy.add(self._decode.duration_s)
+                    self._c_items.add(1)
+                    self._decode = None
                 else:
                     self._process_item(args, kwargs)
             finally:
                 if self._gate is not None:
                     self._gate.release()
-            if self._c_busy is not None:
-                self._c_busy.add(time.perf_counter() - t0)
-                self._c_items.add(1)
-            self._put(VentilatedItemProcessedMessage(
+            self._publish(VentilatedItemProcessedMessage(
                 kwargs.get(ITEM_CONTEXT_KWARG)))
             self._beat()
             if self._straggler is not None:
                 self._straggler.observe(time.perf_counter() - t0,
                                         worker_id=self._worker_impl.worker_id)
+
+    def _publish(self, data):
+        """Hand ``data`` to the consumer. The item's decode ends here; time
+        blocked on the full results queue is ``publish_wait``, a span of
+        its own that feeds no decode counter."""
+        if self._decode is not None:
+            self._decode.close()
+        try:
+            self._result_queue.put_nowait(data)
+            return
+        except queue.Full:
+            pass
+        if self._telemetry is None:
+            self._put(data)
+            return
+        with traced_span("petastorm_tpu.publish_wait", self._telemetry,
+                         trace=self._trace, track=self._track):
+            self._put(data)
 
     def _process_item(self, args, kwargs):
         try:
@@ -234,7 +265,7 @@ class _WorkerThread(threading.Thread):
             # Degraded-mode give-up: the skip record replaces the item's
             # data; the processed marker still follows, so pool accounting
             # treats the item as complete.
-            self._put(RowGroupSkippedMessage(skip.record))
+            self._publish(RowGroupSkippedMessage(skip.record))
 
 
 class ThreadPool:
@@ -308,10 +339,10 @@ class ThreadPool:
             out_q = queue.Queue(maxsize=self._results_queue_size)
             self._input_queues.append(in_q)
             self._result_queues.append(out_q)
-            worker = worker_class(i, self._make_put(i), worker_args)
             per_worker_prof = (cProfile.Profile() if self._profiling_enabled
                                and sys.version_info < (3, 12) else None)
-            self._workers.append(_WorkerThread(worker, in_q, out_q, self._stop_event,
+            self._workers.append(_WorkerThread(worker_class, i, worker_args,
+                                               in_q, out_q, self._stop_event,
                                                self._make_put(i), per_worker_prof,
                                                telemetry=self.telemetry,
                                                gate=self.concurrency_gate,

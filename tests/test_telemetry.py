@@ -77,14 +77,15 @@ def test_histogram_rejects_bad_bounds():
 # SpanRecorder
 # --------------------------------------------------------------------------
 
-def test_recorder_disabled_is_shared_noop():
+def test_recorder_disabled_times_but_skips_the_ring():
+    """``disable()`` is the operator's switch: a span still times itself
+    (its clock pair feeds the site's counters) and the ring stays empty."""
     r = SpanRecorder(enabled=False)
-    # No allocation on the disabled path: same object every call.
-    assert r.span("a") is r.span("b")
-    with r.span("a"):
-        pass
+    with r.span("a") as sp:
+        time.sleep(0.001)
+    assert sp.duration_s >= 0.001
     r.record("direct", 0.0, 1.0)
-    assert r.spans() == []
+    assert r.spans() == [] and r.dropped == 0
 
 
 def test_recorder_records_provenance_and_aggregates():
@@ -120,7 +121,8 @@ def test_recorder_disabled_hot_path_overhead():
     few µs per batch. Measured over 10k no-op spans; the bound is ~50x the
     typical cost so a loaded CI host cannot flake it, while a regression to
     per-call allocation/locking would still blow through it."""
-    registry = TelemetryRegistry(spans_enabled=False)
+    registry = TelemetryRegistry()
+    registry.recorder.disable()
     n = 10_000
     t0 = time.perf_counter()
     for _ in range(n):
@@ -162,7 +164,7 @@ def test_registry_function_gauge_and_dead_gauge():
 
 
 def test_registry_snapshot_schema_and_reset_returns_prior():
-    reg = TelemetryRegistry(spans_enabled=True)
+    reg = TelemetryRegistry()
     reg.counter("n").add(5)
     reg.histogram("lat").observe(0.01)
     reg.gauge("q").set(7)
@@ -306,7 +308,7 @@ def test_stall_mirrors_into_registry():
 # --------------------------------------------------------------------------
 
 def _populated_registry():
-    reg = TelemetryRegistry(spans_enabled=True)
+    reg = TelemetryRegistry()
     reg.counter("loader.batches").add(3)
     reg.counter("loader.host_wait_s").add(0.5)
     reg.gauge("shuffle_buffer.fill").set(42)
@@ -428,7 +430,7 @@ def test_trace_noop_path_is_reentrant(_reset_trace_resolution):
 
 def test_traced_span_mirrors_name_into_recorder(_reset_trace_resolution):
     metrics_mod._TRACE_ANNOTATION = False  # profiler absent: span still lands
-    reg = TelemetryRegistry(spans_enabled=True)
+    reg = TelemetryRegistry()
     with traced_span("petastorm_tpu.stage", reg):
         pass
     assert reg.recorder.spans()[0].name == "petastorm_tpu.stage"
@@ -724,9 +726,9 @@ def test_reader_env_export_writes_snapshot(synthetic_dataset, tmp_path,
     assert snap["counters"]["reader.rows"] == 10
 
 
-def test_spans_env_enables_recorder(synthetic_dataset, monkeypatch):
-    from petastorm_tpu.telemetry import TELEMETRY_SPANS_ENV
-    monkeypatch.setenv(TELEMETRY_SPANS_ENV, "1")
+def test_reader_records_spans_by_default(synthetic_dataset):
+    """No knob: a reader's registry records its span sites from
+    construction."""
     with make_reader(synthetic_dataset.url, schema_fields=["id"],
                      shuffle_row_groups=False,
                      reader_pool_type="dummy") as reader:
@@ -737,10 +739,23 @@ def test_spans_env_enables_recorder(synthetic_dataset, monkeypatch):
     assert spans["petastorm_tpu.pool_wait"]["count"] > 0
 
 
-def test_make_registry_defaults_spans_off(monkeypatch):
-    from petastorm_tpu.telemetry import TELEMETRY_SPANS_ENV
-    monkeypatch.delenv(TELEMETRY_SPANS_ENV, raising=False)
-    assert make_registry().recorder.enabled is False
+def test_make_registry_ring_on_and_bounded():
+    """The default ring holds a measured window whole (four chips at the
+    image cell's rate: ~19.5k spans) and never grows past its capacity."""
+    from petastorm_tpu.telemetry.recorder import SPAN_CAPACITY
+    reg = make_registry()
+    assert reg.recorder.enabled is True
+    assert reg.recorder.capacity == SPAN_CAPACITY >= 32768
+    small = TelemetryRegistry(span_capacity=8)
+    for i in range(20):
+        with small.span(f"s{i}"):
+            pass
+    assert len(small.recorder.spans()) == 8
+    assert small.recorder.dropped == 12
+    small.recorder.disable()
+    with small.span("off"):
+        pass
+    assert small.recorder.dropped == 12
 
 
 # --------------------------------------------------------------------------

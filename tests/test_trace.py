@@ -80,7 +80,7 @@ def test_enable_trace_grows_ring_preserving():
 
 def test_record_remote_anchors_to_local_clock():
     import time
-    rec_reg = TelemetryRegistry(spans_enabled=True)
+    rec_reg = TelemetryRegistry()
     rec = rec_reg.recorder
     now = time.perf_counter()
     rec.record_remote([("petastorm_tpu.worker_decode", "decode", 0.25,
@@ -462,7 +462,7 @@ def test_reader_slo_env_wiring(scalar_store, monkeypatch):
 def test_prometheus_label_escaping_survives_hostile_span_names():
     """Satellite: quotes/backslashes/newlines (a pathological dataset path
     in a span name) must not corrupt the exposition format."""
-    reg = TelemetryRegistry(spans_enabled=True)
+    reg = TelemetryRegistry()
     evil = 'read "/data/ds\\v1\nshard"'
     reg.recorder.record(evil, 0.0, 0.5)
     text = to_prometheus_text(reg.snapshot())
@@ -583,3 +583,417 @@ def test_mesh_trace_acceptance(tmp_path, monkeypatch, capsys):
         assert e["ph"] in ("M", "X", "i")
         if e["ph"] == "X":
             assert e["dur"] >= 0 and "ts" in e
+
+
+# ------------------------------------- one span vocabulary, two sinks
+# (docs/observability.md "Spans"): every hot-path site goes through
+# metrics.traced_span, which records into the ring AND emits a profiler
+# annotation of the same name; spans sit where the work happens.
+@pytest.fixture
+def annotations(monkeypatch):
+    """``jax.profiler.TraceAnnotation`` replaced by a recorder of
+    ``(name, thread)`` per closed annotation."""
+    from petastorm_tpu import metrics
+    seen = []
+
+    class FakeAnnotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            self.thread = threading.current_thread().name
+            return self
+
+        def __exit__(self, *exc):
+            assert threading.current_thread().name == self.thread
+            seen.append((self.name, self.thread))
+            return False
+
+    monkeypatch.setattr(metrics, "_TRACE_ANNOTATION", FakeAnnotation)
+    return seen
+
+
+def _sink_counts(recorder, annotations):
+    """-> (ring, profiler): per-name counts of the locally recorded
+    ``petastorm_tpu.*`` spans and of the annotations."""
+    from collections import Counter
+    ring = Counter(sp.name for sp in recorder.spans()
+                   if sp.thread != "remote" and sp.stage != "ventilate")
+    return ring, Counter(name for name, _ in annotations)
+
+
+def _thread_loader(synthetic_dataset, scalar_store, tmp_path):
+    from petastorm_tpu.jax import DataLoader
+    from petastorm_tpu.reader import make_reader
+    import time
+    with make_reader(synthetic_dataset.url, schema_fields=["id", "matrix"],
+                     num_epochs=2, shuffle_row_groups=False,
+                     reader_pool_type="thread", workers_count=2,
+                     results_queue_size=1) as reader:
+        loader = DataLoader(reader, batch_size=10, prefetch=1)
+        for _ in loader:
+            time.sleep(0.01)    # slow consumer: queues fill, threads park
+        return reader.telemetry.recorder
+
+
+def _dummy_batched_shuffle(synthetic_dataset, scalar_store, tmp_path):
+    from petastorm_tpu.jax import BatchedDataLoader
+    from petastorm_tpu.reader import make_batch_reader
+    with make_batch_reader(scalar_store, num_epochs=1,
+                           reader_pool_type="dummy") as reader:
+        loader = BatchedDataLoader(reader, batch_size=25,
+                                   shuffling_queue_capacity=60, seed=0)
+        assert len(list(loader)) == 8
+        return reader.telemetry.recorder
+
+
+def _readahead(synthetic_dataset, scalar_store, tmp_path):
+    from petastorm_tpu.reader import make_batch_reader
+    with make_batch_reader(scalar_store, num_epochs=1,
+                           reader_pool_type="thread", workers_count=2,
+                           readahead_depth=3) as reader:
+        assert sum(len(b.id) for b in reader) == 200
+        return reader.telemetry.recorder
+
+
+def _process_pool(synthetic_dataset, scalar_store, tmp_path):
+    from petastorm_tpu.reader import make_batch_reader
+    with make_batch_reader(scalar_store, num_epochs=1,
+                           reader_pool_type="process",
+                           workers_count=2) as reader:
+        assert sum(len(b.id) for b in reader) == 200
+        return reader.telemetry.recorder
+
+
+def _mesh(synthetic_dataset, scalar_store, tmp_path):
+    from petastorm_tpu.jax import MeshDataLoader, MeshReaderFactory
+    factory = MeshReaderFactory(scalar_store, batched=True)
+    with MeshDataLoader(factory, batch_size=40, seed=3,
+                        num_epochs=1) as loader:
+        assert sum(len(b["id"]) for b in loader) == 200
+        return loader.telemetry.recorder
+
+
+@pytest.mark.parametrize("scenario,names,exact", [
+    (_thread_loader, ["worker_decode", "publish_wait", "pool_wait",
+                      "collate", "host_batch", "stage", "queue_full", "h2d",
+                      "deliver"], True),
+    (_dummy_batched_shuffle, ["worker_decode", "pool_wait", "shuffle_add",
+                              "shuffle_retrieve", "host_batch", "stage",
+                              "h2d", "deliver"], True),
+    (_readahead, ["fetch", "worker_decode", "pool_wait"], True),
+    pytest.param(_process_pool, ["transport", "pool_wait"], True,
+                 marks=pytest.mark.process_pool),
+    # The per-host readers keep registries of their own; the annotations
+    # are the process's: only the mesh plane's own names compare exactly.
+    pytest.param(_mesh, ["mesh_pull", "mesh_assemble"], False,
+                 marks=pytest.mark.mesh),
+], ids=["thread_loader", "dummy_batched_shuffle", "readahead",
+        "process_pool", "mesh"])
+def test_every_span_site_lands_in_both_sinks_under_one_name(
+        scenario, names, exact, annotations, synthetic_dataset, scalar_store,
+        tmp_path):
+    ring, profiler = _sink_counts(
+        scenario(synthetic_dataset, scalar_store, tmp_path), annotations)
+    for name in names:
+        full = f"petastorm_tpu.{name}"
+        assert ring[full] > 0, (name, dict(ring))
+        assert ring[full] == profiler[full], (name, ring, profiler)
+    if exact:
+        assert ring == profiler
+
+
+def test_spans_nest_on_their_thread_and_feed_the_counters(
+        synthetic_dataset):
+    """``pool_wait`` and ``collate`` are children of the ``host_batch``
+    they ran in; one clock pair feeds a span and its site's counters."""
+    from petastorm_tpu.jax import DataLoader
+    from petastorm_tpu.reader import make_reader
+    with make_reader(synthetic_dataset.url, schema_fields=["id"],
+                     shuffle_row_groups=False, reader_pool_type="thread",
+                     workers_count=2) as reader:
+        loader = DataLoader(reader, batch_size=10)
+        assert len(list(loader)) == 10
+        spans = reader.telemetry.recorder.spans()
+        snap = reader.telemetry.snapshot()
+        metrics = loader.metrics.as_dict()
+    by_name = {}
+    for sp in spans:
+        by_name.setdefault(sp.name.split(".", 1)[1], []).append(sp)
+    batches = {sp.span_id: sp for sp in by_name["host_batch"]}
+    assert all(sp.trace.startswith("b") for sp in batches.values())
+    for child in by_name["pool_wait"] + by_name["collate"]:
+        parent = batches[child.parent_id]
+        assert parent.thread == child.thread == "petastorm-tpu-stage"
+        assert parent.start_s <= child.start_s
+        assert (child.start_s + child.duration_s
+                <= parent.start_s + parent.duration_s)
+    assert {sp.thread for sp in by_name["h2d"]} == {"petastorm-tpu-h2d"}
+    assert {sp.trace for sp in by_name["h2d"]} >= {
+        sp.trace for sp in by_name["deliver"] if sp.trace}
+    decode_s = sum(sp.duration_s for sp in by_name["worker_decode"])
+    assert snap["histograms"]["worker.decode_s"]["sum"] == \
+        pytest.approx(decode_s, abs=1e-5)
+    assert sum(v for k, v in snap["counters"].items()
+               if k.startswith("pool.w") and k.endswith(".busy_s")) == \
+        pytest.approx(decode_s, abs=1e-4)
+    assert metrics["stage_s"] == pytest.approx(
+        sum(sp.duration_s for sp in by_name["stage"]), abs=1e-3)
+
+
+def test_worker_decode_excludes_a_blocked_publish():
+    """Results queue of 1 and a slow consumer: the decode span ends at the
+    item's first publish, the blocked put is ``publish_wait``, and neither
+    ``worker.decode_s`` nor ``pool.w0.busy_s`` counts it."""
+    import time
+    from petastorm_tpu.workers_pool import EmptyResultError
+    from petastorm_tpu.workers_pool.thread_pool import ThreadPool
+    from petastorm_tpu.workers_pool.worker_base import WorkerBase
+
+    planted = []
+
+    class SleepyWorker(WorkerBase):
+        def process(self, n):
+            t0 = time.perf_counter()
+            time.sleep(0.02)                     # the planted decode
+            planted.append(time.perf_counter() - t0)
+            self.publish_func(n)
+
+    reg = TelemetryRegistry()
+    pool = ThreadPool(1, results_queue_size=1)
+    pool.telemetry = reg
+    pool.start(SleepyWorker)
+    for n in range(6):
+        pool.ventilate(n)
+    got = []
+    t0 = time.perf_counter()
+    while len(got) < 6:
+        time.sleep(0.06)                         # the slow consumer
+        got.append(pool.get_results())
+    wall = time.perf_counter() - t0
+    pool.stop()
+    pool.join()
+    assert got == list(range(6))
+    spans = reg.recorder.spans()
+    decode = [sp for sp in spans
+              if sp.name == "petastorm_tpu.worker_decode"]
+    blocked = [sp for sp in spans
+               if sp.name == "petastorm_tpu.publish_wait"]
+    decode_s = sum(sp.duration_s for sp in decode)
+    assert len(decode) == 6 and decode_s <= 1.1 * sum(planted)
+    # The worker's wall is decode + blocked publish: the rest is all there.
+    blocked_s = sum(sp.duration_s for sp in blocked)
+    assert blocked and {sp.thread for sp in blocked} == {"pt-worker-0"}
+    assert decode_s + blocked_s >= 0.8 * wall
+    assert blocked_s >= decode_s
+    snap = reg.snapshot()
+    assert snap["histograms"]["worker.decode_s"]["sum"] == \
+        pytest.approx(decode_s, abs=1e-5)
+    assert snap["counters"]["pool.w0.busy_s"] == \
+        pytest.approx(decode_s, abs=1e-5)
+    assert snap["counters"]["trace.span.decode_s"] == \
+        pytest.approx(decode_s, abs=1e-5)
+    with pytest.raises(EmptyResultError):
+        pool.get_results()
+
+
+def _slow_rows(seconds):
+    import time
+    from petastorm_tpu.transform import TransformSpec
+
+    def slow(row):
+        time.sleep(seconds)
+        return row
+    return TransformSpec(slow)
+
+
+def test_deliver_brackets_what_the_consumer_pays_when_starved(
+        synthetic_dataset):
+    """A sleeping transform starves the loader: the sum of ``deliver``
+    equals, within 5%, what the test itself measures around ``next(it)``
+    (the agreement under starvation PERF.md called not measured), and
+    ``loader.delivery_wait_s`` is fed from those spans."""
+    import time
+    from petastorm_tpu.jax import DataLoader
+    from petastorm_tpu.reader import make_reader
+    with make_reader(synthetic_dataset.url, schema_fields=["id"],
+                     num_epochs=3, shuffle_row_groups=False,
+                     reader_pool_type="thread", workers_count=1,
+                     transform_spec=_slow_rows(0.003)) as reader:
+        loader = DataLoader(reader, batch_size=10)
+        it = iter(loader)
+        next(it)                                  # spin-up
+        waits = []
+        for _ in range(20):
+            t0 = time.perf_counter()
+            next(it)
+            waits.append(time.perf_counter() - t0)
+        delivered = [sp for sp in reader.telemetry.recorder.spans()
+                     if sp.name == "petastorm_tpu.deliver"]
+        it.close()
+        report = loader.stall_report()
+    assert len(delivered) == 21
+    inside = sum(sp.duration_s for sp in delivered[1:])
+    assert sum(waits) > 0.3                       # starved: ~30 ms a batch
+    assert inside == pytest.approx(sum(waits), rel=0.05)
+    assert all(sp.extra["depth"] == 0 for sp in delivered[2:])
+    assert report["steps"] == 20
+    assert report["delivery_wait_s"] == pytest.approx(inside, abs=1e-4)
+    assert report["verdict"] == "host_bound"
+
+
+def _stager_tiling(synthetic_dataset, consumer_s, row_s):
+    """One short run -> (names of the staging thread's top-level spans,
+    the share of its wall they cover, every span)."""
+    import time
+    from petastorm_tpu.jax import DataLoader
+    from petastorm_tpu.reader import make_reader
+    with make_reader(synthetic_dataset.url, schema_fields=["id"],
+                     num_epochs=3, shuffle_row_groups=False,
+                     reader_pool_type="thread", workers_count=2,
+                     transform_spec=_slow_rows(row_s)) as reader:
+        loader = DataLoader(reader, batch_size=10)
+        for n, _ in enumerate(loader):
+            time.sleep(consumer_s)
+            if n == 25:
+                break
+        spans = reader.telemetry.recorder.spans()
+    top = [sp for sp in spans if sp.thread == "petastorm-tpu-stage"
+           and not sp.parent_id][2:-2]
+    wall = (top[-1].start_s + top[-1].duration_s) - top[0].start_s
+    return ({sp.name.split(".", 1)[1] for sp in top},
+            sum(sp.duration_s for sp in top) / wall, spans)
+
+
+@pytest.mark.parametrize("consumer_s,row_s", [(0.03, 0.0), (0.0, 0.003)],
+                         ids=["fed", "starved"])
+def test_staging_threads_spans_tile_its_wall(synthetic_dataset, consumer_s,
+                                             row_s):
+    """``host_batch`` + ``stage`` + ``queue_full`` cover at least 98% of
+    the staging thread's wall, so its busy share can be read off the
+    spans (99.9% in both cells on the chip, PERF.md). What lies between
+    two spans is a few bytecodes; a thread descheduled right there on a
+    loaded test host reads lower, so the best of three short runs
+    counts."""
+    for _ in range(3):
+        names, share, spans = _stager_tiling(synthetic_dataset, consumer_s,
+                                             row_s)
+        assert names <= {"host_batch", "stage", "queue_full"}
+        if consumer_s:      # fed: the stager parks, every transfer landed
+            assert "queue_full" in names
+            assert all(sp.extra["ready"] for sp in spans[40:]
+                       if sp.name == "petastorm_tpu.deliver" and sp.trace)
+        if share >= 0.98:
+            return
+    assert share >= 0.98, (names, share)
+
+
+class _PlantedArray:
+    """Stands in for a staged device array whose transfer takes
+    ``seconds`` (or that was deleted before it was seen ready)."""
+
+    class sharding:
+        device_set = (0,)
+
+    def __init__(self, seconds, deleted=False):
+        self.seconds, self.deleted = seconds, deleted
+        self.nbytes = 8
+
+    def block_until_ready(self):
+        import time
+        if self.deleted:
+            raise RuntimeError("Array has been deleted with shape=int64[8].")
+        time.sleep(self.seconds)
+
+    def is_ready(self):
+        return False
+
+
+def test_h2d_closes_off_the_staging_thread(synthetic_dataset, monkeypatch):
+    """With a planted slow readiness the ``h2d`` span of ``b{n}`` covers
+    it, and does not serialise the stager: ``collate`` of ``b{n+1}``
+    starts before ``h2d`` of ``b{n}`` ends. A deleted array closes it."""
+    from petastorm_tpu.jax import DataLoader
+    from petastorm_tpu.reader import make_reader
+    with make_reader(synthetic_dataset.url, schema_fields=["id"],
+                     shuffle_row_groups=False,
+                     reader_pool_type="dummy") as reader:
+        loader = DataLoader(reader, batch_size=10, prefetch=4)
+        staged = iter(range(100))
+        monkeypatch.setattr(
+            loader, "_stage", lambda hb: {"x": _PlantedArray(
+                0.05, deleted=next(staged) == 3)})
+        assert len(list(loader)) == 10
+        spans = reader.telemetry.recorder.spans()
+    h2d = {sp.trace: sp for sp in spans if sp.name == "petastorm_tpu.h2d"}
+    batch_of = {sp.span_id: sp.trace for sp in spans
+                if sp.name == "petastorm_tpu.host_batch"}
+    collate = {batch_of[sp.parent_id]: sp for sp in spans
+               if sp.name == "petastorm_tpu.collate"}
+    stage = {sp.trace: sp for sp in spans
+             if sp.name == "petastorm_tpu.stage"}
+    assert len(h2d) == 10
+    # handed over while the (planted) transfer was still in flight
+    assert not any(sp.extra["ready"] for sp in spans
+                   if sp.name == "petastorm_tpu.deliver"
+                   and sp.trace in ("b1", "b2", "b3"))
+    assert h2d["b4"].extra.get("deleted") is True
+    for n in (1, 2, 3):
+        this, following = h2d[f"b{n}"], collate[f"b{n + 1}"]
+        assert this.duration_s >= 0.05 and "deleted" not in this.extra
+        assert this.extra == {"bytes": 0, "shards": 1}
+        # it opens where stage closed, on the stager's clock pair
+        assert this.start_s == pytest.approx(
+            stage[f"b{n}"].start_s + stage[f"b{n}"].duration_s, abs=1e-9)
+        assert following.start_s < this.start_s + this.duration_s
+
+
+def test_anchor_places_the_ring_on_unix_nanoseconds():
+    import time
+    from petastorm_tpu.telemetry import SpanRecorder
+    perf_ns, unix_ns = SpanRecorder.anchor()
+    with SpanRecorder().span("x") as sp:
+        now_ns = time.time_ns()
+    mapped = sp.start_s * 1e9 - perf_ns + unix_ns
+    assert abs(mapped - now_ns) < 1e6      # within 1 ms
+
+
+# --------------------------------------------- tools/check_spans.py lint
+def _lint(tmp_path, body, required):
+    from tools import check_spans
+    (tmp_path / "mod.py").write_text(body)
+    return check_spans.check_file("mod.py", required, str(tmp_path))
+
+
+def test_check_spans_registry_matches_the_code():
+    from tools import check_spans
+    assert check_spans.main([]) == 0
+    sites = {name for fns in check_spans.ENTRY_POINTS.values()
+             for names in fns.values() for name in names}
+    assert {"worker_decode", "publish_wait", "pool_wait", "collate",
+            "host_batch", "stage", "queue_full", "h2d", "deliver", "fetch",
+            "transport", "shuffle_add", "shuffle_retrieve", "mesh_pull",
+            "mesh_assemble"} <= sites
+
+
+@pytest.mark.parametrize("body,fault", [
+    ("def f(t):\n    with t.span('petastorm_tpu.stage'):\n        pass\n",
+     "must open the span 'petastorm_tpu.stage' through traced_span"),
+    ("def f(t):\n    with traced_span('petastorm_tpu.other', t):\n"
+     "        pass\n", "must open the span 'petastorm_tpu.stage'"),
+    ("def g(t):\n    pass\n", "entry point f not found"),
+    ("def f(t, rows):\n    with traced_span('petastorm_tpu.stage', t):\n"
+     "        pass\n    for row in rows:\n"
+     "        with traced_span('petastorm_tpu.x', t):\n            pass\n",
+     "inside a per-row loop"),
+], ids=["ring_only", "other_name", "out_of_sync", "per_row"])
+def test_check_spans_flags(tmp_path, body, fault):
+    violations = _lint(tmp_path, body, {"f": ["stage"]})
+    assert len(violations) == 1 and fault in violations[0], violations
+
+
+def test_check_spans_passes_a_site_in_both_sinks(tmp_path):
+    body = ("def f(t):  \n    with traced_span('petastorm_tpu.stage', t):\n"
+            "        pass\n"
+            "def w(t):  # span-ok: spans in its caller\n    pass\n")
+    assert _lint(tmp_path, body, {"f": ["stage"], "w": ["stage"]}) == []

@@ -58,7 +58,7 @@ def _wall_clock_calls(tree: ast.AST, from_time_aliases: set):
         if not isinstance(node, ast.Call):
             continue
         fn = node.func
-        if (isinstance(fn, ast.Attribute) and fn.attr == "time"
+        if (isinstance(fn, ast.Attribute) and fn.attr in ("time", "time_ns")
                 and isinstance(fn.value, ast.Name) and fn.value.id == "time"):
             yield node
         elif isinstance(fn, ast.Name) and fn.id in from_time_aliases:
@@ -71,7 +71,7 @@ def _from_time_aliases(tree: ast.AST) -> set:
     for node in ast.walk(tree):
         if isinstance(node, ast.ImportFrom) and node.module == "time":
             for alias in node.names:
-                if alias.name == "time":
+                if alias.name in ("time", "time_ns"):
                     aliases.add(alias.asname or alias.name)
     return aliases
 
