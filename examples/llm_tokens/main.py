@@ -51,8 +51,7 @@ def train(url: str, steps: int = 40, batch_size: int = 8, window: int = 4,
     # keeps off at its toy scale: make_train_step(xent_chunk=...) (chunked
     # cross-entropy, no (b, s, V) logits), remat_layers=True (per-layer
     # jax.checkpoint), and attn_fn=make_flash_attention() (O(seq) memory)
-    # — together they train 128k-token windows on one 16 GB chip
-    # (docs/performance.md, "single-chip context ceiling").
+    # (see the docstrings of llama.loss_fn and llama.apply).
     cfg = llama.LlamaConfig(vocab=vocab, dim=128, n_layers=2, n_heads=8,
                             n_kv_heads=4, hidden=256)
     params = llama.init_params(jax.random.PRNGKey(0), cfg)

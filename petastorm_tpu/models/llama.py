@@ -344,6 +344,73 @@ def apply(params, tokens, cfg: LlamaConfig, attn_fn=None,
     return (logits, aux) if with_aux else logits
 
 
+def _head_xent_chunks(xf, head, targets, weights, with_grads):
+    """One ``lax.scan`` over token chunks of the weighted cross-entropy
+    ``sum(weights * (logsumexp(x @ head) - (x @ head)[target]))``.
+
+    xf: (n_chunks, chunk, dim) hidden states in the compute dtype; head:
+    (dim, vocab) master weights; targets, weights: (n_chunks, chunk).
+    With ``with_grads`` the same iteration that forms a chunk's logits
+    also forms its ``d loss / d logits`` and multiplies it out, so the
+    head product is never recomputed: returns ``(loss, dxf, dhead)``
+    (``dhead`` a float32 carry), else ``loss`` alone.
+    """
+    head_c = head.astype(xf.dtype)
+    vocab_ids = jnp.arange(head.shape[1])
+
+    def chunk(carry, args):
+        loss, dhead = carry
+        xc, tc, wc = args
+        logits = jax.lax.dot_general(xc, head_c, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        tl = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
+        loss = loss + jnp.sum(wc * (lse - tl))
+        if not with_grads:
+            return (loss, dhead), None
+        # softmax - onehot, weighted, rounded to the compute dtype: what
+        # autodiff's cotangent of ``.astype(float32)`` is.
+        dlogits = ((jnp.exp(logits - lse[:, None])
+                    - (vocab_ids == tc[:, None])) * wc[:, None]).astype(xc.dtype)
+        # Both products below read dlogits. Without the barrier XLA fuses
+        # the exp/onehot arithmetic into each of them and derives dlogits
+        # twice from the float32 logits (ledger, PR 27 probes: 605.7 ms a
+        # step of the token cell against 593.9 with it).
+        dlogits = jax.lax.optimization_barrier(dlogits)
+        dxc = jax.lax.dot_general(dlogits, head_c, (((1,), (1,)), ((), ())))
+        dhead = dhead + jax.lax.dot_general(
+            xc, dlogits, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return (loss, dhead), dxc
+
+    dhead0 = jnp.zeros(head.shape if with_grads else (), jnp.float32)
+    (loss, dhead), dxf = jax.lax.scan(
+        chunk, (jnp.zeros((), jnp.float32), dhead0), (xf, targets, weights))
+    return (loss, dxf, dhead) if with_grads else loss
+
+
+@jax.custom_vjp
+def _chunked_xent(xf, head, targets, weights):
+    """Weighted chunked cross-entropy (see :func:`_head_xent_chunks`).
+    Outside ``grad`` only the forward product runs."""
+    return _head_xent_chunks(xf, head, targets, weights, with_grads=False)
+
+
+def _chunked_xent_fwd(xf, head, targets, weights):
+    loss, dxf, dhead = _head_xent_chunks(xf, head, targets, weights,
+                                         with_grads=True)
+    return loss, (dxf, dhead.astype(head.dtype))
+
+
+def _chunked_xent_bwd(residuals, g):
+    dxf, dhead = residuals
+    return ((g * dxf).astype(dxf.dtype), (g * dhead).astype(dhead.dtype),
+            None, None)
+
+
+_chunked_xent.defvjp(_chunked_xent_fwd, _chunked_xent_bwd)
+
+
 def loss_fn(params, batch, cfg: LlamaConfig, attn_fn=None, activation_spec=None,
             expert_spec=None, aux_weight: float = 1e-2, layers_fn=None,
             embed_lookup: str = "gather", compute_dtype=jnp.bfloat16,
@@ -366,53 +433,52 @@ def loss_fn(params, batch, cfg: LlamaConfig, attn_fn=None, activation_spec=None,
       stays divisible by the mesh seq axis end to end, whereas split mode
       would need an s = multiple-of-sp **plus one** window that cannot be
       device_put evenly.
+
+    ``xent_chunk`` (must divide ``batch * model seq``) computes the loss
+    head ``xent_chunk`` tokens at a time in one loop that, under ``grad``,
+    also yields the hidden states' and the head's gradients: the
+    ``(b, s, V)`` logits never exist, peak logit memory is
+    O(``xent_chunk`` x V), plus one float32 ``(dim, vocab)`` accumulator
+    for the head's gradient (537 MB at 4096 x 32768). ``None`` keeps the
+    full-logits form.
     """
     tokens = batch["tokens"]
     if shift not in ("split", "roll"):
         raise ValueError(f"unknown shift {shift!r}")
     inputs = tokens if shift == "roll" else tokens[:, :-1]
     if xent_chunk:
-        # Long-context path: never materialize the (b, s, V) logits. The
-        # lm_head matmul + logsumexp run per token chunk under
-        # jax.checkpoint, so fwd AND bwd peak at O(chunk * V) logit
-        # memory — at 32k context and 32k vocab the full tensor is
-        # ~4.2 GB f32 (plus its cotangent), which alone decides whether
-        # a single 16 GB chip can train. Measured slower than the fused
-        # full-logits form at 4k (recompute cost > memory savings),
-        # so it stays opt-in for the long-context regime.
+        # Never materialize the (b, s, V) logits: at 32k context and 32k
+        # vocab the full tensor is ~4.2 GB f32 (plus its cotangent), which
+        # alone decides whether a single 16 GB chip can train. One scan over
+        # token chunks (_chunked_xent) forms each chunk's logits once and,
+        # under grad, its dx and dhead in the same iteration: three head
+        # products a step, O(chunk * V) logit memory, a float32 dhead carry.
+        # Recomputing the logits in a second, backward loop (autodiff of a
+        # checkpointed chunk body) costs a fourth product: 620.23 against
+        # 593.79 ms a step at Mistral-7B widths, 16k tokens, chunk 2048
+        # (ledger, PR 27, mistral7b-tok4k-1chip, resident_step_ms.tokens).
         x, aux = apply(params, inputs, cfg, attn_fn=attn_fn,
                        activation_spec=activation_spec,
                        expert_spec=expert_spec, with_aux=True,
                        layers_fn=layers_fn, embed_lookup=embed_lookup,
                        compute_dtype=compute_dtype, return_hidden=True,
                        remat_layers=remat_layers)
+        b, s, dm = x.shape
         if shift == "roll":
             targets = jnp.roll(tokens, -1, axis=1)
-            mask = (jnp.arange(tokens.shape[1]) < tokens.shape[1] - 1)
-            denom = mask.sum() * tokens.shape[0]
+            mask = (jnp.arange(s) < s - 1).astype(jnp.float32)
         else:
             targets = tokens[:, 1:]
-            mask = jnp.ones((inputs.shape[1],), bool)
-            denom = targets.size
-        b, s, dm = x.shape
-        head = params["lm_head"]
+            mask = jnp.ones((s,), jnp.float32)
         n_tok = b * s
         if n_tok % xent_chunk:
             raise ValueError(f"xent_chunk ({xent_chunk}) must divide "
                              f"batch*seq ({n_tok})")
-        xf = x.reshape(n_tok // xent_chunk, xent_chunk, dm)
-        tg = targets.reshape(n_tok // xent_chunk, xent_chunk)
-
-        @jax.checkpoint
-        def chunk_nll(args):
-            xc, tc = args
-            logits = (xc @ head.astype(xc.dtype)).astype(jnp.float32)
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            tl = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
-            return lse - tl
-
-        nll_tok = jax.lax.map(chunk_nll, (xf, tg)).reshape(b, s)
-        nll = (nll_tok * mask).sum() / denom
+        # The weights carry the roll mask and the mean's denominator.
+        weights = jnp.broadcast_to(mask / (mask.sum() * b), (b, s))
+        chunks = (n_tok // xent_chunk, xent_chunk)
+        nll = _chunked_xent(x.reshape(*chunks, dm), params["lm_head"],
+                            targets.reshape(chunks), weights.reshape(chunks))
         return nll + aux_weight * aux
     logits, aux = apply(params, inputs, cfg, attn_fn=attn_fn,
                         activation_spec=activation_spec,

@@ -205,6 +205,149 @@ def test_llama_chunked_xent_matches_full_loss():
                       xent_chunk=5)
 
 
+def _llama_grads(params, tokens, cfg, scale=1.0, **kw):
+    return jax.grad(lambda p: scale * llama.loss_fn(
+        p, {"tokens": tokens}, cfg, **kw))(params)
+
+
+def _max_rel_gap(a, b):
+    a = np.asarray(a, np.float32)
+    b = np.asarray(b, np.float32)
+    return float(np.max(np.abs(a - b)) / (np.max(np.abs(a)) + 1e-9))
+
+
+# (shift, tokens per row, chunk): 2 rows, so model seq is 8 either way; a
+# chunk of 4 splits each row in two, a chunk of 16 is all tokens at once.
+@pytest.mark.parametrize("compute_dtype,tol", [("float32", 1e-5),
+                                               ("bfloat16", 3e-2)])
+@pytest.mark.parametrize("shift,window,chunk", [("roll", 8, 4), ("split", 9, 4),
+                                                ("roll", 8, 16)])
+def test_llama_chunked_xent_grads_match_full_logits(shift, window, chunk,
+                                                    compute_dtype, tol):
+    """Every leaf's gradient through the one-pass chunked head (its own
+    dx / dhead, no autodiff) against autodiff of the full-logits path."""
+    cfg = llama.TINY
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, window), 0, cfg.vocab)
+    kw = dict(shift=shift, aux_weight=0.0, compute_dtype=jnp.dtype(compute_dtype))
+    full = _llama_grads(params, tokens, cfg, **kw)
+    chunked = _llama_grads(params, tokens, cfg, xent_chunk=chunk, **kw)
+    paths = jax.tree_util.tree_flatten_with_path(full)[0]
+    assert len(paths) == len(jax.tree.leaves(chunked))
+    for (path, a), b in zip(paths, jax.tree.leaves(chunked)):
+        assert a.dtype == b.dtype and a.shape == b.shape, path
+        assert _max_rel_gap(a, b) < tol, jax.tree_util.keystr(path)
+
+
+def test_llama_chunked_xent_roll_mask_position_gets_zero_gradient():
+    """shift="roll" masks the wraparound target out of the mean: the hidden
+    state at each row's last position gets no gradient from the head, every
+    other position does, and dhead is the sum over the unmasked ones only."""
+    rng = np.random.default_rng(0)
+    b, s, dm, vocab = 2, 6, 16, 48
+    x = jnp.asarray(rng.normal(size=(b, s, dm)), jnp.float32)
+    head = jnp.asarray(rng.normal(size=(dm, vocab)), jnp.float32)
+    targets = jnp.asarray(rng.integers(0, vocab, (b, s)), jnp.int32)
+    mask = (jnp.arange(s) < s - 1).astype(jnp.float32)
+    weights = jnp.broadcast_to(mask / (mask.sum() * b), (b, s))
+
+    def chunked(x, head):
+        return llama._chunked_xent(x.reshape(3, 4, dm), head,
+                                   targets.reshape(3, 4), weights.reshape(3, 4))
+
+    def plain(x, head):
+        logp = jax.nn.log_softmax(x @ head)
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        return (nll * weights).sum()
+
+    dx, dhead = jax.grad(chunked, argnums=(0, 1))(x, head)
+    assert not np.any(np.asarray(dx[:, -1]))
+    assert np.all(np.any(np.asarray(dx[:, :-1]) != 0, axis=-1))
+    dx_ref, dhead_ref = jax.grad(plain, argnums=(0, 1))(x, head)
+    np.testing.assert_allclose(dx, dx_ref, rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(dhead, dhead_ref, rtol=1e-5, atol=1e-7)
+    assert float(chunked(x, head)) == pytest.approx(float(plain(x, head)),
+                                                    rel=1e-6)
+
+
+def test_llama_chunked_xent_scales_with_the_cotangent():
+    """The backward rule scales the stored (dx, dhead) by the incoming
+    cotangent: grad of 2.5 * loss is 2.5 * grad of loss on every leaf."""
+    cfg = llama.TINY
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0, cfg.vocab)
+    kw = dict(shift="roll", aux_weight=0.0, compute_dtype=jnp.float32,
+              xent_chunk=4)
+    g1 = _llama_grads(params, tokens, cfg, **kw)
+    g2 = _llama_grads(params, tokens, cfg, scale=2.5, **kw)
+    assert float(jnp.max(jnp.abs(g1["lm_head"]))) > 0
+    for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
+        assert _max_rel_gap(2.5 * np.asarray(a), b) < 1e-5
+
+
+def _count_vocab_products_and_loops(jaxpr, vocab):
+    """(dot_generals with the vocabulary axis on an operand or the result,
+    scan/while loops) over a jaxpr and every jaxpr nested in its equations."""
+    products = loops = 0
+    for eqn in jaxpr.eqns:
+        products += eqn.primitive.name == "dot_general" and any(
+            vocab in v.aval.shape for v in (*eqn.invars, *eqn.outvars))
+        loops += eqn.primitive.name in ("scan", "while")
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            p, l = _count_vocab_products_and_loops(sub, vocab)
+            products, loops = products + p, loops + l
+    return products, loops
+
+
+@pytest.mark.parametrize("shift,window", [("roll", 8), ("split", 9)])
+def test_llama_chunked_xent_grad_is_one_loop_of_three_head_products(shift,
+                                                                    window):
+    """The mechanism engages at trace time: under grad the head is one loop
+    holding logits, dx and dhead (the checkpointed lax.map it replaces
+    traced two loops and four products); outside grad the loop holds the
+    logits product alone and returns the same value."""
+    cfg = llama.TINY  # vocab 256: no other axis of the model has that size
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, window),
+                                          0, cfg.vocab)}
+
+    def loss(p):
+        return llama.loss_fn(p, batch, cfg, shift=shift, xent_chunk=4)
+
+    assert _count_vocab_products_and_loops(
+        jax.make_jaxpr(jax.grad(loss))(params).jaxpr, cfg.vocab) == (3, 1)
+    assert _count_vocab_products_and_loops(
+        jax.make_jaxpr(loss)(params).jaxpr, cfg.vocab) == (1, 1)
+    value, _ = jax.value_and_grad(loss)(params)
+    assert float(loss(params)) == pytest.approx(float(value), rel=1e-6)
+
+
+def test_llama_chunked_xent_keeps_the_moe_aux_term():
+    """A switch-dispatch MoE config has aux > 0: the chunked loss still adds
+    aux_weight * aux, and the router gets its gradient through it."""
+    import dataclasses
+
+    cfg = dataclasses.replace(llama.TINY, n_experts=4, moe_every=1,
+                              moe_dispatch="switch")
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0,
+                                          cfg.vocab)}
+    kw = dict(shift="roll", compute_dtype=jnp.float32)
+    _, aux = llama.apply(params, batch["tokens"], cfg, with_aux=True,
+                         compute_dtype=jnp.float32)
+    assert float(aux) > 0
+    bare = float(llama.loss_fn(params, batch, cfg, aux_weight=0.0,
+                               xent_chunk=4, **kw))
+    with_aux = float(llama.loss_fn(params, batch, cfg, aux_weight=0.5,
+                                   xent_chunk=4, **kw))
+    assert with_aux == pytest.approx(bare + 0.5 * float(aux), rel=1e-6)
+    full = _llama_grads(params, batch["tokens"], cfg, aux_weight=0.5, **kw)
+    chunked = _llama_grads(params, batch["tokens"], cfg, aux_weight=0.5,
+                           xent_chunk=4, **kw)
+    for a, b in zip(jax.tree.leaves(full), jax.tree.leaves(chunked)):
+        assert _max_rel_gap(a, b) < 1e-5
+
+
 def test_llama_remat_layers_matches_no_remat():
     """remat_layers wraps each block in jax.checkpoint — the long-context
     memory lever; loss and grads must be identical (checkpoint recompute
