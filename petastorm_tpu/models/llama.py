@@ -2,6 +2,11 @@
 (BASELINE config 5), built TPU-first:
 
 * RMSNorm (float32 stats), RoPE, grouped-query attention, SwiGLU MLP;
+* one block function built from (attention kind x FFN kind): per layer,
+  rotary positions or none and full or sliding-window attention
+  (``rope_layout`` / ``sliding_window_layout``); per model, a dense MLP,
+  the ``soft`` / ``switch`` expert paths, or the dropless top-k expert
+  layer that is told which experts it holds (``n_router_outputs``);
 * bfloat16 activations, float32 master params;
 * **3-D parallelism layout**: batch on ``data``, sequence on ``seq``
   (ring attention over the ICI ring — :mod:`petastorm_tpu.parallel.ring_attention`),
@@ -31,6 +36,12 @@ class LlamaConfig:
     hidden: int = 14336
     rope_theta: float = 500000.0
     norm_eps: float = 1e-5
+    # Standard deviation of the embedding's init. With the 0.02 default a
+    # deep stack of RANDOM weights is soon dominated by what its blocks
+    # add, part of it common to all tokens; a router that reads the stream
+    # (n_router_outputs) then sends most tokens to one expert. 1.0 keeps
+    # the stream token-specific and the experts' load near uniform.
+    embed_std: float = 0.02
     # Mixture-of-experts: every ``moe_every``-th layer uses ``n_experts``
     # soft-mixture experts (0 = dense MLP everywhere). Expert weights carry a
     # leading expert axis that param_shardings places on the model axis —
@@ -44,10 +55,73 @@ class LlamaConfig:
     moe_dispatch: str = "soft"
     moe_top_k: int = 1
     moe_capacity_factor: float = 1.25
+    # Width of one head; None = dim // n_heads (models whose heads are not
+    # hidden / heads wide state it: 28 x 128 on a hidden size of 2560).
+    head_dim: Optional[int] = None
+    # Per-layer attention kind, as the source configs write it: layer l has
+    # rotary positions where rope_layout[l] (None = every layer) and sees
+    # only its own and the sliding_window - 1 keys before it where
+    # sliding_window_layout[l] (None = full causal attention everywhere).
+    rope_layout: Optional[tuple] = None
+    sliding_window_layout: Optional[tuple] = None
+    sliding_window: Optional[int] = None
+    # Dropless top-k expert FFN in every layer (n_router_outputs > 0): the
+    # router scores all n_router_outputs experts, softmax over the top_k
+    # selected; of them this shard holds experts_held = (first, count) and
+    # computes their part of the result, no assignment dropped
+    # (:func:`_dropless_moe_block`). router_input: "layer_input" routes on
+    # the block's input, before attention; "mlp_norm" on the normed
+    # post-attention stream.
+    n_router_outputs: int = 0
+    top_k: int = 1
+    experts_held: Optional[tuple] = None
+    expert_hidden: int = 0
+    expert_act: str = "silu"
+    router_input: str = "mlp_norm"
 
-    @property
-    def head_dim(self) -> int:
-        return self.dim // self.n_heads
+    def __post_init__(self):
+        if self.head_dim is None:
+            object.__setattr__(self, "head_dim", self.dim // self.n_heads)
+        for name in ("rope_layout", "sliding_window_layout"):
+            layout = getattr(self, name)
+            if layout is not None:
+                if len(layout) != self.n_layers:
+                    raise ValueError(f"{name} has {len(layout)} entries for "
+                                     f"{self.n_layers} layers")
+                object.__setattr__(self, name, tuple(bool(v) for v in layout))
+        if self.sliding_window_layout and any(self.sliding_window_layout) \
+                and not self.sliding_window:
+            raise ValueError("sliding_window_layout needs sliding_window")
+        if self.n_router_outputs:
+            if self.n_experts:
+                raise ValueError("n_router_outputs (dropless experts) and "
+                                 "n_experts (soft / switch) exclude each other")
+            if self.experts_held is None:
+                object.__setattr__(self, "experts_held",
+                                   (0, self.n_router_outputs))
+            first, count = self.experts_held
+            if not (0 <= first and count >= 1
+                    and first + count <= self.n_router_outputs
+                    and 1 <= self.top_k <= self.n_router_outputs
+                    and self.expert_hidden > 0):
+                raise ValueError(f"bad expert layer: held {self.experts_held} "
+                                 f"of {self.n_router_outputs}, top "
+                                 f"{self.top_k}, width {self.expert_hidden}")
+            if self.expert_act not in _EXPERT_ACTS:
+                raise ValueError(f"unknown expert_act {self.expert_act!r}")
+            if self.router_input not in ("layer_input", "mlp_norm"):
+                raise ValueError(f"unknown router_input {self.router_input!r}")
+
+    def attention_kind(self, layer_idx: int) -> tuple:
+        """``(rope, window)`` of layer ``layer_idx``: whether it rotates q
+        and k, and its sliding window (None = full causal attention)."""
+        rope = self.rope_layout is None or self.rope_layout[layer_idx]
+        windowed = (self.sliding_window_layout is not None
+                    and self.sliding_window_layout[layer_idx])
+        return rope, (self.sliding_window if windowed else None)
+
+
+_EXPERT_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 TINY = LlamaConfig(vocab=256, dim=64, n_layers=2, n_heads=8, n_kv_heads=4,
@@ -61,7 +135,8 @@ def init_params(rng_key, cfg: LlamaConfig):
         return jax.random.normal(key, (fan_in, fan_out), jnp.float32) / np.sqrt(fan_in)
 
     params = {
-        "embed": jax.random.normal(next(keys), (cfg.vocab, cfg.dim), jnp.float32) * 0.02,
+        "embed": jax.random.normal(next(keys), (cfg.vocab, cfg.dim),
+                                   jnp.float32) * cfg.embed_std,
         "layers": [],
         "norm_out": jnp.ones((cfg.dim,), jnp.float32),
         "lm_head": mat(next(keys), cfg.dim, cfg.vocab),
@@ -76,16 +151,21 @@ def init_params(rng_key, cfg: LlamaConfig):
             "wo": mat(next(keys), cfg.n_heads * hd, cfg.dim),
             "mlp_norm": jnp.ones((cfg.dim,), jnp.float32),
         }
-        if _is_moe_layer(cfg, li):
-            E = cfg.n_experts
+        if cfg.n_router_outputs or _is_moe_layer(cfg, li):
+            # Dropless: the router keeps its full width, the experts are
+            # the ``count`` held here at their own width.
+            n_out, E, width = (
+                (cfg.n_router_outputs, cfg.experts_held[1], cfg.expert_hidden)
+                if cfg.n_router_outputs
+                else (cfg.n_experts, cfg.n_experts, cfg.hidden))
             k1, k2, k3, k4 = jax.random.split(next(keys), 4)
-            layer["router"] = jax.random.normal(k1, (cfg.dim, E), jnp.float32) * 0.02
-            layer["ew1"] = jax.random.normal(k2, (E, cfg.dim, cfg.hidden),
+            layer["router"] = jax.random.normal(k1, (cfg.dim, n_out), jnp.float32) * 0.02
+            layer["ew1"] = jax.random.normal(k2, (E, cfg.dim, width),
                                              jnp.float32) / np.sqrt(cfg.dim)
-            layer["ew3"] = jax.random.normal(k3, (E, cfg.dim, cfg.hidden),
+            layer["ew3"] = jax.random.normal(k3, (E, cfg.dim, width),
                                              jnp.float32) / np.sqrt(cfg.dim)
-            layer["ew2"] = jax.random.normal(k4, (E, cfg.hidden, cfg.dim),
-                                             jnp.float32) / np.sqrt(cfg.hidden)
+            layer["ew2"] = jax.random.normal(k4, (E, width, cfg.dim),
+                                             jnp.float32) / np.sqrt(width)
         else:
             layer["w1"] = mat(next(keys), cfg.dim, cfg.hidden)   # gate
             layer["w3"] = mat(next(keys), cfg.dim, cfg.hidden)   # up
@@ -125,8 +205,9 @@ def _param_pspec_tuples(cfg: LlamaConfig, model_axis):
     }
     return {
         "embed": (m, None),     # vocab-sharded embedding
-        "layers": [dict(moe_layer) if _is_moe_layer(cfg, li) else dict(dense_layer)
-                   for li in range(cfg.n_layers)],
+        "layers": [dict(moe_layer)
+                   if cfg.n_router_outputs or _is_moe_layer(cfg, li)
+                   else dict(dense_layer) for li in range(cfg.n_layers)],
         "norm_out": (),
         "lm_head": (None, m),
     }
@@ -198,9 +279,136 @@ def _moe_block(h, layer):
     return jnp.einsum("besd,bse->bsd", expert_out, probs)
 
 
-def _dense_causal_attention(q, k, v):
-    from petastorm_tpu.parallel.attention import dense_attention
-    return dense_attention(q, k, v, causal=True)
+def _buffer_rows(x, perm):
+    """Token rows ``x`` (T, d) into the expert buffer (T k, d): buffer row
+    ``r`` is assignment ``perm[r]``, which is token ``perm[r] // k``'s
+    (``k = 1``: the rows permuted)."""
+    return x[perm // (perm.shape[0] // x.shape[0])]
+
+
+def _token_sums(rows, inv, n_tok: int):
+    """The transpose of :func:`_buffer_rows`: each token's sum (float32)
+    over the buffer rows of its ``k`` assignments."""
+    rows = rows[inv].reshape(n_tok, inv.shape[0] // n_tok, rows.shape[-1])
+    return jnp.sum(rows, axis=1, dtype=jnp.float32).astype(rows.dtype)
+
+
+@jax.custom_vjp
+def _to_buffer(x, perm, inv):
+    """:func:`_buffer_rows` whose backward is :func:`_token_sums`: the
+    transpose of a gather is a scatter-add, but ``perm`` is a permutation
+    with inverse ``inv``, so both directions gather and neither scatters."""
+    return _buffer_rows(x, perm)
+
+
+def _to_buffer_fwd(x, perm, inv):
+    return _buffer_rows(x, perm), (perm, inv, x.shape[0])
+
+
+def _to_buffer_bwd(residuals, g):
+    perm, inv, n_tok = residuals
+    return _token_sums(g, inv, n_tok), None, None
+
+
+_to_buffer.defvjp(_to_buffer_fwd, _to_buffer_bwd)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _from_buffer(rows, perm, inv, n_tok):
+    """:func:`_token_sums` whose backward is :func:`_buffer_rows`."""
+    return _token_sums(rows, inv, n_tok)
+
+
+def _from_buffer_fwd(rows, perm, inv, n_tok):
+    return _token_sums(rows, inv, n_tok), (perm, inv)
+
+
+def _from_buffer_bwd(n_tok, residuals, g):
+    perm, inv = residuals
+    return _buffer_rows(g, perm), None, None
+
+
+_from_buffer.defvjp(_from_buffer_fwd, _from_buffer_bwd)
+
+MOE_STATS = ("rows_held", "load_max", "rows_buffer")
+
+
+def _dropless_moe_block(route_x, h, layer, cfg: LlamaConfig):
+    """Top-k expert FFN over the experts this shard holds, no assignment
+    dropped -> ``(out (b, s, d), stats)``.
+
+    The router scores all ``n_router_outputs`` experts from ``route_x``
+    (float32), the ``top_k`` selected logits are softmaxed, and of the
+    ``tokens x top_k`` assignments those to experts ``first .. first +
+    count - 1`` are computed here: ``sum_e w_e * (act(h Wg_e) * (h Wu_e))
+    Wd_e`` over the held ``e`` a token chose. What the experts held
+    elsewhere would add is their shard's to compute (the partial sums meet
+    in the expert-parallel exchange, which one shard runs without).
+
+    The assignments are sorted by expert with the held experts' rows
+    leading, into ONE static buffer of ``tokens x top_k`` rows: whatever
+    the imbalance, every assignment has its row. The three products are
+    ``jax.lax.ragged_dot`` over the held experts' group sizes (its
+    transposes give dx and dW); rows past the last held group belong to no
+    group and are masked to zero, going in and coming out. ``stats``: int32
+    scalars, the rows routed to held experts, the largest held expert's
+    rows, and the buffer's rows (:data:`MOE_STATS`).
+    """
+    b, s, d = h.shape
+    n_tok, k = b * s, cfg.top_k
+    first, count = cfg.experts_held
+    n_rows = n_tok * k
+    with jax.named_scope("petastorm_tpu.moe_route"):
+        logits = jnp.dot(route_x.reshape(n_tok, d).astype(jnp.float32),
+                         layer["router"],
+                         precision=jax.lax.Precision.HIGHEST)    # (T, n_out)
+        top, ids = jax.lax.top_k(logits, k)
+        weights = jax.nn.softmax(top, axis=-1)                   # (T, k)
+        # Held experts sort first, in order; the rest follow.
+        key = ((ids - first) % cfg.n_router_outputs).reshape(n_rows)
+        perm = jnp.argsort(key, stable=True)   # buffer row -> assignment
+        inv = jnp.argsort(perm)                # assignment -> buffer row
+        group_sizes = jnp.sum(
+            key[:, None] == jnp.arange(count, dtype=key.dtype)[None, :],
+            axis=0, dtype=jnp.int32)                             # (count,)
+        rows_held = group_sizes.sum()
+        # Each buffer row's weight: nought where its expert is not held.
+        weights = jnp.where(key.reshape(n_tok, k) < count, weights, 0.0)
+        row_weights = _to_buffer(weights.reshape(n_rows, 1), perm, inv)
+    with jax.named_scope("petastorm_tpu.moe_experts"):
+        act = _EXPERT_ACTS[cfg.expert_act]
+        # Rows past the last held group belong to no group: the grouped
+        # product never writes them, in its result or in its transpose
+        # (on the chip they come back as whatever the buffer held), so
+        # they are masked on the way in, for dx, and on the way out.
+        live = (jnp.arange(n_rows) < rows_held)[:, None]
+        xs = _to_buffer(h.reshape(n_tok, d), perm, inv)          # (T k, d)
+        xs = jnp.where(live, xs, 0)
+        gate = jax.lax.ragged_dot(xs, layer["ew1"].astype(h.dtype),
+                                  group_sizes)
+        up = jax.lax.ragged_dot(xs, layer["ew3"].astype(h.dtype),
+                                group_sizes)
+        rows = jax.lax.ragged_dot(act(gate) * up,
+                                  layer["ew2"].astype(h.dtype), group_sizes)
+        rows = jnp.where(live, rows * row_weights, 0).astype(h.dtype)
+        # Back to the tokens: each sums the weighted rows of its k choices.
+        out = _from_buffer(rows, perm, inv, n_tok)
+    stats = {"rows_held": rows_held, "load_max": group_sizes.max(),
+             "rows_buffer": jnp.asarray(n_rows, jnp.int32)}
+    return out.reshape(b, s, d), stats
+
+
+def publish_moe_stats(registry, stats) -> None:
+    """Add a step's (or several steps') expert-layer statistics, as
+    ``make_train_step(..., with_stats=True)`` returns them, to the
+    registry's counters ``model.moe.rows_held`` / ``.rows_buffer`` (rows
+    routed to held experts / rows the expert products are issued over,
+    summed over layers and steps) and ``model.moe.load_max`` (the largest
+    held expert's rows, summed likewise). Reads the device arrays: call it
+    between windows, never inside one."""
+    for name in MOE_STATS:
+        registry.counter(f"model.moe.{name}").add(
+            float(np.sum(np.asarray(stats[name], dtype=np.float64))))
 
 
 def _embed_lookup(embed, tokens, compute_dtype):
@@ -221,33 +429,55 @@ def _embed_lookup(embed, tokens, compute_dtype):
 
 
 def apply_block(layer, x, cfg: LlamaConfig, attn_fn=None, constrain=None,
-                expert_spec=None):
-    """One transformer block (attention + MLP/MoE residuals) -> (x, aux).
+                expert_spec=None, layer_idx: int = 0, window_attn_fn=None,
+                with_stats: bool = False):
+    """One transformer block (attention + MLP/MoE residuals) -> (x, aux),
+    or (x, aux, stats) ``with_stats`` (:data:`MOE_STATS`, zeros where the
+    FFN is not the dropless expert layer).
 
+    The block is built from the layer's attention kind
+    (``cfg.attention_kind(layer_idx)``: rotary positions or none, full or
+    sliding-window) and the model's FFN kind (dense, ``soft`` / ``switch``
+    experts, dropless held experts); the norms, the projections, the
+    residuals and the sharding constraints are the same code for all.
     Shared by :func:`apply`'s sequential layer loop and GPipe pipeline
     stages (:mod:`petastorm_tpu.parallel.pipeline`), so a pipelined model
     runs the exact same math per layer as the sequential one.
     """
+    from petastorm_tpu.parallel.attention import dense_attention
     if constrain is None:
         constrain = lambda t: t  # noqa: E731 - trivial identity
     hd = cfg.head_dim
     rep = cfg.n_heads // cfg.n_kv_heads
-    gqa_native = attn_fn is None or getattr(attn_fn, "supports_gqa", False)
+    rope, window = cfg.attention_kind(layer_idx)
+    fn = attn_fn if window is None else window_attn_fn
+    gqa_native = fn is None or getattr(fn, "supports_gqa", False)
     aux = jnp.zeros((), jnp.float32)
+    layer_input = x
     h = _rmsnorm(x, layer["attn_norm"], cfg.norm_eps)
     b, s, _ = h.shape
     q = (h @ layer["wq"].astype(h.dtype)).reshape(b, s, cfg.n_heads, hd)
     k = (h @ layer["wk"].astype(h.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
     v = (h @ layer["wv"].astype(h.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
-    q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
+    if rope:
+        q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
     if not gqa_native and rep > 1:
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
-    attn = (attn_fn or _dense_causal_attention)(q, k, v)
+    with jax.named_scope("petastorm_tpu.attn_full" if window is None
+                         else "petastorm_tpu.attn_window"):
+        attn = (fn or partial(dense_attention, causal=True,
+                              window=window))(q, k, v)
     attn = attn.reshape(b, s, cfg.n_heads * hd)
     x = constrain(x + attn @ layer["wo"].astype(attn.dtype))
     h = _rmsnorm(x, layer["mlp_norm"], cfg.norm_eps)
-    if "router" in layer:
+    stats = None
+    if cfg.n_router_outputs:
+        moe_out, stats = _dropless_moe_block(
+            layer_input if cfg.router_input == "layer_input" else h,
+            h, layer, cfg)
+        x = constrain(x + moe_out)
+    elif "router" in layer:
         if cfg.moe_dispatch == "switch":
             from petastorm_tpu.parallel.moe import switch_moe_block
             moe_out, layer_aux = switch_moe_block(
@@ -263,14 +493,19 @@ def apply_block(layer, x, cfg: LlamaConfig, attn_fn=None, constrain=None,
         gate = jax.nn.silu(h @ layer["w1"].astype(h.dtype))
         up = h @ layer["w3"].astype(h.dtype)
         x = constrain(x + (gate * up) @ layer["w2"].astype(h.dtype))
-    return x, aux
+    if not with_stats:
+        return x, aux
+    if stats is None:
+        stats = {name: jnp.zeros((), jnp.int32) for name in MOE_STATS}
+    return x, aux, stats
 
 
 def apply(params, tokens, cfg: LlamaConfig, attn_fn=None,
           activation_spec=None, compute_dtype=jnp.bfloat16,
           expert_spec=None, with_aux=False, layers_fn=None,
           embed_lookup: str = "gather", return_hidden: bool = False,
-          remat_layers: bool = False):
+          remat_layers: bool = False, window_attn_fn=None,
+          with_stats: bool = False):
     """tokens: (batch, seq) int32 -> logits (batch, seq, vocab)
     (or the pre-lm_head hidden states when ``return_hidden`` — the
     chunked-cross-entropy path computes per-chunk logits itself).
@@ -282,6 +517,13 @@ def apply(params, tokens, cfg: LlamaConfig, attn_fn=None,
         (dense/ring/ulysses) handle grouped-query K/V natively — K/V stay at
         n_kv_heads width; only user-supplied attentions without the
         ``supports_gqa`` flag get the repeated layout.
+    :param window_attn_fn: the attention callable of the sliding-window
+        layers (``cfg.sliding_window_layout``), e.g.
+        ``make_flash_attention(window=cfg.sliding_window)``; ``None`` uses
+        dense attention under the banded mask. ``attn_fn`` serves the
+        full-attention layers.
+    :param with_stats: also return, last, the expert layers' statistics:
+        ``{name: (n_layers,) int32}`` over :data:`MOE_STATS`.
     :param activation_spec: optional ``PartitionSpec`` for (b, s, d)
         activations; applied with ``with_sharding_constraint`` so GSPMD keeps
         the intended layout between layers.
@@ -317,7 +559,11 @@ def apply(params, tokens, cfg: LlamaConfig, attn_fn=None,
     x = constrain(_embed_lookup(params["embed"], tokens, compute_dtype)
                   if embed_lookup == "onehot"
                   else params["embed"].astype(compute_dtype)[tokens])
+    stats = []
     if layers_fn is not None:
+        if with_stats:
+            raise ValueError("with_stats reads the sequential layer loop; a "
+                             "layers_fn returns none")
         if remat_layers:
             raise ValueError(
                 "remat_layers applies to the sequential layer loop; a "
@@ -326,22 +572,28 @@ def apply(params, tokens, cfg: LlamaConfig, attn_fn=None,
         x, layers_aux = layers_fn(params["layers"], x)
         aux = aux + layers_aux
     else:
-        def one_block(layer, x):
-            return apply_block(layer, x, cfg, attn_fn=attn_fn,
-                               constrain=constrain, expert_spec=expert_spec)
-        if remat_layers:
-            # Long-context lever: save only layer-boundary activations;
-            # the backward recomputes each block (jax.checkpoint trades
-            # one extra forward per block for O(layers) less residual HBM).
-            one_block = jax.checkpoint(one_block)
-        for layer in params["layers"]:
-            x, layer_aux = one_block(layer, x)
+        for li, layer in enumerate(params["layers"]):
+            one_block = partial(
+                apply_block, cfg=cfg, attn_fn=attn_fn, constrain=constrain,
+                expert_spec=expert_spec, layer_idx=li,
+                window_attn_fn=window_attn_fn, with_stats=with_stats)
+            if remat_layers:
+                # Long-context lever: save only layer-boundary activations;
+                # the backward recomputes each block (jax.checkpoint trades
+                # one extra forward per block for O(layers) less residual
+                # HBM).
+                one_block = jax.checkpoint(one_block)
+            x, layer_aux, *layer_stats = one_block(layer, x)
             aux = aux + layer_aux
+            stats.extend(layer_stats)
     x = _rmsnorm(x, params["norm_out"], cfg.norm_eps)
-    if return_hidden:
-        return (x, aux) if with_aux else x
-    logits = (x @ params["lm_head"].astype(x.dtype)).astype(jnp.float32)
-    return (logits, aux) if with_aux else logits
+    if not return_hidden:
+        x = (x @ params["lm_head"].astype(x.dtype)).astype(jnp.float32)
+    out = (x, aux) if with_aux else (x,)
+    if with_stats:
+        out += ({name: jnp.stack([st[name] for st in stats])
+                 for name in MOE_STATS},)
+    return out if len(out) > 1 else out[0]
 
 
 def _head_xent_chunks(xf, head, targets, weights, with_grads):
@@ -415,7 +667,8 @@ def loss_fn(params, batch, cfg: LlamaConfig, attn_fn=None, activation_spec=None,
             expert_spec=None, aux_weight: float = 1e-2, layers_fn=None,
             embed_lookup: str = "gather", compute_dtype=jnp.bfloat16,
             shift: str = "split", xent_chunk: int | None = None,
-            remat_layers: bool = False):
+            remat_layers: bool = False, window_attn_fn=None,
+            with_stats: bool = False):
     """Next-token cross entropy (+ MoE load-balancing aux for switch
     dispatch). batch: {'tokens': (b, s) int32}. ``compute_dtype=float32``
     makes activation math exact — the PP-parity pinning mode (microbatched
@@ -434,6 +687,9 @@ def loss_fn(params, batch, cfg: LlamaConfig, attn_fn=None, activation_spec=None,
       would need an s = multiple-of-sp **plus one** window that cannot be
       device_put evenly.
 
+    ``with_stats`` returns ``(loss, stats)``: the expert layers'
+    statistics of :func:`apply`, for ``value_and_grad(has_aux=True)``.
+
     ``xent_chunk`` (must divide ``batch * model seq``) computes the loss
     head ``xent_chunk`` tokens at a time in one loop that, under ``grad``,
     also yields the hidden states' and the head's gradients: the
@@ -446,6 +702,12 @@ def loss_fn(params, batch, cfg: LlamaConfig, attn_fn=None, activation_spec=None,
     if shift not in ("split", "roll"):
         raise ValueError(f"unknown shift {shift!r}")
     inputs = tokens if shift == "roll" else tokens[:, :-1]
+    run = partial(apply, params, inputs, cfg, attn_fn=attn_fn,
+                  activation_spec=activation_spec, expert_spec=expert_spec,
+                  with_aux=True, layers_fn=layers_fn,
+                  embed_lookup=embed_lookup, compute_dtype=compute_dtype,
+                  remat_layers=remat_layers, window_attn_fn=window_attn_fn,
+                  with_stats=with_stats)
     if xent_chunk:
         # Never materialize the (b, s, V) logits: at 32k context and 32k
         # vocab the full tensor is ~4.2 GB f32 (plus its cotangent), which
@@ -457,12 +719,7 @@ def loss_fn(params, batch, cfg: LlamaConfig, attn_fn=None, activation_spec=None,
         # checkpointed chunk body) costs a fourth product: 620.23 against
         # 593.79 ms a step at Mistral-7B widths, 16k tokens, chunk 2048
         # (ledger, PR 27, mistral7b-tok4k-1chip, resident_step_ms.tokens).
-        x, aux = apply(params, inputs, cfg, attn_fn=attn_fn,
-                       activation_spec=activation_spec,
-                       expert_spec=expert_spec, with_aux=True,
-                       layers_fn=layers_fn, embed_lookup=embed_lookup,
-                       compute_dtype=compute_dtype, return_hidden=True,
-                       remat_layers=remat_layers)
+        x, aux, *stats = run(return_hidden=True)
         b, s, dm = x.shape
         if shift == "roll":
             targets = jnp.roll(tokens, -1, axis=1)
@@ -479,13 +736,9 @@ def loss_fn(params, batch, cfg: LlamaConfig, attn_fn=None, activation_spec=None,
         chunks = (n_tok // xent_chunk, xent_chunk)
         nll = _chunked_xent(x.reshape(*chunks, dm), params["lm_head"],
                             targets.reshape(chunks), weights.reshape(chunks))
-        return nll + aux_weight * aux
-    logits, aux = apply(params, inputs, cfg, attn_fn=attn_fn,
-                        activation_spec=activation_spec,
-                        expert_spec=expert_spec, with_aux=True,
-                        layers_fn=layers_fn, embed_lookup=embed_lookup,
-                        compute_dtype=compute_dtype,
-                        remat_layers=remat_layers)
+        loss = nll + aux_weight * aux
+        return (loss, stats[0]) if with_stats else loss
+    logits, aux, *stats = run()
     # Fused form: nll = logsumexp(logits) - logits[target]. Identical math
     # to log_softmax + gather (log_softmax = logits - lse), but XLA skips
     # materializing the full (b, s, V) log-prob tensor — measured 13%
@@ -503,7 +756,8 @@ def loss_fn(params, batch, cfg: LlamaConfig, attn_fn=None, activation_spec=None,
         targets = tokens[:, 1:]
         tl = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
         nll = (lse - tl).mean()
-    return nll + aux_weight * aux
+    loss = nll + aux_weight * aux
+    return (loss, stats[0]) if with_stats else loss
 
 
 def make_train_step(cfg: LlamaConfig, learning_rate: float = 3e-4,
@@ -511,8 +765,12 @@ def make_train_step(cfg: LlamaConfig, learning_rate: float = 3e-4,
                     layers_fn=None, embed_lookup: str = "gather",
                     compute_dtype=jnp.bfloat16, shift: str = "split",
                     xent_chunk: int | None = None,
-                    remat_layers: bool = False):
-    """AdamW train step via optax; jit with sharded params for TP/DP/SP."""
+                    remat_layers: bool = False, window_attn_fn=None,
+                    with_stats: bool = False):
+    """AdamW train step via optax; jit with sharded params for TP/DP/SP.
+    ``with_stats``: the step returns ``(params, opt_state, loss, stats)``,
+    ``stats`` the expert layers' per-layer rows (:data:`MOE_STATS`): small
+    device arrays, for :func:`publish_moe_stats` between windows."""
     import optax
     tx = optax.adamw(learning_rate, weight_decay=0.1)
 
@@ -520,16 +778,17 @@ def make_train_step(cfg: LlamaConfig, learning_rate: float = 3e-4,
         return tx.init(params)
 
     def train_step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(
+        out, grads = jax.value_and_grad(
             partial(loss_fn, cfg=cfg, attn_fn=attn_fn,
                     activation_spec=activation_spec,
                     expert_spec=expert_spec, layers_fn=layers_fn,
                     embed_lookup=embed_lookup,
                     compute_dtype=compute_dtype, shift=shift,
-                    xent_chunk=xent_chunk,
-                    remat_layers=remat_layers))(params, batch)
+                    xent_chunk=xent_chunk, remat_layers=remat_layers,
+                    window_attn_fn=window_attn_fn, with_stats=with_stats),
+            has_aux=with_stats)(params, batch)
         updates, opt_state = tx.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
-        return params, opt_state, loss
+        return (params, opt_state) + (out if with_stats else (out,))
 
     return init_opt, train_step

@@ -15,6 +15,12 @@ HBM; this kernel never does. Design (flash-attention-2 style, TPU-first):
 * causal programs whose K/V tile lies entirely above the diagonal skip
   the matmuls via ``pl.when`` (the tile DMA still happens — acceptable:
   bandwidth is prefetch-pipelined, MXU time is not);
+* a static ``window`` (with ``causal``) is sliding-window attention: the
+  mask also drops keys ``window`` or more behind their query, and the
+  innermost grid axis walks only the tiles of the band (:func:`_band`),
+  so dead tiles on either side cost neither MXU work nor a grid step;
+  the three calls are then named ``swa_fwd`` / ``swa_bwd_dq`` /
+  ``swa_bwd_dkv`` (one kernel body per pass, the window an argument);
 * scores accumulate in float32 regardless of input dtype (numerics parity
   with :func:`petastorm_tpu.parallel.attention.dense_attention`);
 * the backward pass is two Pallas kernels (flash-attention-2 style,
@@ -111,29 +117,59 @@ def _resolve_interpret(interpret) -> bool:
     return bool(interpret)
 
 
-def _causal_live(causal: bool, q_off, k_off, block_q: int):
-    """True when this (q tile, kv tile) pair has any on-or-below-diagonal
-    element — the skip predicate shared by the forward and both backward
-    kernels."""
-    return jnp.logical_or(not causal, q_off + block_q - 1 >= k_off)
+def _causal_live(causal: bool, q_off, k_off, block_q: int,
+                 block_k: int = 0, window=None):
+    """True when this (q tile, kv tile) pair has any element inside the
+    mask: on or below the diagonal and, with a ``window``, fewer than
+    ``window`` keys behind its query — the skip predicate shared by the
+    forward and both backward kernels."""
+    live = jnp.logical_or(not causal, q_off + block_q - 1 >= k_off)
+    if window is not None:
+        live = jnp.logical_and(live, q_off - (k_off + block_k - 1) < window)
+    return live
 
 
-def _mask_causal(s, causal: bool, q_off, k_off, block_q: int, block_k: int):
-    """Apply the causal mask to a (block_q, block_k) score tile — ONE home
-    for the mask numerics so the backward recompute can never drift from
-    what the forward computed."""
+def _mask_causal(s, causal: bool, q_off, k_off, block_q: int, block_k: int,
+                 window=None):
+    """Apply the causal (and sliding-window) mask to a (block_q, block_k)
+    score tile — ONE home for the mask numerics so the backward recompute
+    can never drift from what the forward computed."""
     if not causal:
         return s
     qpos = q_off + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
     kpos = k_off + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
-    return jnp.where(qpos >= kpos, s, -jnp.inf)
+    keep = qpos >= kpos
+    if window is not None:
+        keep = jnp.logical_and(keep, qpos - kpos < window)
+    return jnp.where(keep, s, -jnp.inf)
+
+
+def _band(window, block_in: int, block_out: int, n_out: int, behind: int):
+    """The tiles of the walked (innermost) grid axis that one tile of the
+    outer axis can see -> ``(first(outer index), tiles walked)``.
+
+    Without a window every tile is walked from 0, as ever. With one, a q
+    tile (walking kv: ``behind = window - 1``) sees keys ``q_off - window
+    + 1 .. q_off + block_q - 1`` and a kv tile (walking q: ``behind = 0``)
+    is seen by queries ``k_off .. k_off + block_k + window - 2``: in both
+    ``window + block_in - 1`` positions, which touch at most ``(window +
+    block_in - 2) // block_out + 2`` tiles. The grid walks only those, so
+    dead tiles on either side of the band cost no grid step; an index past
+    the last tile is clamped in the index maps and dead in the kernel.
+    """
+    if window is None:
+        return (lambda i: 0), n_out
+    walked = min(n_out, (window + block_in - 2) // block_out + 2)
+    return (lambda i: jnp.maximum(i * block_in - behind, 0) // block_out,
+            walked)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
                   block_k: int, causal: bool, scale: float,
-                  emit_stats: bool = False, emit_lse: bool = False):
+                  emit_stats: bool = False, emit_lse: bool = False,
+                  window=None, k_first=lambda qi: 0):
     from jax.experimental import pallas as pl
 
     if emit_stats:
@@ -143,19 +179,21 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
     else:
         acc_ref, m_ref, l_ref = rest
 
-    qi, ki = pl.program_id(2), pl.program_id(3)
-    n_k = pl.num_programs(3)
+    qi, step = pl.program_id(2), pl.program_id(3)
+    n_steps = pl.num_programs(3)
+    ki = k_first(qi) + step     # the kv tile: the band's with a window
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[:] = jnp.zeros_like(l_ref)
 
     q_off, k_off = qi * block_q, ki * block_k
-    # Tiles fully above the causal diagonal contribute nothing: skip the
-    # MXU work (roughly halves causal kernel time at long seq).
-    live = _causal_live(causal, q_off, k_off, block_q)
+    # Tiles fully above the causal diagonal (or wholly behind the window)
+    # contribute nothing: skip the MXU work (roughly halves causal kernel
+    # time at long seq).
+    live = _causal_live(causal, q_off, k_off, block_q, block_k, window)
 
     @pl.when(live)
     def _step():
@@ -169,14 +207,18 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
         s = jax.lax.dot_general(                                 # (bq, bk)
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        s = _mask_causal(s, causal, q_off, k_off, block_q, block_k)
+        s = _mask_causal(s, causal, q_off, k_off, block_q, block_k, window)
         m_prev, l_prev = m_ref[:, 0], l_ref[:, 0]
         m_new = jnp.maximum(m_prev, s.max(axis=-1))
         # m_new is finite from the first live block (causal keeps the
         # diagonal), so exp never sees inf-inf; a still--inf running max
-        # contributes alpha=0 exactly.
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
+        # contributes alpha=0 exactly. Under a window a row's first live
+        # tile can lie wholly behind its own window: exponentiate against
+        # 0 there, so that p and alpha are 0 and not exp(inf - inf).
+        m_exp = m_new if window is None else jnp.where(
+            m_new == -jnp.inf, 0.0, m_new)
+        p = jnp.exp(s - m_exp[:, None])
+        alpha = jnp.exp(m_prev - m_exp)
         l_ref[:, 0] = l_prev * alpha + p.sum(axis=-1)
         m_ref[:, 0] = m_new
         # p rounds to the v dtype for the second MXU pass (standard flash
@@ -185,7 +227,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(ki == n_k - 1)
+    @pl.when(step == n_steps - 1)
     def _emit():
         if emit_stats:
             # Unnormalized accumulator + online-softmax stats, f32: the
@@ -204,11 +246,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
 
 
 def _flash_launch(q, k, v, causal: bool, block_q: int, block_k: int,
-                  interpret: bool, mode: str):
+                  interpret: bool, mode: str, window=None):
     """One launcher for every forward variant — same grid, BlockSpecs and
     scratch; ``mode`` picks the kernel's emit: ``"out"`` (normalized
     output), ``"lse"`` (output + logsumexp, the backward's residual), or
-    ``"stats"`` (unnormalized o + m/l, the ring-merge contract).
+    ``"stats"`` (unnormalized o + m/l, the ring-merge contract). With a
+    ``window`` the kv axis of the grid walks the band's tiles only
+    (:func:`_band`) and the call is named ``swa_fwd``.
 
     Kernel-internal layout is (b, heads, seq, d): Mosaic requires the
     block's minor-most two dims to tile as (sublane, lane) — (block_q, d)
@@ -222,9 +266,18 @@ def _flash_launch(q, k, v, causal: bool, block_q: int, block_k: int,
     b, sq, h, d = q.shape
     sk, kv_h = k.shape[1], k.shape[2]
     rep = h // kv_h
+    n_k = sk // block_k
+    k_first, k_walked = _band(window, block_q, block_k, n_k,
+                              behind=(window or 1) - 1)
     kernel = partial(_flash_kernel, block_q=block_q, block_k=block_k,
                      causal=causal, scale=1.0 / np.sqrt(d),
-                     emit_stats=(mode == "stats"), emit_lse=(mode == "lse"))
+                     emit_stats=(mode == "stats"), emit_lse=(mode == "lse"),
+                     window=window, k_first=k_first)
+    if window is None:
+        kv_index = lambda bi, hi, qi, ki: (bi, hi // rep, ki, 0)  # noqa: E731
+    else:
+        kv_index = lambda bi, hi, qi, step: (  # noqa: E731
+            bi, hi // rep, jnp.minimum(k_first(qi) + step, n_k - 1), 0)
     o_spec = pl.BlockSpec((1, 1, block_q, d),
                           lambda bi, hi, qi, ki: (bi, hi, qi, 0))
     stat_spec = pl.BlockSpec((1, 1, block_q, 1),
@@ -243,13 +296,11 @@ def _flash_launch(q, k, v, causal: bool, block_q: int, block_k: int,
                      stat_shape, stat_shape]
     return pl.pallas_call(
         kernel,
-        grid=(b, h, sq // block_q, sk // block_k),
+        grid=(b, h, sq // block_q, k_walked),
         in_specs=[
             o_spec,
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda bi, hi, qi, ki: (bi, hi // rep, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda bi, hi, qi, ki: (bi, hi // rep, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, d), kv_index),
+            pl.BlockSpec((1, 1, block_k, d), kv_index),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -259,25 +310,26 @@ def _flash_launch(q, k, v, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q, 1), jnp.float32),      # normalizer l
         ],
         interpret=interpret,
-        name="flash_fwd",
+        name="flash_fwd" if window is None else "swa_fwd",
     )(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
       v.transpose(0, 2, 1, 3))
 
 
 def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
-                   interpret: bool):
-    out = _flash_launch(q, k, v, causal, block_q, block_k, interpret, "out")
+                   interpret: bool, window=None):
+    out = _flash_launch(q, k, v, causal, block_q, block_k, interpret, "out",
+                        window)
     return out.transpose(0, 2, 1, 3)
 
 
 def _flash_forward_lse(q, k, v, causal: bool, block_q: int, block_k: int,
-                       interpret: bool):
+                       interpret: bool, window=None):
     """Forward that also emits logsumexp per q row — the residual the
     Pallas backward needs. Returns (o (b, sq, h, d) in q.dtype,
     lse (b, h, sq, 1) f32 — KERNEL layout: only the backward launch
     consumes it, so the model-side transpose round-trip is skipped)."""
     o, lse = _flash_launch(q, k, v, causal, block_q, block_k, interpret,
-                           "lse")
+                           "lse", window)
     return o.transpose(0, 2, 1, 3), lse
 
 
@@ -293,7 +345,7 @@ def _flash_stats_forward(q, k, v, causal: bool, block_q: int, block_k: int,
 
 
 def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, q_off, k_off,
-              block_q, block_k, causal, scale):
+              block_q, block_k, causal, scale, window=None):
     """Shared softmax-gradient tile math for both backward kernels:
     recompute scores from the refs, re-exponentiate against the saved
     lse (lse >= running max, so exp(s - lse) <= 1), and return
@@ -307,7 +359,7 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, q_off, k_off,
     dd = dd_ref[0, 0, :, 0]                                     # (bq,)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    s = _mask_causal(s, causal, q_off, k_off, block_q, block_k)
+    s = _mask_causal(s, causal, q_off, k_off, block_q, block_k, window)
     p = jnp.exp(s - lse[:, None])                               # (bq, bk)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
@@ -317,33 +369,36 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, q_off, k_off,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
                          dq_ref, dq_acc, *, block_q: int, block_k: int,
-                         causal: bool, scale: float):
+                         causal: bool, scale: float, window=None,
+                         k_first=lambda qi: 0):
     """dQ pass (flash-attention-2 backward): grid (b, h, q_blocks,
     kv_blocks), kv innermost; dq accumulates in VMEM scratch across the
     kv dimension. P is re-exponentiated from the saved lse, so no
     softmax state needs carrying."""
     from jax.experimental import pallas as pl
 
-    qi, ki = pl.program_id(2), pl.program_id(3)
-    n_k = pl.num_programs(3)
+    qi, step = pl.program_id(2), pl.program_id(3)
+    n_steps = pl.num_programs(3)
+    ki = k_first(qi) + step
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     q_off, k_off = qi * block_q, ki * block_k
-    live = _causal_live(causal, q_off, k_off, block_q)
+    live = _causal_live(causal, q_off, k_off, block_q, block_k, window)
 
     @pl.when(live)
     def _step():
         _, ds = _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
-                          q_off, k_off, block_q, block_k, causal, scale)
+                          q_off, k_off, block_q, block_k, causal, scale,
+                          window)
         k = k_ref[0, 0, :, :]
         dq_acc[:] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(ki == n_k - 1)
+    @pl.when(step == n_steps - 1)
     def _emit():
         dq_ref[0, 0, :, :] = dq_acc[:].astype(dq_ref.dtype)
 
@@ -351,16 +406,18 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
 def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dd_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, block_q: int,
                           block_k: int, n_q: int, causal: bool,
-                          scale: float):
+                          scale: float, window=None, q_walked=None,
+                          q_first=lambda ki: 0):
     """dK/dV pass: grid (b, kv_heads, kv_blocks, rep * q_blocks) — the
     innermost dimension walks every (grouped-query head, q block) pair
     that attends to this K/V tile, accumulating dk/dv in VMEM scratch
     (GQA gradients sum over the head group here instead of a host-side
-    reduction over repeated K/V)."""
+    reduction over repeated K/V). With a ``window`` only the ``q_walked``
+    q blocks from ``q_first(ki)`` on are walked for each head."""
     from jax.experimental import pallas as pl
 
     ki, t = pl.program_id(2), pl.program_id(3)
-    qi = t % n_q
+    qi = q_first(ki) + t % (n_q if q_walked is None else q_walked)
     n_t = pl.num_programs(3)
 
     @pl.when(t == 0)
@@ -369,12 +426,15 @@ def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dd_ref,
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     q_off, k_off = qi * block_q, ki * block_k
-    live = _causal_live(causal, q_off, k_off, block_q)
+    live = _causal_live(causal, q_off, k_off, block_q, block_k, window)
+    if window is not None:      # a walked index past the last q block
+        live = jnp.logical_and(live, qi < n_q)
 
     @pl.when(live)
     def _step():
         p, ds = _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
-                          q_off, k_off, block_q, block_k, causal, scale)
+                          q_off, k_off, block_q, block_k, causal, scale,
+                          window)
         q = q_ref[0, 0, :, :]
         do = do_ref[0, 0, :, :]
         dv_acc[:] += jax.lax.dot_general(
@@ -391,7 +451,7 @@ def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dd_ref,
 
 
 def _flash_backward(q, k, v, o, lse, do, causal: bool, block_q: int,
-                    block_k: int, interpret: bool):
+                    block_k: int, interpret: bool, window=None):
     """Pallas flash backward: dq via a kv-innermost pass, dk/dv via a
     q-innermost pass with in-kernel GQA group accumulation. O(block)
     VMEM per program, no O(seq^2) or O(block*seq) HBM tensors — the
@@ -413,40 +473,54 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, block_q: int,
     lseT = lse                                  # already (b, h, sq, 1)
     ddT = dd.transpose(0, 2, 1)[..., None]
 
+    n_q, n_k = sq // block_q, sk // block_k
+    k_first, k_walked = _band(window, block_q, block_k, n_k,
+                              behind=(window or 1) - 1)
+    q_first, q_walked = _band(window, block_k, block_q, n_q, behind=0)
     q_spec = pl.BlockSpec((1, 1, block_q, d),
                           lambda bi, hi, qi, ki: (bi, hi, qi, 0))
-    kv_spec = pl.BlockSpec((1, 1, block_k, d),
-                           lambda bi, hi, qi, ki: (bi, hi // rep, ki, 0))
+    if window is None:
+        kv_spec = pl.BlockSpec((1, 1, block_k, d),
+                               lambda bi, hi, qi, ki: (bi, hi // rep, ki, 0))
+    else:
+        kv_spec = pl.BlockSpec(
+            (1, 1, block_k, d), lambda bi, hi, qi, step: (
+                bi, hi // rep, jnp.minimum(k_first(qi) + step, n_k - 1), 0))
     stat_spec = pl.BlockSpec((1, 1, block_q, 1),
                              lambda bi, hi, qi, ki: (bi, hi, qi, 0))
     dq = pl.pallas_call(
         partial(_flash_bwd_dq_kernel, block_q=block_q, block_k=block_k,
-                causal=causal, scale=scale),
-        grid=(b, h, sq // block_q, sk // block_k),
+                causal=causal, scale=scale, window=window, k_first=k_first),
+        grid=(b, h, sq // block_q, k_walked),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-        name="flash_bwd_dq",
+        name="flash_bwd_dq" if window is None else "swa_bwd_dq",
     )(qT, kT, vT, doT, lseT, ddT)
 
-    n_q = sq // block_q
     kv_out_spec = pl.BlockSpec((1, 1, block_k, d),
                                lambda bi, gi, ki, t: (bi, gi, ki, 0))
-    # Per-(kv head, q tile) inputs: head gi*rep + t//n_q, q block t%n_q.
-    q_in = pl.BlockSpec(
-        (1, 1, block_q, d),
-        lambda bi, gi, ki, t: (bi, gi * rep + t // n_q, t % n_q, 0))
-    stat_in = pl.BlockSpec(
-        (1, 1, block_q, 1),
-        lambda bi, gi, ki, t: (bi, gi * rep + t // n_q, t % n_q, 0))
+    # Per-(kv head, q tile) inputs: head gi*rep + t//n_q, q block t%n_q
+    # (with a window: of the q_walked blocks from q_first(ki) on).
+    if window is None:
+        q_index = lambda bi, gi, ki, t: (  # noqa: E731
+            bi, gi * rep + t // n_q, t % n_q, 0)
+    else:
+        q_index = lambda bi, gi, ki, t: (  # noqa: E731
+            bi, gi * rep + t // q_walked,
+            jnp.minimum(q_first(ki) + t % q_walked, n_q - 1), 0)
+    q_in = pl.BlockSpec((1, 1, block_q, d), q_index)
+    stat_in = pl.BlockSpec((1, 1, block_q, 1), q_index)
     kv_in = pl.BlockSpec((1, 1, block_k, d),
                          lambda bi, gi, ki, t: (bi, gi, ki, 0))
     dk, dv = pl.pallas_call(
         partial(_flash_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
-                n_q=n_q, causal=causal, scale=scale),
-        grid=(b, kv_h, sk // block_k, rep * n_q),
+                n_q=n_q, causal=causal, scale=scale, window=window,
+                q_walked=None if window is None else q_walked,
+                q_first=q_first),
+        grid=(b, kv_h, sk // block_k, rep * q_walked),
         in_specs=[kv_in, kv_in, q_in, q_in, stat_in, stat_in],
         out_specs=[kv_out_spec, kv_out_spec],
         out_shape=[jax.ShapeDtypeStruct((b, kv_h, sk, d), k.dtype),
@@ -454,7 +528,7 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, block_q: int,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name="flash_bwd_dkv" if window is None else "swa_bwd_dkv",
     )(kT, vT, qT, doT, lseT, ddT)
     return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
             dv.transpose(0, 2, 1, 3))
@@ -522,29 +596,30 @@ def flash_attention_stats(q, k, v, causal: bool = False,
                             q, k, v)
 
 
-def _dense(q, k, v, causal):
+def _dense(q, k, v, causal, window=None):
     from petastorm_tpu.parallel.attention import dense_attention
-    return dense_attention(q, k, v, causal=causal)
+    return dense_attention(q, k, v, causal=causal, window=window)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
-def _flash_vjp(causal, block_q, block_k, interpret, q, k, v):
-    return _flash_forward(q, k, v, causal, block_q, block_k, interpret)
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
+def _flash_vjp(causal, block_q, block_k, interpret, window, q, k, v):
+    return _flash_forward(q, k, v, causal, block_q, block_k, interpret,
+                          window)
 
 
-def _flash_vjp_fwd(causal, block_q, block_k, interpret, q, k, v):
+def _flash_vjp_fwd(causal, block_q, block_k, interpret, window, q, k, v):
     # The lse-emitting launch costs one extra (b, h, sq) f32 write over
     # the plain forward and saves the backward an entire forward
     # recompute (the old chunked-dense bwd re-ran the whole attention).
     o, lse = _flash_forward_lse(q, k, v, causal, block_q, block_k,
-                                interpret)
+                                interpret, window)
     return o, (q, k, v, o, lse)
 
 
-def _flash_vjp_bwd(causal, block_q, block_k, interpret, residual, g):
+def _flash_vjp_bwd(causal, block_q, block_k, interpret, window, residual, g):
     q, k, v, o, lse = residual
     return _flash_backward(q, k, v, o, lse, g, causal, block_q, block_k,
-                           interpret)
+                           interpret, window)
 
 
 _flash_vjp.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -553,10 +628,13 @@ _flash_vjp.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 def flash_attention(q, k, v, causal: bool = False,
                     block_q: int = _DEFAULT_BLOCK_Q,
                     block_k: int = _DEFAULT_BLOCK_K,
-                    interpret=None):
+                    interpret=None, window=None):
     """Drop-in for :func:`...parallel.attention.dense_attention`:
     q ``(b, sq, heads, d)``, k/v ``(b, sk, kv_heads, d)`` ->
-    ``(b, sq, heads, d)``, grouped-query native.
+    ``(b, sq, heads, d)``, grouped-query native. ``window`` (static, needs
+    ``causal``) keeps of each query's keys its own and the ``window - 1``
+    before it; the grid then walks the band's tiles alone and the three
+    calls are named ``swa_fwd`` / ``swa_bwd_dq`` / ``swa_bwd_dkv``.
 
     Falls back to the dense path when the shape can't tile onto the
     hardware (:func:`_tiles`). ``interpret=None`` selects the Pallas
@@ -567,21 +645,28 @@ def flash_attention(q, k, v, causal: bool = False,
     sk, kv_h = k.shape[1], k.shape[2]
     if h % kv_h:
         raise ValueError(f"heads ({h}) must be a multiple of kv_heads ({kv_h})")
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"window ({window}) needs causal=True and >= 1")
     tiles = _tiles(sq, sk, causal, block_q, block_k)
     if tiles is None:
-        return _dense(q, k, v, causal)
-    return _flash_vjp(causal, *tiles, _resolve_interpret(interpret), q, k, v)
+        return _dense(q, k, v, causal, window)
+    return _flash_vjp(causal, *tiles, _resolve_interpret(interpret), window,
+                      q, k, v)
 
 
 def make_flash_attention(causal: bool = True, block_q: int = _DEFAULT_BLOCK_Q,
-                         block_k: int = _DEFAULT_BLOCK_K, interpret=None):
+                         block_k: int = _DEFAULT_BLOCK_K, interpret=None,
+                         window=None):
     """An ``attn_fn`` for :func:`petastorm_tpu.models.llama.apply`
-    (``supports_gqa``: K/V arrive at native kv-head width). Its caller
-    asked for the kernel, so a shape the tiles cannot divide raises
-    (:func:`require_flash_tiles`) instead of taking the dense route."""
+    (``supports_gqa``: K/V arrive at native kv-head width); with a
+    ``window`` the one for the model's sliding-window layers
+    (``window_attn_fn``). Its caller asked for the kernel, so a shape the
+    tiles cannot divide raises (:func:`require_flash_tiles`) instead of
+    taking the dense route."""
     def attn(q, k, v):
         require_flash_tiles(q.shape[1], k.shape[1], causal, block_q, block_k)
         return flash_attention(q, k, v, causal=causal, block_q=block_q,
-                               block_k=block_k, interpret=interpret)
+                               block_k=block_k, interpret=interpret,
+                               window=window)
     attn.supports_gqa = True
     return attn

@@ -8,16 +8,20 @@ import jax
 import jax.numpy as jnp
 
 
-def dense_attention(q, k, v, causal: bool = False):
+def dense_attention(q, k, v, causal: bool = False, window=None):
     """Softmax attention on full tensors; q is (b, seq, heads, dim) and
     k/v are (b, seq, kv_heads, dim) with ``heads % kv_heads == 0`` —
     grouped-query attention runs natively (each K/V head serves
     ``heads/kv_heads`` query heads via einsum broadcasting, no repeat).
 
     Scores accumulate in float32 regardless of input dtype; the causal mask
-    is position-based so it also holds for lq != lk."""
+    is position-based so it also holds for lq != lk. ``window`` (with
+    ``causal``) keeps of each query's keys its own and the ``window - 1``
+    before it: sliding-window attention."""
     b, lq, h, d = q.shape
     kv_h = k.shape[2]
+    if window is not None and not causal:
+        raise ValueError("window needs causal=True")
     if h == kv_h:
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
     else:
@@ -29,7 +33,9 @@ def dense_attention(q, k, v, causal: bool = False):
     scores = scores / jnp.sqrt(jnp.float32(d))
     if causal:
         lk = k.shape[1]
-        mask = jnp.arange(lq)[:, None] >= jnp.arange(lk)[None, :]
+        behind = jnp.arange(lq)[:, None] - jnp.arange(lk)[None, :]
+        mask = behind >= 0 if window is None else (
+            (behind >= 0) & (behind < window))
         scores = jnp.where(mask[None, None], scores, -jnp.inf)
     w = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     if h == kv_h:

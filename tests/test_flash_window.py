@@ -1,0 +1,117 @@
+"""Sliding-window attention: the three Pallas kernels under a static
+``window`` (interpret mode) and the dense fallback, against plain masked
+attention written out here."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from petastorm_tpu.ops import flash_attn
+from petastorm_tpu.ops.flash_attn import flash_attention
+from petastorm_tpu.parallel.attention import dense_attention
+
+
+def masked_attention(q, k, v, window):
+    """Softmax attention under ``j <= i`` and ``i - j < window``, heads
+    repeated, float32."""
+    rep = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    behind = jnp.arange(q.shape[1])[:, None] - jnp.arange(k.shape[1])[None]
+    keep = (behind >= 0) & (behind < window)
+    probs = jax.nn.softmax(jnp.where(keep, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def inputs(seq, heads=4, kv_heads=2, dim=16, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    shape = lambda h: (2, seq, h, dim)  # noqa: E731
+    return (jax.random.normal(keys[0], shape(heads)),
+            jax.random.normal(keys[1], shape(kv_heads)),
+            jax.random.normal(keys[2], shape(kv_heads)),
+            jax.random.normal(keys[3], shape(heads)))
+
+
+# (seq, window, block_q, block_k): shorter than, equal to and longer than
+# the sequence; not a multiple of either block; one key; blocks either way.
+CASES = [(128, 40, 32, 64), (128, 128, 32, 64), (128, 200, 32, 64),
+         (128, 1, 32, 64), (256, 50, 16, 64), (256, 100, 64, 32),
+         (256, 33, 32, 32), (256, 64, 32, 128)]
+
+
+@pytest.mark.parametrize("seq,window,block_q,block_k", CASES)
+def test_windowed_kernels_match_masked_attention(seq, window, block_q,
+                                                 block_k):
+    q, k, v, g = inputs(seq, seed=seq + window)
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=block_q,
+                               block_k=block_k, window=window)
+
+    want, pull = jax.vjp(lambda q, k, v: masked_attention(q, k, v, window),
+                         q, k, v)
+    got, pull_kernel = jax.vjp(kernel, q, k, v)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    for mine, theirs in zip(pull_kernel(g), pull(g)):
+        np.testing.assert_allclose(mine, theirs, atol=2e-5)
+
+
+@pytest.mark.parametrize("window", [1, 7, 64, 100])
+def test_dense_fallback_takes_the_same_window(window):
+    q, k, v, _ = inputs(64, seed=window)
+    np.testing.assert_allclose(
+        dense_attention(q, k, v, causal=True, window=window),
+        masked_attention(q, k, v, window), atol=2e-6)
+    # A shape the tiles cannot divide takes the dense route, window and all.
+    q, k, v, _ = inputs(100, seed=window)
+    np.testing.assert_allclose(
+        flash_attention(q, k, v, causal=True, window=window),
+        masked_attention(q, k, v, window), atol=2e-6)
+
+
+def test_a_window_needs_the_causal_mask():
+    q, k, v, _ = inputs(64)
+    with pytest.raises(ValueError):
+        flash_attention(q, k, v, causal=False, window=8)
+    with pytest.raises(ValueError):
+        dense_attention(q, k, v, causal=False, window=8)
+    with pytest.raises(ValueError):
+        flash_attention(q, k, v, causal=True, window=0)
+
+
+@pytest.mark.parametrize("window,block_in,block_out,n_out,behind,first,walked",
+                         [(None, 256, 1024, 16, 0, [0, 0, 0], 16),
+                          # kv walked from a q tile, the token cell's tiles
+                          (4096, 256, 1024, 16, 4095, [0, 0, 4, 11], 6),
+                          # q walked from a kv tile
+                          (4096, 1024, 256, 64, 0, [0, 4, 32, 60], 21),
+                          # a window past the sequence walks every tile
+                          (1 << 20, 256, 1024, 16, (1 << 20) - 1,
+                           [0, 0, 0, 0], 16)])
+def test_the_band_walks_only_the_tiles_it_can_see(window, block_in, block_out,
+                                                  n_out, behind, first,
+                                                  walked):
+    first_of, n = flash_attn._band(window, block_in, block_out, n_out, behind)
+    assert n == walked
+    outer = [0, 1, 8, 15] if window else [0, 1, 8]
+    if window == 4096 and block_in == 256:
+        outer = [0, 15, 32, 63]
+    assert [int(first_of(i)) for i in outer] == first
+
+
+def test_windowed_calls_carry_names_of_their_own():
+    """``chipbench/trace_reduce.kernel_seconds`` matches by substring: the
+    windowed calls must not read as the causal ones."""
+    q, k, v, _ = inputs(128)
+
+    def names(window):
+        text = jax.jit(jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=32, block_k=64, window=window,
+            interpret=True).sum(), (0, 1, 2))).lower(q, k, v).as_text(
+                debug_info=True)
+        return {n for n in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                            "swa_fwd", "swa_bwd_dq", "swa_bwd_dkv")
+                if n in text}
+
+    assert names(None) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    assert names(32) == {"swa_fwd", "swa_bwd_dq", "swa_bwd_dkv"}

@@ -198,9 +198,9 @@ def test_flash_attention_block_fallback_keeps_kernel_path(monkeypatch):
     calls = {}
     real = fa_mod._flash_forward
 
-    def spy(q, k, v, causal, block_q, block_k, interpret):
+    def spy(q, k, v, causal, block_q, block_k, interpret, window=None):
         calls["blocks"] = (block_q, block_k)
-        return real(q, k, v, causal, block_q, block_k, interpret)
+        return real(q, k, v, causal, block_q, block_k, interpret, window)
 
     monkeypatch.setattr(fa_mod, "_flash_forward", spy)
     q, k, v = _attn_inputs(s=1280)
