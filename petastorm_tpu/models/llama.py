@@ -279,58 +279,145 @@ def _moe_block(h, layer):
     return jnp.einsum("besd,bse->bsd", expert_out, probs)
 
 
-def _buffer_rows(x, perm):
-    """Token rows ``x`` (T, d) into the expert buffer (T k, d): buffer row
-    ``r`` is assignment ``perm[r]``, which is token ``perm[r] // k``'s
-    (``k = 1``: the rows permuted)."""
-    return x[perm // (perm.shape[0] // x.shape[0])]
+# The grouped product's row tile on the chip (the compiled step of PR 29
+# walks 399 tiles of 512 rows): the short buffer is a whole number of them.
+_ROW_TILE = 512
+# Room over the expected share of rows in the short buffer. A layer that
+# holds more runs the full buffer in the same step, so a wrong guess here
+# costs speed and never an answer.
+_BUFFER_HEADROOM = 1.5
 
 
-def _token_sums(rows, inv, n_tok: int):
+def _short_buffer_rows(n_rows: int, cfg: LlamaConfig) -> int:
+    """Rows of the short expert buffer: the share of the ``n_rows``
+    assignments that the held experts expect, with headroom, in whole row
+    tiles; ``n_rows`` where that is no shorter."""
+    expected = n_rows * cfg.experts_held[1] / cfg.n_router_outputs
+    tiles = int(np.ceil(expected * _BUFFER_HEADROOM / _ROW_TILE))
+    return min(n_rows, tiles * _ROW_TILE)
+
+
+def _buffer_rows(x, head, k: int):
+    """Token rows ``x`` (T, d) into the expert buffer: buffer row ``r`` is
+    assignment ``head[r]``, which is token ``head[r] // k``'s (``k = 1``:
+    the rows themselves). ``head`` is the sort's permutation of the
+    ``T k`` assignments or its first rows."""
+    return x[head // k]
+
+
+def _token_sums(rows, head, k: int, n_tok: int):
     """The transpose of :func:`_buffer_rows`: each token's sum (float32)
-    over the buffer rows of its ``k`` assignments."""
-    rows = rows[inv].reshape(n_tok, inv.shape[0] // n_tok, rows.shape[-1])
-    return jnp.sum(rows, axis=1, dtype=jnp.float32).astype(rows.dtype)
+    over the buffer rows of its assignments. Of a whole permutation every
+    token has exactly ``k`` rows, found through its inverse: a gather and
+    a sum, and no scatter. Of its first rows only, the rows are added
+    where they belong."""
+    if head.shape[0] == n_tok * k:
+        rows = rows[jnp.argsort(head)].reshape(n_tok, k, rows.shape[-1])
+        return jnp.sum(rows, axis=1, dtype=jnp.float32).astype(rows.dtype)
+    # The scatter-add moves the rows held and no others. The other exact
+    # way, k gathers of a token's rows from the buffer with a zero row
+    # for the assignments it lacks, still reads T k rows: 17.8 against
+    # 9.6 ms a call at 73,728 of 196,608 rows x 2,560 (chip probes, PR 30).
+    return jax.ops.segment_sum(rows.astype(jnp.float32), head // k,
+                               num_segments=n_tok).astype(rows.dtype)
 
 
-@jax.custom_vjp
-def _to_buffer(x, perm, inv):
-    """:func:`_buffer_rows` whose backward is :func:`_token_sums`: the
-    transpose of a gather is a scatter-add, but ``perm`` is a permutation
-    with inverse ``inv``, so both directions gather and neither scatters."""
-    return _buffer_rows(x, perm)
+@partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _to_buffer(x, head, k):
+    """:func:`_buffer_rows` whose backward is :func:`_token_sums`."""
+    return _buffer_rows(x, head, k)
 
 
-def _to_buffer_fwd(x, perm, inv):
-    return _buffer_rows(x, perm), (perm, inv, x.shape[0])
+def _to_buffer_fwd(x, head, k):
+    return _buffer_rows(x, head, k), (head, x.shape[0])
 
 
-def _to_buffer_bwd(residuals, g):
-    perm, inv, n_tok = residuals
-    return _token_sums(g, inv, n_tok), None, None
+def _to_buffer_bwd(k, residuals, g):
+    head, n_tok = residuals
+    return _token_sums(g, head, k, n_tok), None
 
 
 _to_buffer.defvjp(_to_buffer_fwd, _to_buffer_bwd)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _from_buffer(rows, perm, inv, n_tok):
+@partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _from_buffer(rows, head, k, n_tok):
     """:func:`_token_sums` whose backward is :func:`_buffer_rows`."""
-    return _token_sums(rows, inv, n_tok)
+    return _token_sums(rows, head, k, n_tok)
 
 
-def _from_buffer_fwd(rows, perm, inv, n_tok):
-    return _token_sums(rows, inv, n_tok), (perm, inv)
+def _from_buffer_fwd(rows, head, k, n_tok):
+    return _token_sums(rows, head, k, n_tok), head
 
 
-def _from_buffer_bwd(n_tok, residuals, g):
-    perm, inv = residuals
-    return _buffer_rows(g, perm), None, None
+def _from_buffer_bwd(k, n_tok, head, g):
+    return _buffer_rows(g, head, k), None
 
 
 _from_buffer.defvjp(_from_buffer_fwd, _from_buffer_bwd)
 
-MOE_STATS = ("rows_held", "load_max", "rows_buffer")
+MOE_STATS = ("rows_held", "load_max", "rows_buffer", "short_buffer")
+
+
+def _expert_rows(n_buf: int, cfg: LlamaConfig, h, weights, w_gate, w_up,
+                 w_down, perm, group_sizes):
+    """The held experts' part of every token's result, through a buffer of
+    the sort's first ``n_buf`` rows (at least ``group_sizes.sum()``).
+    h: (T, d); weights: (T, k), nought where the expert is not held; perm:
+    (T k,) buffer row -> assignment, the held experts' rows leading."""
+    n_tok, k = weights.shape
+    act = _EXPERT_ACTS[cfg.expert_act]
+    head = perm[:n_buf]
+    # Rows past the last held group belong to no group: the grouped
+    # product never writes them, in its result or in its transpose (on the
+    # chip they come back as whatever the buffer held), so they are masked
+    # on the way in, for dx, and on the way out.
+    live = (jnp.arange(n_buf) < group_sizes.sum())[:, None]
+    row_weights = _to_buffer(weights.reshape(n_tok * k, 1), head, 1)
+    xs = jnp.where(live, _to_buffer(h, head, k), 0)              # (n_buf, d)
+    gate = jax.lax.ragged_dot(xs, w_gate.astype(h.dtype), group_sizes)
+    up = jax.lax.ragged_dot(xs, w_up.astype(h.dtype), group_sizes)
+    rows = jax.lax.ragged_dot(act(gate) * up, w_down.astype(h.dtype),
+                              group_sizes)
+    rows = jnp.where(live, rows * row_weights, 0).astype(h.dtype)
+    # Back to the tokens: each sums the weighted rows of its k choices.
+    return _from_buffer(rows, head, k, n_tok)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _expert_rows_short_or_full(n_short: int, cfg: LlamaConfig, short,
+                               *operands):
+    """:func:`_expert_rows` through the short buffer where ``short`` (the
+    held rows fit it: a traced bool), else through the full one.
+    Differentiating the ``cond`` itself would keep the residuals of both
+    branches (the one not taken filled with zeros, every step: the step
+    then no longer fits the chip), so the choice stands outside: forward
+    and backward each branch on ``short``, and the backward recomputes
+    inside the branch it takes."""
+    n_rows = operands[-2].shape[0]
+    return jax.lax.cond(short, partial(_expert_rows, n_short, cfg),
+                        partial(_expert_rows, n_rows, cfg), *operands)
+
+
+def _expert_rows_fwd(n_short, cfg, short, *operands):
+    return (_expert_rows_short_or_full(n_short, cfg, short, *operands),
+            (short, operands))
+
+
+def _expert_rows_bwd(n_short, cfg, residuals, g):
+    short, operands = residuals
+
+    def pull(n_buf, g, *operands):
+        *diff, perm, group_sizes = operands
+        return jax.vjp(lambda *diff: _expert_rows(
+            n_buf, cfg, *diff, perm, group_sizes), *diff)[1](g)
+
+    grads = jax.lax.cond(short, partial(pull, n_short),
+                         partial(pull, operands[-2].shape[0]), g, *operands)
+    return (None, *grads, None, None)
+
+
+_expert_rows_short_or_full.defvjp(_expert_rows_fwd, _expert_rows_bwd)
 
 
 def _dropless_moe_block(route_x, h, layer, cfg: LlamaConfig):
@@ -346,18 +433,26 @@ def _dropless_moe_block(route_x, h, layer, cfg: LlamaConfig):
     in the expert-parallel exchange, which one shard runs without).
 
     The assignments are sorted by expert with the held experts' rows
-    leading, into ONE static buffer of ``tokens x top_k`` rows: whatever
-    the imbalance, every assignment has its row. The three products are
-    ``jax.lax.ragged_dot`` over the held experts' group sizes (its
-    transposes give dx and dW); rows past the last held group belong to no
-    group and are masked to zero, going in and coming out. ``stats``: int32
-    scalars, the rows routed to held experts, the largest held expert's
-    rows, and the buffer's rows (:data:`MOE_STATS`).
+    leading, and the expert computation (:func:`_expert_rows`: gather the
+    rows' tokens, three ``jax.lax.ragged_dot`` products over the held
+    experts' group sizes, weight, sum back into the tokens) moves the
+    sort's first rows through a static buffer. The buffer is SHORT where
+    the held rows fit it: the share the held experts expect of ``tokens x
+    top_k`` rows, times :data:`_BUFFER_HEADROOM`, in whole row tiles
+    (:func:`_short_buffer_rows`). A layer that holds more takes the FULL
+    buffer of ``tokens x top_k`` rows in the same step (``jax.lax.cond`` on
+    the traced count): whatever the imbalance, every assignment has its
+    row. Where the short size is the full one (all experts held, toy
+    shapes) there is one path and no ``cond``. ``stats``: int32 scalars,
+    the rows routed to held experts, the largest held expert's rows, the
+    rows of the buffer taken, and 1 where that was the short one
+    (:data:`MOE_STATS`).
     """
     b, s, d = h.shape
     n_tok, k = b * s, cfg.top_k
     first, count = cfg.experts_held
     n_rows = n_tok * k
+    n_short = _short_buffer_rows(n_rows, cfg)
     with jax.named_scope("petastorm_tpu.moe_route"):
         logits = jnp.dot(route_x.reshape(n_tok, d).astype(jnp.float32),
                          layer["router"],
@@ -367,45 +462,37 @@ def _dropless_moe_block(route_x, h, layer, cfg: LlamaConfig):
         # Held experts sort first, in order; the rest follow.
         key = ((ids - first) % cfg.n_router_outputs).reshape(n_rows)
         perm = jnp.argsort(key, stable=True)   # buffer row -> assignment
-        inv = jnp.argsort(perm)                # assignment -> buffer row
         group_sizes = jnp.sum(
             key[:, None] == jnp.arange(count, dtype=key.dtype)[None, :],
             axis=0, dtype=jnp.int32)                             # (count,)
         rows_held = group_sizes.sum()
-        # Each buffer row's weight: nought where its expert is not held.
+        # Each assignment's weight: nought where its expert is not held.
         weights = jnp.where(key.reshape(n_tok, k) < count, weights, 0.0)
-        row_weights = _to_buffer(weights.reshape(n_rows, 1), perm, inv)
     with jax.named_scope("petastorm_tpu.moe_experts"):
-        act = _EXPERT_ACTS[cfg.expert_act]
-        # Rows past the last held group belong to no group: the grouped
-        # product never writes them, in its result or in its transpose
-        # (on the chip they come back as whatever the buffer held), so
-        # they are masked on the way in, for dx, and on the way out.
-        live = (jnp.arange(n_rows) < rows_held)[:, None]
-        xs = _to_buffer(h.reshape(n_tok, d), perm, inv)          # (T k, d)
-        xs = jnp.where(live, xs, 0)
-        gate = jax.lax.ragged_dot(xs, layer["ew1"].astype(h.dtype),
-                                  group_sizes)
-        up = jax.lax.ragged_dot(xs, layer["ew3"].astype(h.dtype),
-                                group_sizes)
-        rows = jax.lax.ragged_dot(act(gate) * up,
-                                  layer["ew2"].astype(h.dtype), group_sizes)
-        rows = jnp.where(live, rows * row_weights, 0).astype(h.dtype)
-        # Back to the tokens: each sums the weighted rows of its k choices.
-        out = _from_buffer(rows, perm, inv, n_tok)
+        operands = (h.reshape(n_tok, d), weights, layer["ew1"], layer["ew3"],
+                    layer["ew2"], perm, group_sizes)
+        if n_short == n_rows:
+            short = jnp.zeros((), bool)
+            out = _expert_rows(n_rows, cfg, *operands)
+        else:
+            short = rows_held <= n_short
+            out = _expert_rows_short_or_full(n_short, cfg, short, *operands)
     stats = {"rows_held": rows_held, "load_max": group_sizes.max(),
-             "rows_buffer": jnp.asarray(n_rows, jnp.int32)}
+             "rows_buffer": jnp.where(short, n_short, n_rows).astype(jnp.int32),
+             "short_buffer": short.astype(jnp.int32)}
     return out.reshape(b, s, d), stats
 
 
 def publish_moe_stats(registry, stats) -> None:
     """Add a step's (or several steps') expert-layer statistics, as
     ``make_train_step(..., with_stats=True)`` returns them, to the
-    registry's counters ``model.moe.rows_held`` / ``.rows_buffer`` (rows
-    routed to held experts / rows the expert products are issued over,
-    summed over layers and steps) and ``model.moe.load_max`` (the largest
-    held expert's rows, summed likewise). Reads the device arrays: call it
-    between windows, never inside one."""
+    registry's counters, each summed over layers and steps:
+    ``model.moe.rows_held`` (rows routed to held experts),
+    ``model.moe.rows_buffer`` (rows of the buffer the layer moved them
+    through: the short one's or, where it fell back, ``tokens x top_k``),
+    ``model.moe.short_buffer`` (layer-steps that took the short buffer)
+    and ``model.moe.load_max`` (the largest held expert's rows). Reads the
+    device arrays: call it between windows, never inside one."""
     for name in MOE_STATS:
         registry.counter(f"model.moe.{name}").add(
             float(np.sum(np.asarray(stats[name], dtype=np.float64))))

@@ -47,6 +47,20 @@ def assert_close(got, want, tol, what=""):
     assert float(jnp.abs(got - want).max()) <= tol * scale, what
 
 
+@pytest.fixture(params=[512, 8], ids=["one_path", "short_path"])
+def row_tile(request, monkeypatch):
+    """The chip's row tile of 512 rounds the toy shapes' short buffer up to
+    every row (one path, no ``cond``); a tile of 8 leaves it 192 of 512
+    rows (96 of 256), so the toy sizes really take the short path."""
+    monkeypatch.setattr(llama, "_ROW_TILE", request.param)
+    return request.param
+
+
+@pytest.fixture
+def short_tile(monkeypatch):
+    monkeypatch.setattr(llama, "_ROW_TILE", 8)
+
+
 def loss_and_grads(sizes, params, tokens, **kw):
     return jax.jit(jax.value_and_grad(partial(
         llama.loss_fn, cfg=program_config(sizes), shift="roll", xent_chunk=64,
@@ -55,10 +69,11 @@ def loss_and_grads(sizes, params, tokens, **kw):
 
 
 @pytest.mark.parametrize("kernels", ["dense", "flash"])
-def test_program_matches_the_plain_reference(kernels):
+def test_program_matches_the_plain_reference(kernels, row_tile):
     """Loss and every gradient leaf, seeded random weights, toy widths: 4
     layers (full without positions, then three windowed with RoPE), 2 of 8
-    experts held, top 2, window 32 in a sequence of 128."""
+    experts held, top 2, window 32 in a sequence of 128; through the one
+    full buffer, and with every layer on the short one."""
     from chipbench.reference import smallthinker as ref
     sizes = toy_sizes()
     key = jax.random.PRNGKey(3)
@@ -80,8 +95,11 @@ def test_program_matches_the_plain_reference(kernels):
     want_grads = leaves(want_grads)
     for name, got in leaves(grads).items():
         assert_close(got, want_grads[name], 1e-4, name)
-    assert stats["rows_buffer"].tolist() == [2 * 128 * 2] * 4
-    assert all(0 < held < 512 for held in stats["rows_held"].tolist())
+    # The rows of the buffer taken: all 512, or the short one's 192.
+    short = row_tile == 8
+    assert stats["rows_buffer"].tolist() == [192 if short else 512] * 4
+    assert stats["short_buffer"].tolist() == [int(short)] * 4
+    assert all(0 < held < 192 for held in stats["rows_held"].tolist())
     assert all(m <= h for m, h in zip(stats["load_max"].tolist(),
                                       stats["rows_held"].tolist()))
 
@@ -111,12 +129,13 @@ def test_every_assignment_to_a_held_expert_is_computed():
         assert_close(out[row], want, 1e-5)
 
 
-def test_rows_that_no_group_owns_never_reach_a_result(monkeypatch):
+def test_rows_that_no_group_owns_never_reach_a_result(monkeypatch, row_tile):
     """On the chip XLA's grouped product skips the tiles past the last
     group: those rows of its result, and of its transpose for dx, are
     never written (first chip run, PR 29: every gradient upstream of an
     expert layer read 1e4 to 1e8 times too large). Here they are poisoned,
-    and loss-side values and every gradient must not notice."""
+    and loss-side values and every gradient must not notice: past the held
+    rows of the full buffer, and between them and the short buffer's end."""
     from chipbench.reference import smallthinker as ref
     real = jax.lax.ragged_dot
 
@@ -160,11 +179,114 @@ def test_rows_that_no_group_owns_never_reach_a_result(monkeypatch):
     (value, stats), grads = jax.value_and_grad(mine, (0, 1), has_aux=True)(
         h, layer)
     assert int(stats["rows_held"]) < int(stats["rows_buffer"])  # a tail
+    assert int(stats["rows_buffer"]) == (96 if row_tile == 8 else 256)
     want, want_grads = jax.value_and_grad(theirs, (0, 1))(h, layer)
     assert abs(float(value) - float(want)) <= 1e-5 * abs(float(want))
     assert_close(grads[0], want_grads[0], 1e-5, "dh")
-    for name in ("ew1", "ew3", "ew2"):
+    for name in ("ew1", "ew3", "ew2", "router"):
         assert_close(grads[1][name], want_grads[1][name], 1e-5, name)
+
+
+def routed(cfg, n_tok: int, rows_held: int):
+    """``(route_x, router)`` under which exactly ``rows_held`` of the
+    ``n_tok x 2`` assignments go to the two held experts: feature ``e`` of
+    a token is large where expert ``e`` is its first or second choice and
+    the router reads feature ``e`` into logit ``e``; small noise on both
+    keeps the router's gradient a full matrix."""
+    first, held = cfg.experts_held
+    assert held == 2 and cfg.top_k == 2 and 0 <= rows_held <= 2 * n_tok
+    away = [e for e in range(cfg.n_router_outputs)
+            if not first <= e < first + held]
+    both = max(0, rows_held - n_tok)
+    one = rows_held - 2 * both
+    choices = np.empty((n_tok, 2), np.int64)
+    for t in range(n_tok):
+        far = (away[t % len(away)], away[(t + 1) % len(away)])
+        if t < both:
+            pair = (first + t % 2, first + (t + 1) % 2)
+        elif t < both + one:
+            pair = (first + t % 2, far[0])[::1 if t % 3 else -1]
+        else:
+            pair = far
+        choices[t] = pair
+    x = 0.1 * np.asarray(jax.random.normal(jax.random.PRNGKey(12),
+                                           (n_tok, cfg.dim)))
+    x[np.arange(n_tok), choices[:, 0]] = 10.0
+    x[np.arange(n_tok), choices[:, 1]] = 9.0
+    router = 0.01 * np.asarray(jax.random.normal(
+        jax.random.PRNGKey(13), (cfg.dim, cfg.n_router_outputs)))
+    router[np.arange(cfg.n_router_outputs),
+           np.arange(cfg.n_router_outputs)] = 1.0
+    return jnp.asarray(x, jnp.float32), jnp.asarray(router, jnp.float32)
+
+
+@pytest.mark.parametrize("rows_held,short", [
+    (100, True),        # under the short buffer's 192 rows
+    (192, True),        # the boundary: its last row is held
+    (193, False),       # one more: the full buffer, in the same call
+    (400, False),
+    (512, False),       # every assignment held
+], ids=["short", "short_to_its_last_row", "one_row_over", "fallback",
+        "all_held"])
+def test_either_buffer_gives_the_references_answer(short_tile, rows_held,
+                                                    short):
+    """The short buffer (192 of the toy shapes' 512 rows) where the held
+    rows fit it and the full one where they do not, chosen inside one
+    jitted program from the traced count: value, dh, the three expert
+    matrices' and the router's gradient are the plain reference's."""
+    from chipbench.reference import smallthinker as ref
+    sizes = toy_sizes(num_hidden_layers=1)
+    cfg = program_config(sizes)
+    layer = llama.init_params(jax.random.PRNGKey(1), cfg)["layers"][0]
+    x, layer["router"] = routed(cfg, 256, rows_held)
+    h = jax.random.normal(jax.random.PRNGKey(4), (256, cfg.dim))
+    g = jax.random.normal(jax.random.PRNGKey(8), (256, cfg.dim))
+
+    def mine(h, layer):
+        out, stats = llama._dropless_moe_block(
+            x.reshape(2, 128, -1), h.reshape(2, 128, -1), layer, cfg)
+        return jnp.sum(out.reshape(256, -1) * g), stats
+
+    def theirs(h, layer):
+        weights, ids = ref.route(x, layer["router"], cfg.top_k)
+        return jnp.sum(ref.experts_part(layer, h, weights, ids, sizes, None)
+                       * g)
+
+    (value, stats), grads = jax.jit(jax.value_and_grad(
+        mine, (0, 1), has_aux=True))(h, layer)
+    assert {k: int(v) for k, v in stats.items() if k != "load_max"} == {
+        "rows_held": rows_held, "short_buffer": int(short),
+        "rows_buffer": 192 if short else 512}
+    want, want_grads = jax.jit(jax.value_and_grad(theirs, (0, 1)))(h, layer)
+    assert abs(float(value) - float(want)) <= 1e-5 * abs(float(want))
+    assert_close(grads[0], want_grads[0], 1e-5, "dh")
+    for name in ("ew1", "ew3", "ew2", "router"):
+        assert_close(grads[1][name], want_grads[1][name], 1e-5, name)
+
+
+def test_a_shard_that_holds_every_expert_keeps_one_path(short_tile):
+    """``count == n_router_outputs``: the short size is the full one, so
+    nothing is chosen: no ``cond`` is traced and the buffer is ``T x k``.
+    (With 2 of 8 held the same call does trace one.)"""
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 128, 64))
+
+    def traced(held):
+        cfg = program_config(toy_sizes(num_hidden_layers=1,
+                                       moe_num_primary_experts=held))
+        assert cfg.experts_held == (0, held)
+        layer = llama.init_params(jax.random.PRNGKey(1), cfg)["layers"][0]
+        block = partial(llama._dropless_moe_block, cfg=cfg)
+        _, stats = block(x, x, layer)
+        return str(jax.make_jaxpr(jax.grad(
+            lambda h: block(x, h, layer)[0].sum()))(x)), stats
+
+    text, stats = traced(8)
+    assert " cond[" not in text and "ragged_dot" in text
+    assert (int(stats["rows_buffer"]), int(stats["short_buffer"])) == (512, 0)
+    assert int(stats["rows_held"]) == 512
+    text, stats = traced(2)
+    assert " cond[" in text
+    assert (int(stats["rows_buffer"]), int(stats["short_buffer"])) == (192, 1)
 
 
 def test_the_four_shares_add_up_to_the_uncut_layer():
@@ -280,7 +402,7 @@ def test_attention_kind_follows_the_two_layouts():
     hash(cfg)       # static under jit: the layouts are tuples
 
 
-def test_step_statistics_become_the_registrys_counters():
+def test_step_statistics_become_the_registrys_counters(row_tile):
     from petastorm_tpu.telemetry.registry import TelemetryRegistry
     sizes = toy_sizes()
     cfg = program_config(sizes)
@@ -298,7 +420,11 @@ def test_step_statistics_become_the_registrys_counters():
     llama.publish_moe_stats(registry, stats)
     llama.publish_moe_stats(registry, stats)
     counters = registry.metrics_view()["counters"]
-    assert counters["model.moe.rows_buffer"] == 2 * 4 * 512
+    # Two publications of four layers: the rows of the buffer each took,
+    # and the layer-steps that took the short one.
+    short = row_tile == 8
+    assert counters["model.moe.rows_buffer"] == 2 * 4 * (192 if short else 512)
+    assert counters["model.moe.short_buffer"] == (2 * 4 if short else 0)
     assert counters["model.moe.rows_held"] == 2 * int(stats["rows_held"].sum())
     assert counters["model.moe.load_max"] == 2 * int(stats["load_max"].sum())
     # A model without the expert layer reports noughts, not an error.
