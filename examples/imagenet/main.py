@@ -12,10 +12,44 @@ import time
 
 import numpy as np
 
-from petastorm_tpu.benchmark.imagenet_bench import (ImagenetSchema,  # noqa: F401
-                                                    write_synthetic_imagenet)
+from petastorm_tpu.codecs import CompressedImageCodec, ScalarCodec
+from petastorm_tpu.etl.writer import materialize_dataset_local
 from petastorm_tpu.jax import DataLoader, DTypePolicy
 from petastorm_tpu.reader import make_reader
+from petastorm_tpu.unischema import Unischema, UnischemaField
+
+
+def make_imagenet_schema(image_size: int = 224) -> Unischema:
+    return Unischema("ImagenetSchema", [
+        UnischemaField("image", np.uint8, (image_size, image_size, 3),
+                       CompressedImageCodec("jpeg", 85), False),
+        UnischemaField("label", np.int32, (), ScalarCodec(np.int32), False),
+    ])
+
+
+ImagenetSchema = make_imagenet_schema()
+
+
+def write_synthetic_imagenet(url: str, rows: int, classes: int = 100,
+                             seed: int = 0, rows_per_row_group: int = 64,
+                             image_size: int = 224):
+    """Class-separable synthetic images: a per-class 8x8 proto upsampled to
+    ``image_size`` plus uniform noise — compresses like a photo, trains like
+    a toy. ``image_size`` must be a multiple of 8; smaller sizes make the
+    ResNet step CPU-feasible for tests (ResNet is fully convolutional)."""
+    if image_size % 8:
+        raise ValueError("image_size must be a multiple of 8")
+    rng = np.random.default_rng(seed)
+    protos = rng.integers(60, 195, (classes, 8, 8, 3)).astype(np.uint8)
+    up = image_size // 8
+    with materialize_dataset_local(url, make_imagenet_schema(image_size),
+                                   rows_per_row_group=rows_per_row_group) as w:
+        for _ in range(rows):
+            label = int(rng.integers(0, classes))
+            base = np.kron(protos[label], np.ones((up, up, 1), np.uint8))
+            noise = rng.integers(0, 60, (image_size, image_size, 3)).astype(np.uint8)
+            w.write_row({"image": np.clip(base + noise, 0, 255).astype(np.uint8),
+                         "label": np.int32(label)})
 
 
 def train(url: str, steps: int = 30, per_device_batch: int = 8,

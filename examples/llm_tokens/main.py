@@ -63,7 +63,7 @@ def train(url: str, steps: int = 40, batch_size: int = 8, window: int = 4,
     # instead of {offset: namedtuple} — one reshape away from a training
     # sequence. (On scalar token stores — one token per row — dense also
     # unlocks the fully vectorized column-major assembly; see
-    # petastorm_tpu/benchmark/llm_bench.py and docs/performance.md.)
+    # petastorm_tpu/ngram.py.)
     ngram = NGram({i: ["tokens"] for i in range(window)},
                   delta_threshold=1, timestamp_field="seq",
                   timestamp_overlap=True, dense=True)

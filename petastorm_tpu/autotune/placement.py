@@ -6,7 +6,7 @@ The knob is binary — ``0`` = thread pool (in-process, zero transport cost,
 GIL-shared), ``1`` = process pool (spawned workers, shm Arrow transport,
 GIL-free) — and which side wins is workload- and host-dependent: a
 decode-heavy store on a many-core host wants processes; a small store on a
-starved host wants threads (docs/performance.md measured both outcomes).
+starved host wants threads.
 So the controller runs a **measured trial**: when the pipeline stays
 producer-bound with every conventional knob maxed, it flips placement,
 waits for the migration to apply and a settle window to pass, then compares

@@ -1,5 +1,5 @@
 """Persistent XLA compilation cache for the entry points that jit for the
-chip (``chip_smoke.py``, the benchmark CLIs, the examples).
+chip (``python3 -m chipbench.run``, the examples).
 
 Library code (``make_reader``, the loaders) sets no global JAX config;
 only a ``main`` calls :func:`ensure_compile_cache`, before its first

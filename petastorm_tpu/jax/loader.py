@@ -924,8 +924,7 @@ class LoaderBase:
             return None
 
     def stage_breakdown(self) -> dict:
-        """Cumulative seconds per pipeline stage (the ``stage_breakdown``
-        block ``bench.py`` emits):
+        """Cumulative seconds per pipeline stage:
 
         * ``decode_s`` — in-worker row-group read+decode (thread/dummy
           pools; 0 for spawned process pools, whose workers cannot share

@@ -16,7 +16,7 @@ can fail (see docs/resilience.md):
   detection + re-ventilation of lost row groups under a crash budget.
 * :mod:`~petastorm_tpu.resilience.faults` — deterministic seeded
   :class:`FaultPlan` injection (IOError / corruption / latency with
-  seeded jitter / worker-kill) for tests and ``bench.py``.
+  seeded jitter / worker-kill) for tests.
 
 Latency faults — the *slow* failure mode PR 2's fail-stop machinery
 cannot see — get their own three-piece defense layer (docs/resilience.md

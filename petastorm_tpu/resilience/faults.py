@@ -119,8 +119,8 @@ class FaultSpec:
         (AWS-style ``min(jit, uniform(jit/10, 3 * prev))``, per
         ``(spec, worker)`` RNG keyed off the plan seed). Real straggler
         distributions are long-tailed and uncorrelated injection-to-
-        injection, not a constant; the seeded draw keeps tests and
-        ``bench.py straggler_epoch`` byte-reproducible run-to-run. The
+        injection, not a constant; the seeded draw keeps tests
+        byte-reproducible run-to-run. The
         jitter RNG stream is separate from the ``rate`` decision stream,
         so adding jitter to an existing plan never shifts which accesses
         fire.

@@ -18,8 +18,8 @@ Exports: Prometheus text format and JSON snapshots
 petastorm_tpu.telemetry`` CLI to dump/watch a live pipeline. See
 ``docs/observability.md``.
 
-Stage metric names (the documented schema; also the keys behind
-``bench.py``'s ``stage_breakdown``):
+Stage metric names (the documented schema; also the keys behind the
+loaders' ``stage_breakdown()``):
 
 ==============================  =================================================
 metric                          meaning

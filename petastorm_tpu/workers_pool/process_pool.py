@@ -117,23 +117,15 @@ class _SegmentClaim:
 
 
 def _resolve_auto_transport() -> str:
-    """Measured rule for ``transport="auto"`` (round-4 verdict "weak" 2:
-    auto must cite a measurement, not lib-buildability).
+    """The rule for ``transport="auto"``: **shm when the ring builds, zmq
+    otherwise.** ``PETASTORM_TPU_TRANSPORT`` (``shm``/``zmq``) overrides
+    outright.
 
-    ``PETASTORM_TPU_TRANSPORT`` (``shm``/``zmq``) overrides outright — it is
-    also how the sweep in ``benchmark/transport_bench.py`` drives each
-    transport through the full reader stack.
-
-    The rule: **shm when the ring builds, zmq otherwise.** Basis (bench
-    host, docs/performance.md): pool payloads are serialized row-group
-    batches — hundreds of KB to MB, beyond the ~100 KB transport crossover
-    where the ring holds a >=2x per-item advantage over pipe-class IPC
-    (5 GB/s vs 1.9 at 1 MB); and end-to-end through the reader on the
-    decode-heavy 10k store the shm ring beats the zmq-ipc path on the same
-    host (``reader_transport_sweep``; see docs/performance.md for the
-    numbers). Thread-vs-process is the caller's ``reader_pool_type``
-    choice, not this rule's: on hosts without spare cores EVERY process
-    transport loses to threads."""
+    Basis: pool payloads are serialized row-group batches — hundreds of KB
+    to MB, where one shared-memory write replaces the kernel copies of
+    pipe-class IPC (sandbox counts only; no cell of the benchmark runs a
+    process pool yet, PERF.md "Open questions"). Thread-vs-process is the
+    caller's ``reader_pool_type`` choice, not this rule's."""
     forced = os.environ.get("PETASTORM_TPU_TRANSPORT", "").strip().lower()
     if forced:
         if forced not in ("shm", "zmq"):

@@ -90,3 +90,19 @@ def rows_equal(actual, expected_row) -> bool:
         elif got != expected:
             return False
     return True
+
+
+def write_token_store(url, windows, window, vocab=32000, seed=0):
+    """Timestamped token store, one NGram window per row group (windows
+    never cross row groups — same layout contract as the reference's
+    NGram, ngram.py:86-91 there)."""
+    schema = Unischema("TokSchema", [
+        UnischemaField("ts", np.int64, (), ScalarCodec(np.int64), False),
+        UnischemaField("token", np.int32, (), ScalarCodec(np.int32), False),
+    ])
+    rng = np.random.default_rng(seed)
+    with materialize_dataset_local(url, schema,
+                                   rows_per_row_group=window) as w:
+        for i in range(windows * window):
+            w.write_row({"ts": np.int64(i),
+                         "token": np.int32(rng.integers(0, vocab))})

@@ -223,7 +223,7 @@ def test_copy_dataset_refuses_nested_paths(synthetic_dataset, tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# tools/bench_compare.py — cross-round regression diff (docs/io.md round 7)
+# tools/check_columns.py — explicit columns= lint (docs/io.md)
 # ---------------------------------------------------------------------------
 def _load_tool(name):
     import importlib.util
@@ -235,63 +235,6 @@ def _load_tool(name):
     return mod
 
 
-@pytest.mark.io
-class TestBenchCompare:
-    @pytest.fixture(scope="class")
-    def tool(self):
-        return _load_tool("bench_compare")
-
-    def _write(self, tmp_path, name, doc):
-        import json
-        p = tmp_path / name
-        p.write_text(json.dumps(doc))
-        return str(p)
-
-    def test_ok_within_threshold(self, tool, tmp_path, capsys):
-        old = self._write(tmp_path, "old.json",
-                          {"value": 100.0, "x_samples_per_sec": 50.0})
-        new = self._write(tmp_path, "new.json",
-                          {"value": 90.0, "x_samples_per_sec": 55.0})
-        assert tool.main([old, new]) == 0
-
-    def test_regression_fails(self, tool, tmp_path):
-        old = self._write(tmp_path, "old.json", {"value": 100.0})
-        new = self._write(tmp_path, "new.json", {"value": 70.0})
-        assert tool.main([old, new]) == 1
-        assert tool.main([old, new, "--threshold", "0.5"]) == 0
-
-    def test_nested_phases_and_p50_preference(self, tool, tmp_path):
-        old = self._write(tmp_path, "old.json", {
-            "value": 100.0, "value_p50": 100.0,
-            "mem": {"epoch2_speedup": 10.0}})
-        new = self._write(tmp_path, "new.json", {
-            "value": 200.0, "value_p50": 60.0,   # p50 regressed: must fail
-            "mem": {"epoch2_speedup": 9.5}})
-        assert tool.main([old, new]) == 1
-
-    def test_added_and_removed_phases_never_fail(self, tool, tmp_path):
-        old = self._write(tmp_path, "old.json",
-                          {"value": 100.0, "gone_samples_per_sec": 5.0})
-        new = self._write(tmp_path, "new.json",
-                          {"value": 100.0, "new_samples_per_sec": 5.0})
-        assert tool.main([old, new]) == 0
-
-    def test_driver_wrapper_unwrapped(self, tool, tmp_path):
-        old = self._write(tmp_path, "old.json",
-                          {"rc": 0, "parsed": {"value": 100.0}})
-        new = self._write(tmp_path, "new.json", {"value": 50.0})
-        assert tool.main([old, new]) == 1
-
-    def test_unreadable_input(self, tool, tmp_path):
-        bad = tmp_path / "bad.json"
-        bad.write_text("{not json")
-        ok = self._write(tmp_path, "ok.json", {"value": 1.0})
-        assert tool.main([str(bad), ok]) == 2
-
-
-# ---------------------------------------------------------------------------
-# tools/check_columns.py — explicit columns= lint (docs/io.md)
-# ---------------------------------------------------------------------------
 @pytest.mark.io
 class TestCheckColumnsLint:
     @pytest.fixture(scope="class")

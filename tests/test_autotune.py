@@ -658,9 +658,8 @@ class TestReaderIntegration:
             assert a.decimal_col == b.decimal_col
 
     def test_epoch2_faster_on_synthetic_store(self, synthetic_dataset):
-        """Soft perf sanity (the hard >=1.3x acceptance gate runs on the
-        decode-heavy store in bench.py mem_cache_epoch — 100x there): the
-        cached epoch must never be slower than the decode-everything one."""
+        """Soft perf sanity: the cached epoch must never be slower than the
+        decode-everything one."""
         with make_reader(synthetic_dataset.url, num_epochs=3,
                          shuffle_row_groups=False, reader_pool_type="dummy",
                          memory_cache_size_bytes=1 << 30) as r:

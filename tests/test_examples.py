@@ -120,9 +120,8 @@ def test_imagenet_example_runs(tmp_path):
     """The ImageNet example runs end to end on a tiny synthetic store and
     reports a positive throughput."""
     ex = _load_example("imagenet")
-    from petastorm_tpu.benchmark.imagenet_bench import write_synthetic_imagenet
     url = f"file://{tmp_path}/imgnet"
-    write_synthetic_imagenet(url, rows=128, classes=2, rows_per_row_group=32,
+    ex.write_synthetic_imagenet(url, rows=128, classes=2, rows_per_row_group=32,
                              image_size=48)
     stall, sps = ex.train(url, steps=10, per_device_batch=4, classes=2,
                           learning_rate=0.005)

@@ -760,3 +760,28 @@ def test_echo_cuts_stall_when_host_is_the_bottleneck(synthetic_dataset):
     plain_wait = plain["wait_s"] / plain["steps"]
     echoed_wait = echoed["wait_s"] / echoed["steps"]
     assert echoed_wait < plain_wait * 0.6, (plain, echoed)
+
+
+def test_commit_batch_surfaces_device_failures(monkeypatch):
+    """The staging fallback is for an odd leaf (TypeError/ValueError); a
+    runtime failure on the device must surface, not be retried quietly."""
+    import jax
+    import numpy as np
+
+    from petastorm_tpu.jax import DataLoader
+
+    loader = object.__new__(DataLoader)
+    loader._commit_cache = {}
+    cols = {"a": np.arange(4, dtype=np.int32)}
+
+    def failing_jit(error):
+        def jit(fn):
+            raise error
+        return jit
+
+    monkeypatch.setattr(jax, "jit", failing_jit(RuntimeError("device lost")))
+    with pytest.raises(RuntimeError, match="device lost"):
+        loader._commit_batch(cols)
+    monkeypatch.setattr(jax, "jit", failing_jit(TypeError("odd leaf")))
+    staged = loader._commit_batch(cols)
+    assert np.array_equal(np.asarray(staged["a"]), cols["a"])

@@ -31,8 +31,8 @@ def scalar_store(tmp_path_factory):
 @pytest.fixture(scope="module")
 def token_store(tmp_path_factory):
     """Petastorm token store: 16 NGram windows of 32 tokens, one per
-    row group (the llm_bench layout)."""
-    from petastorm_tpu.benchmark.llm_bench import write_token_store
+    row group."""
+    from dataset_utils import write_token_store
     path = tmp_path_factory.mktemp("mesh_tokens")
     url = f"file://{path}/tokens"
     write_token_store(url, windows=16, window=32)

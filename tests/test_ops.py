@@ -358,3 +358,40 @@ def test_flash_backward_gqa_group_accumulation_matches_dense():
         for name, a, b in zip("qkv", gf, gd):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=2e-4, err_msg=f"d{name}")
+
+
+def test_make_flash_attention_raises_where_flash_attention_falls_back():
+    import jax.numpy as jnp
+
+    from petastorm_tpu.ops.flash_attn import (flash_attention,
+                                              make_flash_attention)
+
+    q = jnp.zeros((1, 100, 2, 8), jnp.float32)
+    assert flash_attention(q, q, q, causal=True).shape == q.shape  # dense
+    with pytest.raises(ValueError, match="cannot tile"):
+        make_flash_attention(causal=True)(q, q, q)
+
+
+def test_pallas_interpret_is_chosen_on_cpu_only(monkeypatch):
+    import jax
+
+    from petastorm_tpu.ops import flash_attn
+
+    assert flash_attn._resolve_interpret(None) is True       # tests: cpu
+    assert flash_attn._resolve_interpret(False) is False
+    for backend in ("tpu", "gpu", "some_plugin"):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert flash_attn._resolve_interpret(None) is False
+        assert flash_attn._resolve_interpret(False) is False
+        with pytest.raises(ValueError, match="cpu only"):
+            flash_attn._resolve_interpret(True)
+
+
+def test_flash_launch_tiles_hold_at_the_smoke_windows():
+    """4k and 32k — the chip legs — tile at the launch defaults, 256x1024."""
+    from petastorm_tpu.ops.flash_attn import require_flash_tiles
+
+    assert require_flash_tiles(4096, 4096, causal=True) == (256, 1024)
+    assert require_flash_tiles(32768, 32768, causal=True) == (256, 1024)
+    with pytest.raises(ValueError, match="cannot tile"):
+        require_flash_tiles(4096, 2048, causal=True)   # causal needs sq == sk

@@ -170,25 +170,40 @@ _GRAFT_ENTRY = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "__graft_entry__.py")
 
 
-@pytest.mark.slow
-def test_graft_entry_dryrun_multichip():
+def _load_graft_entry():
     import importlib.util
     spec = importlib.util.spec_from_file_location(
-        "graft_entry", _GRAFT_ENTRY)
+        "graft_entry_under_test", _GRAFT_ENTRY)
     m = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(m)
-    m.dryrun_multichip(8)
+    return m
+
+
+@pytest.mark.slow
+def test_graft_entry_dryrun_multichip():
+    _load_graft_entry().dryrun_multichip(8)
 
 
 def test_graft_entry_forward_compiles():
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "graft_entry2", _GRAFT_ENTRY)
-    m = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(m)
-    fn, args = m.entry()
+    fn, args = _load_graft_entry().entry()
     out = jax.eval_shape(fn, *args)
     assert out.shape == (8, 1000)
+
+
+def test_graft_entry_dry_run_refuses_a_live_accelerator(monkeypatch):
+    """__graft_entry__ is a CPU-only dry run: in a process whose backend is
+    not the CPU it raises instead of clearing backends under the owner."""
+    import types
+
+    import jax
+
+    graft = _load_graft_entry()
+    assert len(graft._virtual_cpu_devices(2)) >= 2       # tests run on CPU
+    monkeypatch.setattr(
+        jax, "devices", lambda: [types.SimpleNamespace(platform="tpu")])
+    with pytest.raises(RuntimeError, match="CPU-only dry run"):
+        graft._virtual_cpu_devices(2)
+    assert "clear_backends" not in open(_GRAFT_ENTRY).read()
 
 
 @pytest.mark.slow

@@ -245,17 +245,3 @@ def test_pool_profiling_prints_worker_frames(capsys):
     out = capsys.readouterr().out
     assert "function calls" in out and "cumulative" in out
     assert "stub_workers" in out  # a worker-side frame, not just consumer
-
-
-@pytest.mark.slow
-@pytest.mark.process_pool
-def test_reader_transport_sweep_smoke(synthetic_dataset):
-    """The sweep behind transport='auto' (thread vs process x {zmq, shm})
-    runs end-to-end at tiny cycle counts: three configs, fresh subprocess
-    each, PETASTORM_TPU_TRANSPORT pinned per config, positive throughput."""
-    from petastorm_tpu.benchmark.transport_bench import reader_transport_sweep
-    out = reader_transport_sweep(synthetic_dataset.url, workers=2,
-                                 warmup=5, measure=40, reruns=1)
-    assert set(out) == {"thread_x2", "process_x2_zmq", "process_x2_shm"}
-    for config, samples in out.items():
-        assert len(samples) == 1 and samples[0] > 0, (config, samples)

@@ -214,248 +214,3 @@ def test_user_codec_receives_bytes_not_memoryview(tmp_path):
                          reader_pool_type=pool, workers_count=2) as reader:
             rows = sorted(reader, key=lambda r: r.id)
         assert [r.blob for r in rows] == [bytes([i, i]) for i in range(20)]
-
-
-def test_scalar_bench_generate_and_measure(tmp_path):
-    """The scalar columnar bench runs end to end on a tiny store and the
-    generated store is plain Parquet (no petastorm sidecars)."""
-    import os
-
-    from petastorm_tpu.benchmark.scalar_bench import (batched_loader_throughput,
-                                                      generate_scalar_dataset)
-    url = f"file://{tmp_path}/scalar"
-    generate_scalar_dataset(url, rows=2000, float_cols=3, int_cols=2,
-                            row_group_size=256)
-    assert os.path.exists(f"{tmp_path}/scalar/part0.parquet")
-    assert not os.path.exists(f"{tmp_path}/scalar/_common_metadata")
-    sps = batched_loader_throughput(url, batch_size=128, workers_count=2,
-                                    warmup_batches=2, measure_batches=10)
-    assert sps > 0
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("echo", [1, 2])
-def test_imagenet_bench_runs_on_cpu(tmp_path, echo):
-    """run_imagenet_bench (the BENCH artifact's target workload) executes
-    end to end on CPU with a small image size and reports stall+throughput
-    — at the default echo=1 (every production caller's honest feed rate)
-    and with image-regime data echoing wired through."""
-    from petastorm_tpu.benchmark.imagenet_bench import (run_imagenet_bench,
-                                                        write_synthetic_imagenet)
-    url = f"file://{tmp_path}/imgnet48"
-    write_synthetic_imagenet(url, rows=64, classes=4, rows_per_row_group=32,
-                             image_size=48)
-    r = run_imagenet_bench(url, steps=3, per_device_batch=2, workers_count=2,
-                           pool_type="thread", echo=echo)
-    assert r["samples_per_sec"] > 0
-    assert 0.0 <= r["input_stall_pct"] <= 100.0
-    assert r["global_batch"] == 2 * r["devices"]
-    assert r["echo"] == echo
-
-
-@pytest.mark.slow
-def test_llm_bench_runs_on_cpu(tmp_path):
-    """run_llm_bench (BASELINE config 5's pipeline: token store -> NGram
-    windows -> DataLoader -> llama AdamW step) executes end to end on CPU
-    with tiny shapes; echo>1 and the resident phase are exercised."""
-    from petastorm_tpu.benchmark.llm_bench import (run_llm_bench,
-                                                   write_token_store)
-    url = f"file://{tmp_path}/tok"
-    write_token_store(url, windows=16, window=16, vocab=128)
-    tiny = dict(vocab=128, dim=32, n_layers=2, n_heads=2, n_kv_heads=1,
-                hidden=64)
-    # batch must divide the data axis: the CPU conftest runs an 8-device
-    # virtual mesh, so the P("data") batch sharding is exercised for real
-    r = run_llm_bench(url, steps=2, batch_size=8, window=16,
-                      workers_count=2, echo=2, resident_steps=2,
-                      model_kwargs=tiny)
-    assert r["tokens_per_step"] == 128 and r["echo"] == 2
-    assert r["tokens_per_sec"] > 0
-    assert 0.0 <= r["input_stall_pct"] <= 100.0
-    assert np.isfinite(r["loss_first"]) and np.isfinite(r["loss_last"])
-    assert r["step_time_ms_resident"] > 0
-
-
-def test_peak_flops_lookup():
-    """One table keyed by the exact device_kind the chip reports: the CPU
-    platform has no peak, and an unknown accelerator kind is an error —
-    never a substring guess, an environment override or a silent None."""
-    from petastorm_tpu.benchmark.imagenet_bench import _peak_flops
-
-    assert _peak_flops("tpu", "TPU v5 lite") == 197e12
-    assert _peak_flops("cpu", "cpu") is None
-    for kind in ("TPU v5", "TPU v5p", "tpu v5 lite", "TPU v9000", ""):
-        with pytest.raises(ValueError, match="no bf16 peak known"):
-            _peak_flops("tpu", kind)
-
-
-def test_peak_flops_env_override_is_gone(monkeypatch):
-    from petastorm_tpu.benchmark.imagenet_bench import _peak_flops
-
-    monkeypatch.setenv("PETASTORM_TPU_PEAK_FLOPS", "1.5e14")
-    assert _peak_flops("tpu", "TPU v5 lite") == 197e12
-
-
-def test_bench_embedded_children_compile_and_run():
-    """bench.py builds its subprocess phases as code strings; a signature
-    drift would only explode at round-bench time. Compile every embedded
-    child, and run the _cpu_subprocess plumbing end-to-end on a stub."""
-    import importlib.util
-    import pathlib
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_under_test",
-        pathlib.Path(__file__).parent.parent / "bench.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    src = (pathlib.Path(__file__).parent.parent / "bench.py").read_text()
-    import ast
-    tree = ast.parse(src)
-    children = [n.value for n in ast.walk(tree)
-                if isinstance(n, ast.Constant) and isinstance(n.value, str)
-                and "print('BENCHJSON:'" in n.value]  # code, not docstrings
-    # scalar phase + best_config sweep at least
-    assert len(children) >= 2
-    for child in children:
-        compile(child, "<bench-child>", "exec")
-        assert "jax.config.update('jax_platforms', 'cpu')" in child
-
-    out = bench._cpu_subprocess(
-        "import json\nprint('BENCHJSON:' + json.dumps({'ok': 1}))\n",
-        data_dir="/tmp", timeout_s=60.0)
-    assert out == {"ok": 1}
-
-
-def test_bench_main_flow_host_phases_and_dispersion(monkeypatch, capsys,
-                                                   tmp_path):
-    """Flow-level guard for bench.main(): it is a host-side micro-benchmark
-    — every JAX-touching phase goes through the CPU-pinned subprocess, it
-    writes no chip-named key (no imagenet_* stand-in, no carried evidence)
-    — and dispersion keys land next to each multi-rerun phase. All heavy
-    phases are stubbed."""
-    import importlib.util
-    import pathlib
-    import types
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_flow_under_test",
-        pathlib.Path(__file__).parent.parent / "bench.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    import petastorm_tpu.benchmark.hello_world as hw
-    import petastorm_tpu.benchmark.scalar_bench as sb
-    import petastorm_tpu.benchmark.throughput as tp
-    monkeypatch.setattr(hw, "generate_hello_world_dataset",
-                        lambda *a, **k: None)
-    monkeypatch.setattr(sb, "generate_scalar_dataset", lambda *a, **k: None)
-    seq = iter([700.0, 710.0, 690.0, 705.0, 702.0,   # hello_world x5
-                4000.0, 4100.0, 3900.0])             # 10k x3
-    monkeypatch.setattr(
-        tp, "reader_throughput",
-        lambda *a, **k: types.SimpleNamespace(samples_per_second=next(seq)))
-
-    children = []
-
-    def fake_cpu_subprocess(child, data_dir, timeout_s=0):
-        children.append(child)
-        if "batched_loader_throughput" in child:
-            return {"samples": [50000.0, 52000.0]}
-        if "stall_pct_at_" in child:
-            return {"stall_pct_at_5ms": 30.2, "step_ms_actual_at_5ms": 5.9,
-                    "stall_pct_at_10ms": 0.9, "step_ms_actual_at_10ms": 10.4,
-                    "stall_pct_at_20ms": 1.8, "step_ms_actual_at_20ms": 20.1}
-        return {"config": "thread_pool+workers=3",
-                "samples": {"thread_pool+workers=3": [5000.0, 5100.0]}}
-    monkeypatch.setattr(bench, "_cpu_subprocess", fake_cpu_subprocess)
-    # Pin the prior-round artifact: the real glob would read whatever
-    # BENCH_r*.json is newest in the repo root, coupling this test to each
-    # round's committed numbers.
-    monkeypatch.setattr(
-        bench, "_prior_round_artifact",
-        lambda: ("BENCH_rXX.json",
-                 {"value_p50": 2000.0, "value_spread_pct": 10.0,
-                  "hello_world_10k_samples_per_sec_p50": 4100.0,
-                  "hello_world_10k_samples_per_sec_spread_pct": 30.0}))
-    monkeypatch.setenv("BENCH_DATA_DIR", str(tmp_path))
-    # markers exist -> _ensure skips generation
-    for d in ("hello_world", "hello_world_10k", "scalar_100k"):
-        (tmp_path / d).mkdir()
-        (tmp_path / d / "_common_metadata").write_text("x")
-    (tmp_path / "scalar_100k" / "part0.parquet").write_text("x")
-
-    assert bench.main() == 0
-    out = capsys.readouterr().out.strip().splitlines()[-1]
-    import json as json_mod
-    parsed = json_mod.loads(out)
-
-    # host-side only: no train-step child, no chip-named or carried key
-    assert children and not any("run_imagenet_bench" in c or
-                                "run_llm_bench" in c for c in children)
-    assert all("jax.config.update('jax_platforms', 'cpu')" in c
-               for c in children if "import jax" in c)
-    assert not [k for k in parsed
-                if k.startswith("imagenet_") or "evidence" in k]
-
-    # dispersion keys alongside the best-of-N values
-    assert parsed["value"] == 710.0
-    assert parsed["value_p50"] == 702.0
-    assert parsed["value_spread_pct"] == pytest.approx(2.8, abs=0.1)
-    assert parsed["hello_world_10k_samples_per_sec"] == 4100.0
-    assert parsed["hello_world_10k_samples_per_sec_p50"] == 4000.0
-    assert "scalar_batched_samples_per_sec_p50" in parsed
-    assert "best_config_samples_per_sec_p50" in parsed
-    assert parsed["best_config_sweep"] == {"thread_pool+workers=3": 5100.0}
-
-    # stall sweep keys + the derived <5%-stall boundary (round-4 verdict
-    # item 2): 5ms stalls 30%, 10ms is the first step under 5%
-    assert parsed["stall_pct_at_5ms"] == 30.2
-    assert parsed["stall_pct_at_10ms"] == 0.9
-    assert parsed["min_step_ms_under_5pct_stall"] == 10
-
-    # cross-round regression guard against the pinned synthetic prior:
-    # the stubbed 710-sps headline is a big drop (flagged); the 10k phase
-    # sits within its noise bound (not flagged)
-    assert parsed["vs_prior_round"]["against"] == "BENCH_rXX.json"
-    assert "value" in parsed["regressions"]
-    assert "hello_world_10k_samples_per_sec" not in parsed["regressions"]
-
-
-def test_transport_bench_ring_vs_pipe_roundtrip():
-    """The transport micro-bench (shm ring vs pipe) produces sane rows and
-    a markdown table at tiny sizes — guards the producer/consumer protocol
-    and the ShmRing binding it drives."""
-    from petastorm_tpu.benchmark import transport_bench as tb
-    from petastorm_tpu.native import ring_available
-
-    if not ring_available():
-        import pytest as _pytest
-        _pytest.skip("native ring unavailable on this host")
-    rows = [tb.pipe_throughput(512, 64), tb.ring_throughput(512, 64),
-            tb.ring_throughput(512, 64, zero_copy=True)]
-    for r in rows:
-        assert r["items"] == 64
-        assert r["items_per_sec"] > 0 and r["mb_per_sec"] > 0
-    md = tb.to_markdown(rows)
-    assert "ring speedup" in md and "0 KB |" in md  # 512B renders as 0 KB
-
-
-@pytest.mark.slow
-def test_llm_bench_flash_attention_wiring(tmp_path):
-    """flash=True swaps the Pallas kernel (interpret mode on CPU) into the
-    llm bench's train step; losses must match the dense-attention run."""
-    from petastorm_tpu.benchmark.llm_bench import (run_llm_bench,
-                                                   write_token_store)
-    url = f"file://{tmp_path}/tok"
-    write_token_store(url, windows=16, window=16, vocab=128)
-    tiny = dict(vocab=128, dim=32, n_layers=1, n_heads=2, n_kv_heads=1,
-                hidden=64)
-    rf = run_llm_bench(url, steps=2, batch_size=8, window=16,
-                       workers_count=2, flash=True, xent_chunk=32,
-                       model_kwargs=tiny)
-    rd = run_llm_bench(url, steps=2, batch_size=8, window=16,
-                       workers_count=2, flash=False, model_kwargs=tiny)
-    assert rf["flash"] is True and rd["flash"] is False
-    assert abs(rf["loss_first"] - rd["loss_first"]) < 2e-2
-    assert abs(rf["loss_last"] - rd["loss_last"]) < 2e-2

@@ -117,19 +117,37 @@ def test_recorder_ring_bound_and_dropped_count():
 
 
 def test_recorder_disabled_hot_path_overhead():
-    """The satellite's contract: a disabled recorder must cost well under a
-    few µs per batch. Measured over 10k no-op spans; the bound is ~50x the
-    typical cost so a loaded CI host cannot flake it, while a regression to
-    per-call allocation/locking would still blow through it."""
+    """What a disabled recorder owes the hot path: 10k spans leave the ring
+    empty, drop nothing and never take the ring's lock (each span still
+    times itself for its site's counters; no duration is asserted)."""
     registry = TelemetryRegistry()
-    registry.recorder.disable()
-    n = 10_000
-    t0 = time.perf_counter()
-    for _ in range(n):
-        with registry.span("hot"):
+    recorder = registry.recorder
+    recorder.disable()
+
+    class CountingLock:
+        def __init__(self, lock):
+            self._lock, self.acquisitions = lock, 0
+
+        def __enter__(self):
+            self.acquisitions += 1
+            return self._lock.__enter__()
+
+        def __exit__(self, *exc):
+            return self._lock.__exit__(*exc)
+
+    recorder._lock = lock = CountingLock(recorder._lock)
+    for _ in range(10_000):
+        with registry.span("hot") as live:
             pass
-    per_call = (time.perf_counter() - t0) / n
-    assert per_call < 5e-6, f"disabled span cost {per_call * 1e6:.2f}µs/call"
+    assert live.duration_s >= 0.0          # the span still timed itself
+    assert lock.acquisitions == 0
+    assert recorder.spans() == [] and recorder.dropped == 0
+    recorder.enable()                  # the same span, enabled, is counted
+    before = lock.acquisitions
+    with registry.span("hot"):
+        pass
+    assert lock.acquisitions == before + 1
+    assert [s.name for s in recorder.spans()] == ["hot"]
 
 
 # --------------------------------------------------------------------------
@@ -790,12 +808,12 @@ def test_repo_hot_path_is_monotonic_clean():
 
 
 # --------------------------------------------------------------------------
-# bench.py integration surface: the stage-breakdown keys bench emits
+# the CLI's per-stage rendering
 # --------------------------------------------------------------------------
 
 def test_stage_breakdown_keys_match_cli_stage_order():
-    """bench.py's stage_breakdown block and the CLI's per-stage rendering
-    both derive from the documented metric schema — keep them coherent."""
+    """The CLI's stage breakdown derives from the documented metric
+    schema: every key it renders is a stage of ``_STAGE_ORDER``."""
     from petastorm_tpu.telemetry.__main__ import _STAGE_ORDER, _stage_breakdown
     reg = _populated_registry()
     reg.counter("loader.shuffle_s").add(0.1)

@@ -7,8 +7,7 @@ segments, the consumer deserializes numpy views over the mapped Arrow
 buffers, and ``jax.dlpack`` adopts big host buffers into device arrays.
 One careless ``bytes(view)`` / ``.tobytes()`` / ``np.copy`` on that path
 quietly reintroduces the full-payload copy the whole plane exists to
-eliminate — and nothing fails, it just gets slower (the exact regression
-BENCH_r03–r05 measured as the process pool's 3.4x loss).
+eliminate — and nothing fails, it just gets slower.
 
 So the hot-path transport modules are held to an explicit-copy rule: every
 ``bytes(...)`` call, ``.tobytes()`` call, ``.to_pybytes()`` call, and
