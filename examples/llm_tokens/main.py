@@ -50,7 +50,9 @@ def train(url: str, steps: int = 40, batch_size: int = 8, window: int = 4,
     # For long contexts, the same model exposes three levers this example
     # keeps off at its toy scale: make_train_step(xent_chunk=...) (chunked
     # cross-entropy, no (b, s, V) logits), remat_layers=True (per-layer
-    # jax.checkpoint), and attn_fn=make_flash_attention() (O(seq) memory)
+    # jax.checkpoint that keeps the flash kernel's output, so the backward
+    # pass recomputes the block but launches no second forward kernel), and
+    # attn_fn=make_flash_attention() (O(seq) memory)
     # (see the docstrings of llama.loss_fn and llama.apply).
     cfg = llama.LlamaConfig(vocab=vocab, dim=128, n_layers=2, n_heads=8,
                             n_kv_heads=4, hidden=256)

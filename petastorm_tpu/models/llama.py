@@ -25,6 +25,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from petastorm_tpu.ops.flash_attn import SAVED_NAMES
+
+# The policy of ``apply(remat_layers=True)``: recompute the block, except
+# what the attention kernel already wrote.
+_SAVE_ATTENTION = jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES)
+
 
 @dataclass(frozen=True)
 class LlamaConfig:
@@ -623,9 +629,16 @@ def apply(params, tokens, cfg: LlamaConfig, attn_fn=None,
         (pass a :func:`petastorm_tpu.parallel.pipeline.make_pipeline`
         wrapper over :func:`apply_block` with stacked stage params).
     :param remat_layers: wrap each transformer block in ``jax.checkpoint``
-        (the long-context memory lever: only layer-boundary activations
-        are saved; the backward recomputes each block). Applies to the
-        sequential layer loop only — a ``layers_fn`` (pipeline
+        (the long-context memory lever: the backward recomputes each
+        block from its layer-boundary activation). One thing is saved
+        beside the boundaries: the output and row logsumexp that the
+        Pallas flash kernel's forward rule names
+        (:data:`petastorm_tpu.ops.flash_attn.SAVED_NAMES`; a bf16
+        activation and ``(b, heads, seq)`` float32 a layer), so the
+        recomputation launches no second forward kernel. An attention
+        that does not run that kernel (dense, ring) names nothing and is
+        recomputed whole. Applies
+        to the sequential layer loop only — a ``layers_fn`` (pipeline
         parallelism) owns its own rematerialization and combining the
         two is rejected below.
     :param embed_lookup: ``"gather"`` (default) | ``"onehot"``. A plain
@@ -665,11 +678,12 @@ def apply(params, tokens, cfg: LlamaConfig, attn_fn=None,
                 expert_spec=expert_spec, layer_idx=li,
                 window_attn_fn=window_attn_fn, with_stats=with_stats)
             if remat_layers:
-                # Long-context lever: save only layer-boundary activations;
-                # the backward recomputes each block (jax.checkpoint trades
-                # one extra forward per block for O(layers) less residual
-                # HBM).
-                one_block = jax.checkpoint(one_block)
+                # Long-context lever: save the layer-boundary activations
+                # and what the Pallas attention kernel names (its output
+                # and row statistics, one activation's size); the backward
+                # recomputes the rest of each block, but launches no
+                # second forward kernel to remake what the first one wrote.
+                one_block = jax.checkpoint(one_block, policy=_SAVE_ATTENTION)
             x, layer_aux, *layer_stats = one_block(layer, x)
             aux = aux + layer_aux
             stats.extend(layer_stats)

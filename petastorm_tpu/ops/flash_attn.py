@@ -28,7 +28,13 @@ HBM; this kernel never does. Design (flash-attention-2 style, TPU-first):
   kv-innermost pass accumulates dQ and a q-innermost pass accumulates
   dK/dV — with grouped-query head gradients summed inside the kernel by
   walking every (group head, q block) pair over one K/V tile. No
-  O(seq^2) or O(block*seq) tensors touch HBM in training either;
+  O(seq^2) or O(block*seq) tensors touch HBM in training either. The
+  forward rule names ``o`` and ``lse`` (:data:`SAVED_NAMES`, via
+  ``jax.ad_checkpoint.checkpoint_name``): a ``jax.checkpoint`` around the
+  caller whose policy saves those names keeps the two arrays only the
+  kernel can make, and its recomputation launches no second forward
+  (``llama.apply(remat_layers=True)`` does); under a bare checkpoint, or
+  none, the names are identities;
 * on the ``cpu`` backend the kernel runs in Pallas interpret mode
   (tests); every other backend compiles it or raises. Shapes that don't
   tile cleanly (seq not divisible by an 8-aligned block, or ``causal``
@@ -50,6 +56,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
+
+# What :func:`_flash_vjp_fwd` names: the attention output and its rows'
+# logsumexp. ``jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES)``
+# keeps them across a checkpoint (``llama.apply(remat_layers=True)``).
+SAVED_NAMES = ("flash_attn_out", "flash_attn_lse")
 
 _DEFAULT_BLOCK = 128
 # Launch defaults: bigger tiles amortize per-program overhead (an 8k seq
@@ -613,13 +625,20 @@ def _flash_vjp_fwd(causal, block_q, block_k, interpret, window, q, k, v):
     # recompute (the old chunked-dense bwd re-ran the whole attention).
     o, lse = _flash_forward_lse(q, k, v, causal, block_q, block_k,
                                 interpret, window)
+    # Named for a checkpoint around the caller to keep (SAVED_NAMES). The
+    # primal output is the named ``o``, so that what follows the kernel
+    # reads the saved array too and the recomputation needs no second
+    # launch. ``lse`` is kept as (b, h, sq): the trailing 1 of its kernel
+    # layout pads to a lane tile of 128 in HBM.
+    o = checkpoint_name(o, SAVED_NAMES[0])
+    lse = checkpoint_name(lse[..., 0], SAVED_NAMES[1])
     return o, (q, k, v, o, lse)
 
 
 def _flash_vjp_bwd(causal, block_q, block_k, interpret, window, residual, g):
     q, k, v, o, lse = residual
-    return _flash_backward(q, k, v, o, lse, g, causal, block_q, block_k,
-                           interpret, window)
+    return _flash_backward(q, k, v, o, lse[..., None], g, causal, block_q,
+                           block_k, interpret, window)
 
 
 _flash_vjp.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)

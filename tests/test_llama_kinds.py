@@ -6,6 +6,7 @@ numbers held bit for bit through the refactor."""
 import hashlib
 import json
 import os
+import re
 from functools import partial
 
 import jax
@@ -332,11 +333,16 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
 
 # sha256 over the gradient leaves' bytes and the loss as a hex float, read
 # on the commit before the block was rebuilt (04b7841), same construction.
+# The flash digest is of the program whose checkpoint keeps the kernel's
+# output (PR 32): the backward reads a saved array where it read a
+# recomputed one, and XLA's CPU backend then fuses the bf16 arithmetic
+# around it otherwise and rounds in other places (in float32 the gradients
+# stayed bit-equal; the dense program, which names nothing, did not move).
 MISTRAL_TOY = {
     "dense": ("0x1.7d6c0e0000000p+2", "83b2d19b59573ebdcec3e1ef7c8b4a26"
               "043b297a259855c9564642a1928e8b93"),
-    "flash": ("0x1.7d6bd40000000p+2", "661101d140d4679da6c5fc5ff02b0390"
-              "49d3dd263ba9a9c293054487b09b5087"),
+    "flash": ("0x1.7d6bd40000000p+2", "7cef7d142f1ff124d664e75095e27541"
+              "30b6844181bd5ffd72260d34073bb947"),
 }
 
 
@@ -434,3 +440,94 @@ def test_step_statistics_become_the_registrys_counters(row_tile):
             llama.init_params(jax.random.PRNGKey(0), llama.TINY)),
         {"tokens": tokens})
     assert int(none["rows_buffer"].sum()) == 0
+
+
+# What ``remat_layers`` keeps across a block's checkpoint: (attention kind x
+# FFN kind) at toy sizes, two layers, the Pallas kernels interpreted.
+@pytest.fixture(params=[(attention, ffn) for attention in ("full", "window")
+                        for ffn in ("dense_ffn", "experts")],
+                ids="-".join)
+def remat_case(request, short_tile):
+    """``(loss(params, remat_layers, flash=True) -> scalar, params, the
+    kernels' name prefix)`` of one kind of block."""
+    attention, ffn = request.param
+    windowed = int(attention == "window")
+    if ffn == "experts":
+        cfg = program_config(toy_sizes(
+            num_hidden_layers=2, sliding_window_layout=[windowed] * 2))
+    else:
+        cfg = llama.LlamaConfig(
+            vocab=256, dim=64, n_layers=2, n_heads=8, n_kv_heads=4,
+            hidden=128, sliding_window_layout=(windowed,) * 2,
+            sliding_window=32)
+    kernels = dict(
+        attn_fn=make_flash_attention(block_q=32, block_k=64),
+        window_attn_fn=make_flash_attention(window=32, block_q=32,
+                                            block_k=64))
+    params = llama.init_params(jax.random.PRNGKey(3), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (2, 128), 0, 256,
+                                jnp.int32)
+
+    def loss(p, remat_layers, flash=True):
+        return llama.loss_fn(p, {"tokens": tokens}, cfg, shift="roll",
+                             xent_chunk=64, compute_dtype=jnp.float32,
+                             remat_layers=remat_layers,
+                             **(kernels if flash else {}))
+    return loss, params, ("swa" if windowed else "flash")
+
+
+def grad_program(loss, params, **kw) -> str:
+    return str(jax.make_jaxpr(jax.grad(partial(loss, **kw)))(params))
+
+
+def kernel_calls(program: str, name: str) -> int:
+    return len(re.findall(rf"\bname={name}\b", program))
+
+
+def test_remat_launches_each_forward_attention_kernel_once(remat_case,
+                                                           monkeypatch):
+    """The block's checkpoint keeps what the kernel's forward rule names, so
+    the gradient program holds one forward call a layer; the bare checkpoint
+    (the program before PR 32) holds a second one for the recomputation."""
+    loss, params, kernel = remat_case
+    kept = grad_program(loss, params, remat_layers=True)
+    assert kernel_calls(kept, f"{kernel}_fwd") == 2
+    assert kernel_calls(kept, f"{kernel}_bwd_dq") == 2
+    assert kernel_calls(kept, f"{kernel}_bwd_dkv") == 2
+    # Without a checkpoint the names are identities: one call a layer.
+    assert kernel_calls(grad_program(loss, params, remat_layers=False),
+                        f"{kernel}_fwd") == 2
+    monkeypatch.setattr(llama, "_SAVE_ATTENTION", None)
+    bare = grad_program(loss, params, remat_layers=True)
+    assert kernel_calls(bare, f"{kernel}_fwd") == 4
+    assert kernel_calls(bare, f"{kernel}_bwd_dq") == 2
+
+
+def test_remat_with_the_kept_output_gives_the_same_gradients(remat_case):
+    loss, params, _ = remat_case
+    want, want_grads = jax.jit(jax.value_and_grad(
+        partial(loss, remat_layers=False)))(params)
+    got, grads = jax.jit(jax.value_and_grad(
+        partial(loss, remat_layers=True)))(params)
+    assert float(got) == float(want)
+    want_grads = leaves(want_grads)
+    for name, leaf in leaves(grads).items():
+        np.testing.assert_allclose(np.asarray(leaf),
+                                   np.asarray(want_grads[name]), atol=1e-6,
+                                   err_msg=name)
+
+
+def test_remat_of_dense_attention_is_the_program_it_was(remat_case,
+                                                        monkeypatch):
+    """Dense attention names nothing, so the policy has nothing to keep:
+    the gradient program is the bare checkpoint's, equation for equation."""
+    loss, params, _ = remat_case
+
+    def program():
+        return re.sub(r"policy=[^\n]*", "", grad_program(
+            loss, params, remat_layers=True, flash=False))
+
+    with_policy = program()
+    assert "pallas_call" not in with_policy
+    monkeypatch.setattr(llama, "_SAVE_ATTENTION", None)
+    assert with_policy == program()
