@@ -4,9 +4,15 @@
 * RMSNorm (float32 stats), RoPE, grouped-query attention, SwiGLU MLP;
 * one block function built from (attention kind x FFN kind): per layer,
   rotary positions or none and full or sliding-window attention
-  (``rope_layout`` / ``sliding_window_layout``); per model, a dense MLP,
+  (``rope_layout`` / ``sliding_window_layout``), or for the whole model
+  EVA's one softmax over a block's own keys and the earlier blocks' chunk
+  summaries (``attention="eva"``, :mod:`petastorm_tpu.ops.eva_attn`); per
+  model, a dense MLP,
   the ``soft`` / ``switch`` expert paths, or the dropless top-k expert
   layer that is told which experts it holds (``n_router_outputs``);
+* optionally RMSNorm scales stored as offsets from one
+  (``norm_unit_offset``) and ``n_pred_heads`` output heads that predict
+  the next 1..n tokens in the loss head's one pass;
 * bfloat16 activations, float32 master params;
 * **3-D parallelism layout**: batch on ``data``, sequence on ``seq``
   (ring attention over the ICI ring — :mod:`petastorm_tpu.parallel.ring_attention`),
@@ -84,6 +90,25 @@ class LlamaConfig:
     expert_hidden: int = 0
     expert_act: str = "silu"
     router_input: str = "mlp_norm"
+    # "softmax": one softmax over every earlier key (or the window's).
+    # "eva": every layer attends within blocks of eva_window positions and
+    # to one learned summary per eva_chunk keys of each earlier block
+    # (per-head ``eva_phi``, ``eva_mu`` leaves; one KV head a query head).
+    attention: str = "softmax"
+    eva_window: int = 0
+    eva_chunk: int = 0
+    # RMSNorm multiplies by 1 + g, g drawn as zeros (else by g, drawn as
+    # ones).
+    norm_unit_offset: bool = False
+    # Output heads: head m (0-based) of ``lm_head``'s n_pred_heads x vocab
+    # columns predicts the token m + 1 positions on; the loss is the mean
+    # over heads and positions that have their target in the window.
+    n_pred_heads: int = 1
+    # The residual adds in float32: each branch's closing product leaves
+    # its float32 accumulator unrounded, is added to the stream widened
+    # to float32, and the sum is rounded once to the stream's dtype (else
+    # the product is rounded first and the add is the compute dtype's).
+    fp32_skip_add: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -98,6 +123,21 @@ class LlamaConfig:
         if self.sliding_window_layout and any(self.sliding_window_layout) \
                 and not self.sliding_window:
             raise ValueError("sliding_window_layout needs sliding_window")
+        if self.attention not in ("softmax", "eva"):
+            raise ValueError(f"unknown attention {self.attention!r}")
+        if self.attention == "eva":
+            if (self.eva_chunk < 1 or self.eva_window % self.eva_chunk
+                    or self.eva_window < self.eva_chunk):
+                raise ValueError(f"eva_window ({self.eva_window}) must be a "
+                                 f"multiple of eva_chunk ({self.eva_chunk})")
+            if self.n_kv_heads != self.n_heads or self.sliding_window_layout:
+                raise ValueError("EVA attention takes one KV head a query "
+                                 "head and no sliding-window layers")
+        if self.n_pred_heads < 1:
+            raise ValueError(f"n_pred_heads ({self.n_pred_heads}) < 1")
+        if self.fp32_skip_add and (self.n_experts or self.n_router_outputs):
+            raise ValueError("fp32_skip_add is the dense FFN's: the expert "
+                             "layers add their own sums")
         if self.n_router_outputs:
             if self.n_experts:
                 raise ValueError("n_router_outputs (dropless experts) and "
@@ -120,7 +160,8 @@ class LlamaConfig:
 
     def attention_kind(self, layer_idx: int) -> tuple:
         """``(rope, window)`` of layer ``layer_idx``: whether it rotates q
-        and k, and its sliding window (None = full causal attention)."""
+        and k, and its sliding window (None = full causal attention, or
+        EVA's where ``attention == "eva"``)."""
         rope = self.rope_layout is None or self.rope_layout[layer_idx]
         windowed = (self.sliding_window_layout is not None
                     and self.sliding_window_layout[layer_idx])
@@ -140,22 +181,26 @@ def init_params(rng_key, cfg: LlamaConfig):
     def mat(key, fan_in, fan_out):
         return jax.random.normal(key, (fan_in, fan_out), jnp.float32) / np.sqrt(fan_in)
 
+    def norm_scale():
+        return (jnp.zeros if cfg.norm_unit_offset else jnp.ones)(
+            (cfg.dim,), jnp.float32)
+
     params = {
         "embed": jax.random.normal(next(keys), (cfg.vocab, cfg.dim),
                                    jnp.float32) * cfg.embed_std,
         "layers": [],
-        "norm_out": jnp.ones((cfg.dim,), jnp.float32),
-        "lm_head": mat(next(keys), cfg.dim, cfg.vocab),
+        "norm_out": norm_scale(),
+        "lm_head": mat(next(keys), cfg.dim, cfg.n_pred_heads * cfg.vocab),
     }
     hd = cfg.head_dim
     for li in range(cfg.n_layers):
         layer = {
-            "attn_norm": jnp.ones((cfg.dim,), jnp.float32),
+            "attn_norm": norm_scale(),
             "wq": mat(next(keys), cfg.dim, cfg.n_heads * hd),
             "wk": mat(next(keys), cfg.dim, cfg.n_kv_heads * hd),
             "wv": mat(next(keys), cfg.dim, cfg.n_kv_heads * hd),
             "wo": mat(next(keys), cfg.n_heads * hd, cfg.dim),
-            "mlp_norm": jnp.ones((cfg.dim,), jnp.float32),
+            "mlp_norm": norm_scale(),
         }
         if cfg.n_router_outputs or _is_moe_layer(cfg, li):
             # Dropless: the router keeps its full width, the experts are
@@ -176,6 +221,13 @@ def init_params(rng_key, cfg: LlamaConfig):
             layer["w1"] = mat(next(keys), cfg.dim, cfg.hidden)   # gate
             layer["w3"] = mat(next(keys), cfg.dim, cfg.hidden)   # up
             layer["w2"] = mat(next(keys), cfg.hidden, cfg.dim)   # down
+        if cfg.attention == "eva":
+            # The chunk summaries' per-head pooling direction and key
+            # offset: clip(N(0, 1), -1, 1) / sqrt(head_dim).
+            for name, key in zip(("eva_phi", "eva_mu"),
+                                 jax.random.split(next(keys))):
+                layer[name] = jnp.clip(jax.random.normal(
+                    key, (cfg.n_heads, hd), jnp.float32), -1, 1) / np.sqrt(hd)
         params["layers"].append(layer)
     return params
 
@@ -209,6 +261,10 @@ def _param_pspec_tuples(cfg: LlamaConfig, model_axis):
         "ew3": (m, None, None),
         "ew2": (m, None, None),
     }
+    if cfg.attention == "eva":
+        # Heads are sharded with the projections' columns.
+        for layer in (dense_layer, moe_layer):
+            layer.update(eva_phi=(m, None), eva_mu=(m, None))
     return {
         "embed": (m, None),     # vocab-sharded embedding
         "layers": [dict(moe_layer)
@@ -251,10 +307,24 @@ def param_shardings_fsdp(mesh, cfg: LlamaConfig, data_axis: str = "data",
                         is_leaf=lambda x: isinstance(x, tuple))
 
 
-def _rmsnorm(x, scale, eps):
+def _rmsnorm(x, scale, eps, unit_offset: bool = False):
+    """``unit_offset``: the stored scale is an offset from one."""
     x32 = x.astype(jnp.float32)
     inv = jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+    if unit_offset:
+        scale = 1.0 + scale
     return (x32 * inv * scale).astype(x.dtype)
+
+
+def _skip_add(x, h, w, fp32: bool = False):
+    """The residual add ``x + h @ w``. ``fp32`` (``cfg.fp32_skip_add``):
+    the product keeps its float32 accumulator, the add is float32 and the
+    sum is rounded once to the stream's dtype."""
+    if not fp32:
+        return x + h @ w.astype(h.dtype)
+    branch = jnp.matmul(h, w.astype(h.dtype),
+                        preferred_element_type=jnp.float32)
+    return (x.astype(jnp.float32) + branch).astype(x.dtype)
 
 
 def _rope(x, theta):
@@ -523,15 +593,16 @@ def _embed_lookup(embed, tokens, compute_dtype):
 
 def apply_block(layer, x, cfg: LlamaConfig, attn_fn=None, constrain=None,
                 expert_spec=None, layer_idx: int = 0, window_attn_fn=None,
-                with_stats: bool = False):
+                with_stats: bool = False, eva_attn_fn=None):
     """One transformer block (attention + MLP/MoE residuals) -> (x, aux),
     or (x, aux, stats) ``with_stats`` (:data:`MOE_STATS`, zeros where the
     FFN is not the dropless expert layer).
 
     The block is built from the layer's attention kind
     (``cfg.attention_kind(layer_idx)``: rotary positions or none, full or
-    sliding-window) and the model's FFN kind (dense, ``soft`` / ``switch``
-    experts, dropless held experts); the norms, the projections, the
+    sliding-window; or EVA's blocks and chunk summaries, through
+    ``eva_attn_fn(q, k, v, phi, mu)``) and the model's FFN kind (dense,
+    ``soft`` / ``switch`` experts, dropless held experts); the norms, the projections, the
     residuals and the sharding constraints are the same code for all.
     Shared by :func:`apply`'s sequential layer loop and GPipe pipeline
     stages (:mod:`petastorm_tpu.parallel.pipeline`), so a pipelined model
@@ -547,7 +618,7 @@ def apply_block(layer, x, cfg: LlamaConfig, attn_fn=None, constrain=None,
     gqa_native = fn is None or getattr(fn, "supports_gqa", False)
     aux = jnp.zeros((), jnp.float32)
     layer_input = x
-    h = _rmsnorm(x, layer["attn_norm"], cfg.norm_eps)
+    h = _rmsnorm(x, layer["attn_norm"], cfg.norm_eps, cfg.norm_unit_offset)
     b, s, _ = h.shape
     q = (h @ layer["wq"].astype(h.dtype)).reshape(b, s, cfg.n_heads, hd)
     k = (h @ layer["wk"].astype(h.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
@@ -557,13 +628,20 @@ def apply_block(layer, x, cfg: LlamaConfig, attn_fn=None, constrain=None,
     if not gqa_native and rep > 1:
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
-    with jax.named_scope("petastorm_tpu.attn_full" if window is None
-                         else "petastorm_tpu.attn_window"):
-        attn = (fn or partial(dense_attention, causal=True,
-                              window=window))(q, k, v)
+    if cfg.attention == "eva":
+        if eva_attn_fn is None:
+            from petastorm_tpu.ops.eva_attn import make_eva_attention
+            eva_attn_fn = make_eva_attention(cfg.eva_window, cfg.eva_chunk)
+        with jax.named_scope("petastorm_tpu.attn_eva"):
+            attn = eva_attn_fn(q, k, v, layer["eva_phi"], layer["eva_mu"])
+    else:
+        with jax.named_scope("petastorm_tpu.attn_full" if window is None
+                             else "petastorm_tpu.attn_window"):
+            attn = (fn or partial(dense_attention, causal=True,
+                                  window=window))(q, k, v)
     attn = attn.reshape(b, s, cfg.n_heads * hd)
-    x = constrain(x + attn @ layer["wo"].astype(attn.dtype))
-    h = _rmsnorm(x, layer["mlp_norm"], cfg.norm_eps)
+    x = constrain(_skip_add(x, attn, layer["wo"], cfg.fp32_skip_add))
+    h = _rmsnorm(x, layer["mlp_norm"], cfg.norm_eps, cfg.norm_unit_offset)
     stats = None
     if cfg.n_router_outputs:
         moe_out, stats = _dropless_moe_block(
@@ -585,7 +663,7 @@ def apply_block(layer, x, cfg: LlamaConfig, attn_fn=None, constrain=None,
     else:
         gate = jax.nn.silu(h @ layer["w1"].astype(h.dtype))
         up = h @ layer["w3"].astype(h.dtype)
-        x = constrain(x + (gate * up) @ layer["w2"].astype(h.dtype))
+        x = constrain(_skip_add(x, gate * up, layer["w2"], cfg.fp32_skip_add))
     if not with_stats:
         return x, aux
     if stats is None:
@@ -598,8 +676,8 @@ def apply(params, tokens, cfg: LlamaConfig, attn_fn=None,
           expert_spec=None, with_aux=False, layers_fn=None,
           embed_lookup: str = "gather", return_hidden: bool = False,
           remat_layers: bool = False, window_attn_fn=None,
-          with_stats: bool = False):
-    """tokens: (batch, seq) int32 -> logits (batch, seq, vocab)
+          with_stats: bool = False, eva_attn_fn=None):
+    """tokens: (batch, seq) int32 -> logits (batch, seq, n_pred_heads x vocab)
     (or the pre-lm_head hidden states when ``return_hidden`` — the
     chunked-cross-entropy path computes per-chunk logits itself).
 
@@ -615,6 +693,11 @@ def apply(params, tokens, cfg: LlamaConfig, attn_fn=None,
         ``make_flash_attention(window=cfg.sliding_window)``; ``None`` uses
         dense attention under the banded mask. ``attn_fn`` serves the
         full-attention layers.
+    :param eva_attn_fn: the attention callable of an ``attention="eva"``
+        model, ``(q, k, v, phi, mu) -> out``; ``None`` builds
+        :func:`petastorm_tpu.ops.eva_attn.make_eva_attention` from the
+        config (wrap that in ``jax.shard_map`` where the batch is sharded:
+        a Pallas call is not partitioned by ``jit``).
     :param with_stats: also return, last, the expert layers' statistics:
         ``{name: (n_layers,) int32}`` over :data:`MOE_STATS`.
     :param activation_spec: optional ``PartitionSpec`` for (b, s, d)
@@ -676,7 +759,8 @@ def apply(params, tokens, cfg: LlamaConfig, attn_fn=None,
             one_block = partial(
                 apply_block, cfg=cfg, attn_fn=attn_fn, constrain=constrain,
                 expert_spec=expert_spec, layer_idx=li,
-                window_attn_fn=window_attn_fn, with_stats=with_stats)
+                window_attn_fn=window_attn_fn, with_stats=with_stats,
+                eva_attn_fn=eva_attn_fn)
             if remat_layers:
                 # Long-context lever: save the layer-boundary activations
                 # and what the Pallas attention kernel names (its output
@@ -687,7 +771,7 @@ def apply(params, tokens, cfg: LlamaConfig, attn_fn=None,
             x, layer_aux, *layer_stats = one_block(layer, x)
             aux = aux + layer_aux
             stats.extend(layer_stats)
-    x = _rmsnorm(x, params["norm_out"], cfg.norm_eps)
+    x = _rmsnorm(x, params["norm_out"], cfg.norm_eps, cfg.norm_unit_offset)
     if not return_hidden:
         x = (x @ params["lm_head"].astype(x.dtype)).astype(jnp.float32)
     out = (x, aux) if with_aux else (x,)
@@ -702,29 +786,37 @@ def _head_xent_chunks(xf, head, targets, weights, with_grads):
     ``sum(weights * (logsumexp(x @ head) - (x @ head)[target]))``.
 
     xf: (n_chunks, chunk, dim) hidden states in the compute dtype; head:
-    (dim, vocab) master weights; targets, weights: (n_chunks, chunk).
-    With ``with_grads`` the same iteration that forms a chunk's logits
+    (dim, vocab) master weights; targets, weights: (n_chunks, chunk). With
+    several prediction heads, head is (dim, heads x vocab) and targets,
+    weights are (n_chunks, chunk, heads): a token's row of logits is read
+    as (heads, vocab) and each head has its own logsumexp, in the same
+    pass. With ``with_grads`` the same iteration that forms a chunk's logits
     also forms its ``d loss / d logits`` and multiplies it out, so the
     head product is never recomputed: returns ``(loss, dxf, dhead)``
     (``dhead`` a float32 carry), else ``loss`` alone.
     """
     head_c = head.astype(xf.dtype)
-    vocab_ids = jnp.arange(head.shape[1])
+    heads = targets.shape[2:]           # () or (n_pred_heads,)
+    vocab_ids = jnp.arange(head.shape[1] // int(np.prod(heads)))
 
     def chunk(carry, args):
         loss, dhead = carry
         xc, tc, wc = args
         logits = jax.lax.dot_general(xc, head_c, (((1,), (0,)), ((), ())),
                                      preferred_element_type=jnp.float32)
+        if heads:
+            logits = logits.reshape(*tc.shape, -1)
         lse = jax.nn.logsumexp(logits, axis=-1)
-        tl = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
+        tl = jnp.take_along_axis(logits, tc[..., None], axis=-1)[..., 0]
         loss = loss + jnp.sum(wc * (lse - tl))
         if not with_grads:
             return (loss, dhead), None
         # softmax - onehot, weighted, rounded to the compute dtype: what
         # autodiff's cotangent of ``.astype(float32)`` is.
-        dlogits = ((jnp.exp(logits - lse[:, None])
-                    - (vocab_ids == tc[:, None])) * wc[:, None]).astype(xc.dtype)
+        dlogits = ((jnp.exp(logits - lse[..., None])
+                    - (vocab_ids == tc[..., None])) * wc[..., None]).astype(xc.dtype)
+        if heads:
+            dlogits = dlogits.reshape(tc.shape[0], -1)
         # Both products below read dlogits. Without the barrier XLA fuses
         # the exp/onehot arithmetic into each of them and derives dlogits
         # twice from the float32 logits (ledger, PR 27 probes: 605.7 ms a
@@ -764,12 +856,24 @@ def _chunked_xent_bwd(residuals, g):
 _chunked_xent.defvjp(_chunked_xent_fwd, _chunked_xent_bwd)
 
 
+def _multi_head_targets(tokens, n_heads: int):
+    """Targets and mask of ``n_heads`` prediction heads over the FULL
+    window (the ``"roll"`` layout): ``targets[b, t, m] = tokens[b, t + 1 +
+    m]`` and ``mask[t, m] = 1`` where that position is inside the window
+    -> ``(b, s, heads)`` int, ``(s, heads)`` float32."""
+    s = tokens.shape[1]
+    targets = jnp.stack([jnp.roll(tokens, -(m + 1), axis=1)
+                         for m in range(n_heads)], axis=-1)
+    ahead = jnp.arange(s)[:, None] + 1 + jnp.arange(n_heads)[None, :]
+    return targets, (ahead < s).astype(jnp.float32)
+
+
 def loss_fn(params, batch, cfg: LlamaConfig, attn_fn=None, activation_spec=None,
             expert_spec=None, aux_weight: float = 1e-2, layers_fn=None,
             embed_lookup: str = "gather", compute_dtype=jnp.bfloat16,
             shift: str = "split", xent_chunk: int | None = None,
             remat_layers: bool = False, window_attn_fn=None,
-            with_stats: bool = False):
+            with_stats: bool = False, eva_attn_fn=None):
     """Next-token cross entropy (+ MoE load-balancing aux for switch
     dispatch). batch: {'tokens': (b, s) int32}. ``compute_dtype=float32``
     makes activation math exact — the PP-parity pinning mode (microbatched
@@ -791,6 +895,12 @@ def loss_fn(params, batch, cfg: LlamaConfig, attn_fn=None, activation_spec=None,
     ``with_stats`` returns ``(loss, stats)``: the expert layers'
     statistics of :func:`apply`, for ``value_and_grad(has_aux=True)``.
 
+    With ``cfg.n_pred_heads > 1`` (needs ``shift="roll"`` and
+    ``xent_chunk``) head ``m``
+    predicts the token ``m + 1`` positions on, and the loss is the mean
+    cross-entropy over the heads and the positions whose target lies in
+    the window (:func:`_multi_head_targets`).
+
     ``xent_chunk`` (must divide ``batch * model seq``) computes the loss
     head ``xent_chunk`` tokens at a time in one loop that, under ``grad``,
     also yields the hidden states' and the head's gradients: the
@@ -802,13 +912,17 @@ def loss_fn(params, batch, cfg: LlamaConfig, attn_fn=None, activation_spec=None,
     tokens = batch["tokens"]
     if shift not in ("split", "roll"):
         raise ValueError(f"unknown shift {shift!r}")
+    if cfg.n_pred_heads > 1 and (shift != "roll" or not xent_chunk):
+        raise ValueError("n_pred_heads > 1 needs shift='roll' (every head "
+                         "reads the full window) and xent_chunk (the heads "
+                         "share the chunked loss head's one pass)")
     inputs = tokens if shift == "roll" else tokens[:, :-1]
     run = partial(apply, params, inputs, cfg, attn_fn=attn_fn,
                   activation_spec=activation_spec, expert_spec=expert_spec,
                   with_aux=True, layers_fn=layers_fn,
                   embed_lookup=embed_lookup, compute_dtype=compute_dtype,
                   remat_layers=remat_layers, window_attn_fn=window_attn_fn,
-                  with_stats=with_stats)
+                  with_stats=with_stats, eva_attn_fn=eva_attn_fn)
     if xent_chunk:
         # Never materialize the (b, s, V) logits: at 32k context and 32k
         # vocab the full tensor is ~4.2 GB f32 (plus its cotangent), which
@@ -822,7 +936,9 @@ def loss_fn(params, batch, cfg: LlamaConfig, attn_fn=None, activation_spec=None,
         # (ledger, PR 27, mistral7b-tok4k-1chip, resident_step_ms.tokens).
         x, aux, *stats = run(return_hidden=True)
         b, s, dm = x.shape
-        if shift == "roll":
+        if cfg.n_pred_heads > 1:
+            targets, mask = _multi_head_targets(tokens, cfg.n_pred_heads)
+        elif shift == "roll":
             targets = jnp.roll(tokens, -1, axis=1)
             mask = (jnp.arange(s) < s - 1).astype(jnp.float32)
         else:
@@ -833,9 +949,9 @@ def loss_fn(params, batch, cfg: LlamaConfig, attn_fn=None, activation_spec=None,
             raise ValueError(f"xent_chunk ({xent_chunk}) must divide "
                              f"batch*seq ({n_tok})")
         # The weights carry the roll mask and the mean's denominator.
-        weights = jnp.broadcast_to(mask / (mask.sum() * b), (b, s))
-        chunks = (n_tok // xent_chunk, xent_chunk)
-        nll = _chunked_xent(x.reshape(*chunks, dm), params["lm_head"],
+        weights = jnp.broadcast_to(mask / (mask.sum() * b), (b, *mask.shape))
+        chunks = (n_tok // xent_chunk, xent_chunk, *mask.shape[1:])
+        nll = _chunked_xent(x.reshape(*chunks[:2], dm), params["lm_head"],
                             targets.reshape(chunks), weights.reshape(chunks))
         loss = nll + aux_weight * aux
         return (loss, stats[0]) if with_stats else loss
@@ -867,7 +983,7 @@ def make_train_step(cfg: LlamaConfig, learning_rate: float = 3e-4,
                     compute_dtype=jnp.bfloat16, shift: str = "split",
                     xent_chunk: int | None = None,
                     remat_layers: bool = False, window_attn_fn=None,
-                    with_stats: bool = False):
+                    with_stats: bool = False, eva_attn_fn=None):
     """AdamW train step via optax; jit with sharded params for TP/DP/SP.
     ``with_stats``: the step returns ``(params, opt_state, loss, stats)``,
     ``stats`` the expert layers' per-layer rows (:data:`MOE_STATS`): small
@@ -886,7 +1002,8 @@ def make_train_step(cfg: LlamaConfig, learning_rate: float = 3e-4,
                     embed_lookup=embed_lookup,
                     compute_dtype=compute_dtype, shift=shift,
                     xent_chunk=xent_chunk, remat_layers=remat_layers,
-                    window_attn_fn=window_attn_fn, with_stats=with_stats),
+                    window_attn_fn=window_attn_fn, with_stats=with_stats,
+                    eva_attn_fn=eva_attn_fn),
             has_aux=with_stats)(params, batch)
         updates, opt_state = tx.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)

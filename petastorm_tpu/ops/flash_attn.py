@@ -178,6 +178,43 @@ def _band(window, block_in: int, block_out: int, n_out: int, behind: int):
             walked)
 
 
+def _softmax_tile(q, k, v, mask, acc_ref, m_ref, l_ref, scale: float,
+                  rows_may_be_dead: bool = False):
+    """One (q tile, key tile) step of the online softmax, the tile body of
+    every forward kernel here and of :mod:`petastorm_tpu.ops.eva_attn`:
+    scores ``q k^T * scale`` through ``mask`` (a callable on the float32
+    score tile), then the running max ``m_ref``, normalizer ``l_ref`` and
+    unnormalized accumulator ``acc_ref`` (VMEM scratch) take the tile in.
+
+    Matmuls stay in the input dtype (bf16 on the training path) with f32
+    accumulation — the MXU's native mode; upcasting the operands to f32
+    first would run the systolic array at a fraction of peak. All softmax
+    bookkeeping (max, exp, normalizer) is f32."""
+    s = jax.lax.dot_general(                                     # (bq, bk)
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    s = mask(s)
+    m_prev, l_prev = m_ref[:, 0], l_ref[:, 0]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1))
+    # m_new is finite from the first live block (causal keeps the
+    # diagonal), so exp never sees inf-inf; a still--inf running max
+    # contributes alpha=0 exactly. Under a window a row's first live
+    # tile can lie wholly behind its own window (``rows_may_be_dead``):
+    # exponentiate against 0 there, so that p and alpha are 0 and not
+    # exp(inf - inf).
+    m_exp = jnp.where(m_new == -jnp.inf, 0.0, m_new) if rows_may_be_dead \
+        else m_new
+    p = jnp.exp(s - m_exp[:, None])
+    alpha = jnp.exp(m_prev - m_exp)
+    l_ref[:, 0] = l_prev * alpha + p.sum(axis=-1)
+    m_ref[:, 0] = m_new
+    # p rounds to the v dtype for the second MXU pass (standard flash
+    # practice: p is in [0, 1], the f32 accumulator absorbs the sum).
+    acc_ref[:] = acc_ref[:] * alpha[:, None] + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
                   block_k: int, causal: bool, scale: float,
                   emit_stats: bool = False, emit_lse: bool = False,
@@ -209,35 +246,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
 
     @pl.when(live)
     def _step():
-        # Matmuls stay in the input dtype (bf16 on the training path) with
-        # f32 accumulation — the MXU's native mode; upcasting the operands
-        # to f32 first would run the systolic array at a fraction of peak.
-        # All softmax bookkeeping (max, exp, normalizer) is f32.
-        q = q_ref[0, 0, :, :]                                    # (bq, d)
-        k = k_ref[0, 0, :, :]                                    # (bk, d)
-        v = v_ref[0, 0, :, :]
-        s = jax.lax.dot_general(                                 # (bq, bk)
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = _mask_causal(s, causal, q_off, k_off, block_q, block_k, window)
-        m_prev, l_prev = m_ref[:, 0], l_ref[:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        # m_new is finite from the first live block (causal keeps the
-        # diagonal), so exp never sees inf-inf; a still--inf running max
-        # contributes alpha=0 exactly. Under a window a row's first live
-        # tile can lie wholly behind its own window: exponentiate against
-        # 0 there, so that p and alpha are 0 and not exp(inf - inf).
-        m_exp = m_new if window is None else jnp.where(
-            m_new == -jnp.inf, 0.0, m_new)
-        p = jnp.exp(s - m_exp[:, None])
-        alpha = jnp.exp(m_prev - m_exp)
-        l_ref[:, 0] = l_prev * alpha + p.sum(axis=-1)
-        m_ref[:, 0] = m_new
-        # p rounds to the v dtype for the second MXU pass (standard flash
-        # practice: p is in [0, 1], the f32 accumulator absorbs the sum).
-        acc_ref[:] = acc_ref[:] * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        _softmax_tile(
+            q_ref[0, 0, :, :], k_ref[0, 0, :, :], v_ref[0, 0, :, :],
+            lambda s: _mask_causal(s, causal, q_off, k_off, block_q, block_k,
+                                   window),
+            acc_ref, m_ref, l_ref, scale, rows_may_be_dead=window is not None)
 
     @pl.when(step == n_steps - 1)
     def _emit():
@@ -363,15 +376,19 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, q_off, k_off,
     lse (lse >= running max, so exp(s - lse) <= 1), and return
     ``(p, ds)`` with ``ds`` already scaled — keeping the numerics in ONE
     place so dQ and dK/dV cannot drift apart."""
-    q = q_ref[0, 0, :, :]
-    k = k_ref[0, 0, :, :]
-    v = v_ref[0, 0, :, :]
-    do = do_ref[0, 0, :, :]
-    lse = lse_ref[0, 0, :, 0]                                   # (bq,)
-    dd = dd_ref[0, 0, :, 0]                                     # (bq,)
+    return _p_ds_tile(
+        q_ref[0, 0, :, :], k_ref[0, 0, :, :], v_ref[0, 0, :, :],
+        do_ref[0, 0, :, :], lse_ref[0, 0, :, 0], dd_ref[0, 0, :, 0],
+        lambda s: _mask_causal(s, causal, q_off, k_off, block_q, block_k,
+                               window), scale)
+
+
+def _p_ds_tile(q, k, v, do, lse, dd, mask, scale: float):
+    """The tile math of :func:`_bwd_p_ds` on loaded tiles: q, do (bq, d);
+    k, v (bk, d); lse, dd (bq,); ``mask`` a callable on the score tile."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    s = _mask_causal(s, causal, q_off, k_off, block_q, block_k, window)
+    s = mask(s)
     p = jnp.exp(s - lse[:, None])                               # (bq, bk)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
