@@ -3,31 +3,39 @@
 XLA's plain softmax attention materializes the O(seq^2) score matrix in
 HBM; this kernel never does. Design (flash-attention-2 style, TPU-first):
 
-* grid = (batch, q_heads, q_blocks, kv_blocks), kv innermost — TPU grids
-  execute sequentially, so the online-softmax state (running max ``m``,
+* every kernel walks a STATIC schedule of live tiles: the (q tile, K/V
+  tile) pairs that hold at least one pair inside the mask, made in numpy
+  at trace time from the shapes, the tiles and the mask
+  (:func:`_live_tiles`) and handed to the kernel as scalar-prefetch
+  arrays, as :mod:`petastorm_tpu.ops.eva_attn` does. The forward and dQ
+  grids are ``(batch, q_heads, items)``, a q tile's K/V tiles in
+  ascending order (:func:`_q_schedule`) — TPU grids execute
+  sequentially, so the online-softmax state (running max ``m``,
   normalizer ``l``, unnormalized accumulator ``acc``) lives in VMEM
-  scratch carried across the kv dimension. VMEM holds ONE q tile and ONE
-  K/V tile at a time (O(block * d), not O(seq * d)), which is what makes
-  long sequences fit;
+  scratch carried across a q tile's items, flags marking its first and
+  last. VMEM holds ONE q tile and ONE K/V tile at a time (O(block * d),
+  not O(seq * d)), which is what makes long sequences fit;
 * grouped-query attention is native: the K/V BlockSpec index-maps the
   q-head grid coordinate onto its kv head (``h // rep``) — K/V are never
   repeated in memory;
-* causal programs whose K/V tile lies entirely above the diagonal skip
-  the matmuls via ``pl.when`` (the tile DMA still happens — acceptable:
-  bandwidth is prefetch-pipelined, MXU time is not);
+* a tile above the causal diagonal is not in the schedule: it costs no
+  grid step, no K/V copy and no MXU work (:func:`grid_steps` counts what
+  is left: at the launch tiles, 1024 x 1024, 10 of 16 steps a head at
+  4096 positions and 136 of 256 at 16,384);
 * a static ``window`` (with ``causal``) is sliding-window attention: the
-  mask also drops keys ``window`` or more behind their query, and the
-  innermost grid axis walks only the tiles of the band (:func:`_band`),
-  so dead tiles on either side cost neither MXU work nor a grid step;
-  the three calls are then named ``swa_fwd`` / ``swa_bwd_dq`` /
-  ``swa_bwd_dkv`` (one kernel body per pass, the window an argument);
+  mask also drops keys ``window`` or more behind their query, which only
+  changes the tiles the schedule lists (70 of 256 a head at 16,384
+  positions under a window of 4096); the three calls are then named
+  ``swa_fwd`` / ``swa_bwd_dq`` / ``swa_bwd_dkv`` (one kernel body per
+  pass, the window an argument). ``causal=False`` lists every tile;
 * scores accumulate in float32 regardless of input dtype (numerics parity
   with :func:`petastorm_tpu.parallel.attention.dense_attention`);
 * the backward pass is two Pallas kernels (flash-attention-2 style,
   ``custom_vjp``): the forward saves ``(q, k, v, o, lse)``, then a
   kv-innermost pass accumulates dQ and a q-innermost pass accumulates
   dK/dV — with grouped-query head gradients summed inside the kernel by
-  walking every (group head, q block) pair over one K/V tile. No
+  walking every (group head, live q tile) pair over one K/V tile
+  (:func:`_kv_schedule`). No
   O(seq^2) or O(block*seq) tensors touch HBM in training either. The
   forward rule names ``o`` and ``lse`` (:data:`SAVED_NAMES`, via
   ``jax.ad_checkpoint.checkpoint_name``): a ``jax.checkpoint`` around the
@@ -64,23 +72,39 @@ from jax.ad_checkpoint import checkpoint_name
 SAVED_NAMES = ("flash_attn_out", "flash_attn_lse")
 
 _DEFAULT_BLOCK = 128
-# Launch defaults: bigger tiles amortize per-program overhead (an 8k seq
-# at 128x128 is a 32k-program grid; at 256x1024 it is 1k) while staying
-# far under VMEM (q 64KB + k/v 256KB each + f32 scores 1MB per step).
-# 256x1024 measured fastest of a 6-config on-chip sweep at both 8k
-# (10.2 ms vs 11.6 at 256x512) and near-best at 16k (14.3 vs 17.5) —
-# TPU v5 lite, 2026-07-31; fewer kv iterations amortize the K/V DMA.
-# Seqs the big tiles don't divide step down to _DEFAULT_BLOCK before
+# Launch defaults, from a sweep of 16 tilings on the chip on the static
+# schedule (TPU v5 lite, 2026-10-03, PR 34; forward / dQ / dK+dV timed
+# apart, bfloat16, head 128, ms a call with its transposes) at the token
+# cells' three calls: b4 h32/8 s4096, b2 h7/1 s16384, the same under a
+# window of 4096. 1024 x 1024 won every kernel at every shape:
+#   b4 h32/8 s4096        6.63 /  8.64 / 10.51  (256 x 1024: 8.21 /  9.86 / 11.61)
+#   b2 h7/1 s16384        7.67 /  9.30 / 12.37  (256 x 1024: 10.19 / 11.35 / 14.21)
+#   the same, window 4096 4.15 /  5.44 /  6.80  (256 x 1024: 5.45 /  6.40 /  7.56)
+# with 512 x 1024 next (7.03 / 9.07 / 10.65 at the first), key tiles of
+# 512 or less 20-60% slower in the forward, and 2048-row or 2048-key tiles
+# (under a 96 MiB VMEM limit) 10-15% slower: with no dead step left,
+# a step's fixed cost is what a larger tile amortizes, although a tile on
+# the diagonal is then half masked. One tiling for the three kernels.
+# Seqs the big tiles don't divide halve down to _DEFAULT_BLOCK before
 # falling back to dense, so the kernel-path coverage of the old 128
 # defaults (e.g. seq 1280) is preserved.
-_DEFAULT_BLOCK_Q = 256
+_DEFAULT_BLOCK_Q = 1024
 _DEFAULT_BLOCK_K = 1024
+# The three calls' scoped-VMEM limit. The default 16 MiB holds a 1024 x
+# 1024 tile's float32 scores and their gradient beside bfloat16 operands
+# at head 128, not beside float32 operands at head 256 (the dQ call);
+# 32 MiB holds both with room, a quarter of what a v5e core has. The token
+# cells' steps read the same under either limit (chip runs, PR 34).
+_VMEM_LIMIT = 32 << 20
 
 
 def _pick_block(requested: int, seq: int) -> int:
-    """Clamp ``requested`` to ``seq``; if it doesn't divide, retry the
-    128 granule before :func:`_tiles` rejects it."""
+    """Clamp ``requested`` to ``seq``; if it doesn't divide, halve it down
+    to the 128 granule (1280 takes 256, not 1024), and retry the granule
+    itself before :func:`_tiles` rejects it."""
     blk = min(requested, seq)
+    while seq % blk and not blk % 2 and blk > _DEFAULT_BLOCK:
+        blk //= 2
     if seq % blk and not seq % _DEFAULT_BLOCK:
         blk = _DEFAULT_BLOCK
     return blk
@@ -129,16 +153,62 @@ def _resolve_interpret(interpret) -> bool:
     return bool(interpret)
 
 
-def _causal_live(causal: bool, q_off, k_off, block_q: int,
-                 block_k: int = 0, window=None):
-    """True when this (q tile, kv tile) pair has any element inside the
-    mask: on or below the diagonal and, with a ``window``, fewer than
-    ``window`` keys behind its query — the skip predicate shared by the
-    forward and both backward kernels."""
-    live = jnp.logical_or(not causal, q_off + block_q - 1 >= k_off)
+# Bits of a schedule item's flags: the first and the last item of a run
+# (one q tile's K/V tiles, or one K/V tile's (head, q tile) pairs).
+_FIRST, _LAST = 1, 2
+
+
+def _live_tiles(sq: int, sk: int, block_q: int, block_k: int, causal: bool,
+                window=None) -> np.ndarray:
+    """``(sq // block_q, sk // block_k)`` booleans: True where the (q tile,
+    K/V tile) pair has any element inside the mask — on or below the
+    diagonal and, with a ``window``, fewer than ``window`` keys behind its
+    query. What all three kernels walk, and nothing else."""
+    q_off = np.arange(sq // block_q)[:, None] * block_q
+    k_off = np.arange(sk // block_k)[None, :] * block_k
+    live = np.ones((sq // block_q, sk // block_k), bool)
+    if causal:
+        live &= q_off + block_q - 1 >= k_off
     if window is not None:
-        live = jnp.logical_and(live, q_off - (k_off + block_k - 1) < window)
+        live &= q_off - (k_off + block_k - 1) < window
     return live
+
+
+def _run_flags(outer: np.ndarray) -> np.ndarray:
+    """``_FIRST`` / ``_LAST`` on the items where ``outer`` (ascending)
+    takes a new value / holds one for the last time."""
+    edge = outer[1:] != outer[:-1]
+    return (np.r_[True, edge] * _FIRST | np.r_[edge, True] * _LAST).astype(
+        np.int32)
+
+
+def _q_schedule(live: np.ndarray) -> tuple:
+    """Items of the forward and the dQ kernel, q tile by q tile: its live
+    K/V tiles in ascending order -> int32 arrays ``(q tile, K/V tile,
+    flags)``."""
+    qt, kt = (a.astype(np.int32) for a in np.nonzero(live))
+    return qt, kt, _run_flags(qt)
+
+
+def _kv_schedule(live: np.ndarray, rep: int) -> tuple:
+    """Items of the dK/dV kernel, K/V tile by K/V tile: every (head of the
+    group, live q tile) pair, heads outermost -> int32 arrays ``(K/V tile,
+    head in group, q tile, flags)``."""
+    kt, head, qt = (np.asarray(a, np.int32) for a in zip(*(
+        (ki, r, qi) for ki in range(live.shape[1]) for r in range(rep)
+        for qi in np.flatnonzero(live[:, ki]))))
+    return kt, head, qt, _run_flags(kt)
+
+
+def grid_steps(sq: int, sk: int, block_q: int, block_k: int, causal: bool,
+               window=None, rep: int = 1) -> dict:
+    """Length of the innermost grid axis of the three kernels a (batch,
+    head) — a (batch, kv head) for ``"dkv"`` — at these tiles: the
+    schedules' own lengths, every item a live tile."""
+    live = _live_tiles(sq, sk, block_q, block_k, causal, window)
+    n_q_items = len(_q_schedule(live)[0])
+    return {"fwd": n_q_items, "dq": n_q_items,
+            "dkv": len(_kv_schedule(live, rep)[0])}
 
 
 def _mask_causal(s, causal: bool, q_off, k_off, block_q: int, block_k: int,
@@ -156,26 +226,6 @@ def _mask_causal(s, causal: bool, q_off, k_off, block_q: int, block_k: int,
     if window is not None:
         keep = jnp.logical_and(keep, qpos - kpos < window)
     return jnp.where(keep, s, -jnp.inf)
-
-
-def _band(window, block_in: int, block_out: int, n_out: int, behind: int):
-    """The tiles of the walked (innermost) grid axis that one tile of the
-    outer axis can see -> ``(first(outer index), tiles walked)``.
-
-    Without a window every tile is walked from 0, as ever. With one, a q
-    tile (walking kv: ``behind = window - 1``) sees keys ``q_off - window
-    + 1 .. q_off + block_q - 1`` and a kv tile (walking q: ``behind = 0``)
-    is seen by queries ``k_off .. k_off + block_k + window - 2``: in both
-    ``window + block_in - 1`` positions, which touch at most ``(window +
-    block_in - 2) // block_out + 2`` tiles. The grid walks only those, so
-    dead tiles on either side of the band cost no grid step; an index past
-    the last tile is clamped in the index maps and dead in the kernel.
-    """
-    if window is None:
-        return (lambda i: 0), n_out
-    walked = min(n_out, (window + block_in - 2) // block_out + 2)
-    return (lambda i: jnp.maximum(i * block_in - behind, 0) // block_out,
-            walked)
 
 
 def _softmax_tile(q, k, v, mask, acc_ref, m_ref, l_ref, scale: float,
@@ -215,10 +265,10 @@ def _softmax_tile(q, k, v, mask, acc_ref, m_ref, l_ref, scale: float,
         preferred_element_type=jnp.float32)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
-                  block_k: int, causal: bool, scale: float,
-                  emit_stats: bool = False, emit_lse: bool = False,
-                  window=None, k_first=lambda qi: 0):
+def _flash_kernel(qt_ref, kt_ref, flags_ref, q_ref, k_ref, v_ref, o_ref,
+                  *rest, block_q: int, block_k: int, causal: bool,
+                  scale: float, emit_stats: bool = False,
+                  emit_lse: bool = False, window=None):
     from jax.experimental import pallas as pl
 
     if emit_stats:
@@ -228,31 +278,23 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
     else:
         acc_ref, m_ref, l_ref = rest
 
-    qi, step = pl.program_id(2), pl.program_id(3)
-    n_steps = pl.num_programs(3)
-    ki = k_first(qi) + step     # the kv tile: the band's with a window
+    item = pl.program_id(2)
+    flags = flags_ref[item]
+    q_off, k_off = qt_ref[item] * block_q, kt_ref[item] * block_k
 
-    @pl.when(step == 0)
+    @pl.when((flags & _FIRST) != 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    q_off, k_off = qi * block_q, ki * block_k
-    # Tiles fully above the causal diagonal (or wholly behind the window)
-    # contribute nothing: skip the MXU work (roughly halves causal kernel
-    # time at long seq).
-    live = _causal_live(causal, q_off, k_off, block_q, block_k, window)
+    _softmax_tile(
+        q_ref[0, 0, :, :], k_ref[0, 0, :, :], v_ref[0, 0, :, :],
+        lambda s: _mask_causal(s, causal, q_off, k_off, block_q, block_k,
+                               window),
+        acc_ref, m_ref, l_ref, scale, rows_may_be_dead=window is not None)
 
-    @pl.when(live)
-    def _step():
-        _softmax_tile(
-            q_ref[0, 0, :, :], k_ref[0, 0, :, :], v_ref[0, 0, :, :],
-            lambda s: _mask_causal(s, causal, q_off, k_off, block_q, block_k,
-                                   window),
-            acc_ref, m_ref, l_ref, scale, rows_may_be_dead=window is not None)
-
-    @pl.when(step == n_steps - 1)
+    @pl.when((flags & _LAST) != 0)
     def _emit():
         if emit_stats:
             # Unnormalized accumulator + online-softmax stats, f32: the
@@ -270,14 +312,31 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
                 lse_ref[0, 0, :, :] = m_ref[:] + jnp.log(l_ref[:])
 
 
+def _q_walk_specs(block_q: int, block_k: int, d: int, rep: int):
+    """Block specs of the forward and the dQ kernel over
+    :func:`_q_schedule`'s arrays ``(q tile, K/V tile, flags)``: a (b, h,
+    rows, d) operand read a q tile at a time, K/V a tile at a time from
+    the q head's kv head, and the (b, h, seq, 1) row statistics."""
+    from jax.experimental import pallas as pl
+
+    def q_index(bi, hi, item, qt, kt, flags):
+        return bi, hi, qt[item], 0
+
+    def kv_index(bi, hi, item, qt, kt, flags):
+        return bi, hi // rep, kt[item], 0
+    return (pl.BlockSpec((1, 1, block_q, d), q_index),
+            pl.BlockSpec((1, 1, block_k, d), kv_index),
+            pl.BlockSpec((1, 1, block_q, 1), q_index))
+
+
 def _flash_launch(q, k, v, causal: bool, block_q: int, block_k: int,
                   interpret: bool, mode: str, window=None):
     """One launcher for every forward variant — same grid, BlockSpecs and
     scratch; ``mode`` picks the kernel's emit: ``"out"`` (normalized
     output), ``"lse"`` (output + logsumexp, the backward's residual), or
-    ``"stats"`` (unnormalized o + m/l, the ring-merge contract). With a
-    ``window`` the kv axis of the grid walks the band's tiles only
-    (:func:`_band`) and the call is named ``swa_fwd``.
+    ``"stats"`` (unnormalized o + m/l, the ring-merge contract). The grid
+    is ``(b, h, items)`` over :func:`_q_schedule`; with a ``window`` the
+    call is named ``swa_fwd``.
 
     Kernel-internal layout is (b, heads, seq, d): Mosaic requires the
     block's minor-most two dims to tile as (sublane, lane) — (block_q, d)
@@ -290,23 +349,12 @@ def _flash_launch(q, k, v, causal: bool, block_q: int, block_k: int,
 
     b, sq, h, d = q.shape
     sk, kv_h = k.shape[1], k.shape[2]
-    rep = h // kv_h
-    n_k = sk // block_k
-    k_first, k_walked = _band(window, block_q, block_k, n_k,
-                              behind=(window or 1) - 1)
+    sched = _q_schedule(_live_tiles(sq, sk, block_q, block_k, causal, window))
     kernel = partial(_flash_kernel, block_q=block_q, block_k=block_k,
                      causal=causal, scale=1.0 / np.sqrt(d),
                      emit_stats=(mode == "stats"), emit_lse=(mode == "lse"),
-                     window=window, k_first=k_first)
-    if window is None:
-        kv_index = lambda bi, hi, qi, ki: (bi, hi // rep, ki, 0)  # noqa: E731
-    else:
-        kv_index = lambda bi, hi, qi, step: (  # noqa: E731
-            bi, hi // rep, jnp.minimum(k_first(qi) + step, n_k - 1), 0)
-    o_spec = pl.BlockSpec((1, 1, block_q, d),
-                          lambda bi, hi, qi, ki: (bi, hi, qi, 0))
-    stat_spec = pl.BlockSpec((1, 1, block_q, 1),
-                             lambda bi, hi, qi, ki: (bi, hi, qi, 0))
+                     window=window)
+    o_spec, kv_spec, stat_spec = _q_walk_specs(block_q, block_k, d, h // kv_h)
     stat_shape = jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32)
     if mode == "out":
         out_specs = o_spec
@@ -321,22 +369,20 @@ def _flash_launch(q, k, v, causal: bool, block_q: int, block_k: int,
                      stat_shape, stat_shape]
     return pl.pallas_call(
         kernel,
-        grid=(b, h, sq // block_q, k_walked),
-        in_specs=[
-            o_spec,
-            pl.BlockSpec((1, 1, block_k, d), kv_index),
-            pl.BlockSpec((1, 1, block_k, d), kv_index),
-        ],
-        out_specs=out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(sched), grid=(b, h, len(sched[0])),
+            in_specs=[o_spec, kv_spec, kv_spec],
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),      # acc
+                pltpu.VMEM((block_q, 1), jnp.float32),      # running max m
+                pltpu.VMEM((block_q, 1), jnp.float32),      # normalizer l
+            ]),
         out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),      # acc
-            pltpu.VMEM((block_q, 1), jnp.float32),      # running max m
-            pltpu.VMEM((block_q, 1), jnp.float32),      # normalizer l
-        ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="flash_fwd" if window is None else "swa_fwd",
-    )(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+    )(*sched, q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
       v.transpose(0, 2, 1, 3))
 
 
@@ -396,84 +442,68 @@ def _p_ds_tile(q, k, v, do, lse, dd, mask, scale: float):
     return p, ds
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
-                         dq_ref, dq_acc, *, block_q: int, block_k: int,
-                         causal: bool, scale: float, window=None,
-                         k_first=lambda qi: 0):
-    """dQ pass (flash-attention-2 backward): grid (b, h, q_blocks,
-    kv_blocks), kv innermost; dq accumulates in VMEM scratch across the
-    kv dimension. P is re-exponentiated from the saved lse, so no
-    softmax state needs carrying."""
+def _flash_bwd_dq_kernel(qt_ref, kt_ref, flags_ref, q_ref, k_ref, v_ref,
+                         do_ref, lse_ref, dd_ref, dq_ref, dq_acc, *,
+                         block_q: int, block_k: int, causal: bool,
+                         scale: float, window=None):
+    """dQ pass (flash-attention-2 backward): grid (b, h, items) over
+    :func:`_q_schedule`, a q tile's K/V tiles innermost; dq accumulates in
+    VMEM scratch across them. P is re-exponentiated from the saved lse, so
+    no softmax state needs carrying."""
     from jax.experimental import pallas as pl
 
-    qi, step = pl.program_id(2), pl.program_id(3)
-    n_steps = pl.num_programs(3)
-    ki = k_first(qi) + step
+    item = pl.program_id(2)
+    flags = flags_ref[item]
+    q_off, k_off = qt_ref[item] * block_q, kt_ref[item] * block_k
 
-    @pl.when(step == 0)
+    @pl.when((flags & _FIRST) != 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    q_off, k_off = qi * block_q, ki * block_k
-    live = _causal_live(causal, q_off, k_off, block_q, block_k, window)
+    _, ds = _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, q_off,
+                      k_off, block_q, block_k, causal, scale, window)
+    k = k_ref[0, 0, :, :]
+    dq_acc[:] += jax.lax.dot_general(
+        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
-    @pl.when(live)
-    def _step():
-        _, ds = _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
-                          q_off, k_off, block_q, block_k, causal, scale,
-                          window)
-        k = k_ref[0, 0, :, :]
-        dq_acc[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(step == n_steps - 1)
+    @pl.when((flags & _LAST) != 0)
     def _emit():
         dq_ref[0, 0, :, :] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dd_ref,
-                          dk_ref, dv_ref, dk_acc, dv_acc, *, block_q: int,
-                          block_k: int, n_q: int, causal: bool,
-                          scale: float, window=None, q_walked=None,
-                          q_first=lambda ki: 0):
-    """dK/dV pass: grid (b, kv_heads, kv_blocks, rep * q_blocks) — the
-    innermost dimension walks every (grouped-query head, q block) pair
-    that attends to this K/V tile, accumulating dk/dv in VMEM scratch
+def _flash_bwd_dkv_kernel(kt_ref, head_ref, qt_ref, flags_ref, k_ref, v_ref,
+                          q_ref, do_ref, lse_ref, dd_ref, dk_ref, dv_ref,
+                          dk_acc, dv_acc, *, block_q: int, block_k: int,
+                          causal: bool, scale: float, window=None):
+    """dK/dV pass: grid (b, kv_heads, items) over :func:`_kv_schedule` —
+    the innermost dimension walks every (grouped-query head, live q tile)
+    pair that attends to a K/V tile, accumulating dk/dv in VMEM scratch
     (GQA gradients sum over the head group here instead of a host-side
-    reduction over repeated K/V). With a ``window`` only the ``q_walked``
-    q blocks from ``q_first(ki)`` on are walked for each head."""
+    reduction over repeated K/V)."""
     from jax.experimental import pallas as pl
 
-    ki, t = pl.program_id(2), pl.program_id(3)
-    qi = q_first(ki) + t % (n_q if q_walked is None else q_walked)
-    n_t = pl.num_programs(3)
+    item = pl.program_id(2)
+    flags = flags_ref[item]
+    q_off, k_off = qt_ref[item] * block_q, kt_ref[item] * block_k
 
-    @pl.when(t == 0)
+    @pl.when((flags & _FIRST) != 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    q_off, k_off = qi * block_q, ki * block_k
-    live = _causal_live(causal, q_off, k_off, block_q, block_k, window)
-    if window is not None:      # a walked index past the last q block
-        live = jnp.logical_and(live, qi < n_q)
+    p, ds = _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, q_off,
+                      k_off, block_q, block_k, causal, scale, window)
+    q = q_ref[0, 0, :, :]
+    do = do_ref[0, 0, :, :]
+    dv_acc[:] += jax.lax.dot_general(
+        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                     # (bk, d)
+    dk_acc[:] += jax.lax.dot_general(
+        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                     # (bk, d)
 
-    @pl.when(live)
-    def _step():
-        p, ds = _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
-                          q_off, k_off, block_q, block_k, causal, scale,
-                          window)
-        q = q_ref[0, 0, :, :]
-        do = do_ref[0, 0, :, :]
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                 # (bk, d)
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                 # (bk, d)
-
-    @pl.when(t == n_t - 1)
+    @pl.when((flags & _LAST) != 0)
     def _emit():
         dk_ref[0, 0, :, :] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0, :, :] = dv_acc[:].astype(dv_ref.dtype)
@@ -502,63 +532,48 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, block_q: int,
     lseT = lse                                  # already (b, h, sq, 1)
     ddT = dd.transpose(0, 2, 1)[..., None]
 
-    n_q, n_k = sq // block_q, sk // block_k
-    k_first, k_walked = _band(window, block_q, block_k, n_k,
-                              behind=(window or 1) - 1)
-    q_first, q_walked = _band(window, block_k, block_q, n_q, behind=0)
-    q_spec = pl.BlockSpec((1, 1, block_q, d),
-                          lambda bi, hi, qi, ki: (bi, hi, qi, 0))
-    if window is None:
-        kv_spec = pl.BlockSpec((1, 1, block_k, d),
-                               lambda bi, hi, qi, ki: (bi, hi // rep, ki, 0))
-    else:
-        kv_spec = pl.BlockSpec(
-            (1, 1, block_k, d), lambda bi, hi, qi, step: (
-                bi, hi // rep, jnp.minimum(k_first(qi) + step, n_k - 1), 0))
-    stat_spec = pl.BlockSpec((1, 1, block_q, 1),
-                             lambda bi, hi, qi, ki: (bi, hi, qi, 0))
+    live = _live_tiles(sq, sk, block_q, block_k, causal, window)
+    tile = dict(block_q=block_q, block_k=block_k, causal=causal, scale=scale,
+                window=window)
+    sched = _q_schedule(live)
+    q_spec, kv_spec, stat_spec = _q_walk_specs(block_q, block_k, d, rep)
     dq = pl.pallas_call(
-        partial(_flash_bwd_dq_kernel, block_q=block_q, block_k=block_k,
-                causal=causal, scale=scale, window=window, k_first=k_first),
-        grid=(b, h, sq // block_q, k_walked),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec],
-        out_specs=q_spec,
+        partial(_flash_bwd_dq_kernel, **tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(sched), grid=(b, h, len(sched[0])),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec],
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="flash_bwd_dq" if window is None else "swa_bwd_dq",
-    )(qT, kT, vT, doT, lseT, ddT)
+    )(*sched, qT, kT, vT, doT, lseT, ddT)
 
-    kv_out_spec = pl.BlockSpec((1, 1, block_k, d),
-                               lambda bi, gi, ki, t: (bi, gi, ki, 0))
-    # Per-(kv head, q tile) inputs: head gi*rep + t//n_q, q block t%n_q
-    # (with a window: of the q_walked blocks from q_first(ki) on).
-    if window is None:
-        q_index = lambda bi, gi, ki, t: (  # noqa: E731
-            bi, gi * rep + t // n_q, t % n_q, 0)
-    else:
-        q_index = lambda bi, gi, ki, t: (  # noqa: E731
-            bi, gi * rep + t // q_walked,
-            jnp.minimum(q_first(ki) + t % q_walked, n_q - 1), 0)
-    q_in = pl.BlockSpec((1, 1, block_q, d), q_index)
-    stat_in = pl.BlockSpec((1, 1, block_q, 1), q_index)
-    kv_in = pl.BlockSpec((1, 1, block_k, d),
-                         lambda bi, gi, ki, t: (bi, gi, ki, 0))
+    sched = _kv_schedule(live, rep)     # (K/V tile, head in group, q tile, .)
+
+    def kv_index(bi, gi, item, kt, head, qt, flags):
+        return bi, gi, kt[item], 0
+
+    def q_index(bi, gi, item, kt, head, qt, flags):
+        return bi, gi * rep + head[item], qt[item], 0
+    kv_spec = pl.BlockSpec((1, 1, block_k, d), kv_index)
+    q_spec = pl.BlockSpec((1, 1, block_q, d), q_index)
+    stat_spec = pl.BlockSpec((1, 1, block_q, 1), q_index)
     dk, dv = pl.pallas_call(
-        partial(_flash_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
-                n_q=n_q, causal=causal, scale=scale, window=window,
-                q_walked=None if window is None else q_walked,
-                q_first=q_first),
-        grid=(b, kv_h, sk // block_k, rep * q_walked),
-        in_specs=[kv_in, kv_in, q_in, q_in, stat_in, stat_in],
-        out_specs=[kv_out_spec, kv_out_spec],
+        partial(_flash_bwd_dkv_kernel, **tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(sched), grid=(b, kv_h, len(sched[0])),
+            in_specs=[kv_spec, kv_spec, q_spec, q_spec, stat_spec, stat_spec],
+            out_specs=[kv_spec, kv_spec],
+            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                            pltpu.VMEM((block_k, d), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((b, kv_h, sk, d), k.dtype),
                    jax.ShapeDtypeStruct((b, kv_h, sk, d), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="flash_bwd_dkv" if window is None else "swa_bwd_dkv",
-    )(kT, vT, qT, doT, lseT, ddT)
+    )(*sched, kT, vT, qT, doT, lseT, ddT)
     return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
             dv.transpose(0, 2, 1, 3))
 
@@ -669,7 +684,7 @@ def flash_attention(q, k, v, causal: bool = False,
     q ``(b, sq, heads, d)``, k/v ``(b, sk, kv_heads, d)`` ->
     ``(b, sq, heads, d)``, grouped-query native. ``window`` (static, needs
     ``causal``) keeps of each query's keys its own and the ``window - 1``
-    before it; the grid then walks the band's tiles alone and the three
+    before it; the schedule then lists the band's tiles alone and the three
     calls are named ``swa_fwd`` / ``swa_bwd_dq`` / ``swa_bwd_dkv``.
 
     Falls back to the dense path when the shape can't tile onto the

@@ -1,7 +1,8 @@
-"""The three EVA Pallas kernels compile for a described TPU v5e at the byte
-cell's own shapes (no chip: the ``on-chip-measurement`` guide's third
-rehearsal, kept as a test; the topology is described inside a fixture of
-this file only). What the chip's compiler refuses here costs no chip
+"""The three EVA Pallas kernels, and the six flash / sliding-window ones,
+compile for a described TPU v5e at their cells' own shapes (no chip: the
+``on-chip-measurement`` guide's third rehearsal, kept as a test; the
+topology is described inside a fixture of this file only, which is why the
+flash kernels' compiles live here too). What the chip's compiler refuses here costs no chip
 time: a tile the lanes cannot hold, more fast memory than a kernel may
 use, a scalar-prefetch schedule Mosaic cannot index."""
 import json
@@ -84,3 +85,62 @@ def test_eva_backward_compiles_at_the_cells_shapes(cell, no_persistent_cache):
         rows, rows, rows, per_head, per_head).compile()
     assert kernels_in(compiled) == {"eva_fwd", "eva_bwd_dq", "eva_bwd_dkv"}
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
+# (configuration, traffic, windowed): the dense decoder's call and the sparse
+# one's full and windowed layers' (``chipbench/pipelines/token_decoder.py``,
+# ``token_moe_decoder.py``: default tiles, the window the configuration's).
+FLASH_CALLS = [("mistral7b-v03-d2", "tok4k-b4", False, "flash"),
+               ("smallthinker21b-tp4-d4", "tok16k-b2", False, "flash"),
+               ("smallthinker21b-tp4-d4", "tok16k-b2", True, "swa")]
+
+
+@pytest.mark.parametrize("config,traffic,windowed,prefix", FLASH_CALLS)
+def test_flash_kernels_compile_at_the_cells_shapes(topo, no_persistent_cache,
+                                                   config, traffic, windowed,
+                                                   prefix):
+    """Forward and both backward kernels on their scalar-prefetch
+    schedules, at the launch defaults and the calls' VMEM limit."""
+    from petastorm_tpu.ops.flash_attn import make_flash_attention
+    with open(os.path.join(ROOT, "chipbench", "configs",
+                           f"{config}.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(ROOT, "chipbench", "traffic",
+                           f"{traffic}.json")) as f:
+        rows = json.load(f)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def operand(heads):
+        return jax.ShapeDtypeStruct(
+            (rows["per_chip_batch"], rows["window"], heads, cfg["head_dim"]),
+            jnp.bfloat16, sharding=one_chip)
+
+    attn = make_flash_attention(
+        causal=True, interpret=False,
+        window=cfg["sliding_window_size"] if windowed else None)
+    q, kv = (operand(cfg["num_attention_heads"]),
+             operand(cfg["num_key_value_heads"]))
+    compiled = jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32)),
+        argnums=(0, 1, 2))).lower(q, kv, kv).compile()
+    assert kernels_in(compiled) == {f"{prefix}_fwd", f"{prefix}_bwd_dq",
+                                    f"{prefix}_bwd_dkv"}
+
+
+def test_flash_backward_compiles_for_wide_float32_rows(topo,
+                                                       no_persistent_cache):
+    """Float32 operands at head 256 need more scoped VMEM at the launch
+    tiles than the default 16 MiB holds (the dQ call): what the calls'
+    ``_VMEM_LIMIT`` is for."""
+    from petastorm_tpu.ops.flash_attn import make_flash_attention
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((1, 4096, 8, 256), jnp.float32,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4096, 2, 256), jnp.float32,
+                              sharding=one_chip)
+    attn = make_flash_attention(causal=True, interpret=False)
+    compiled = jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(attn(q, k, v)), argnums=(0, 1, 2))).lower(
+            q, kv, kv).compile()
+    assert kernels_in(compiled) == {"flash_fwd", "flash_bwd_dq",
+                                    "flash_bwd_dkv"}

@@ -11,15 +11,24 @@ from petastorm_tpu.ops.flash_attn import flash_attention
 from petastorm_tpu.parallel.attention import dense_attention
 
 
-def masked_attention(q, k, v, window):
-    """Softmax attention under ``j <= i`` and ``i - j < window``, heads
-    repeated, float32."""
-    rep = q.shape[2] // k.shape[2]
-    k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+def masked_scores(q, k, causal, window):
+    """Scaled scores (b, h, sq, sk), heads repeated, float32, -inf outside
+    ``j <= i`` (``causal``) and ``i - j < window``."""
+    k = jnp.repeat(k, q.shape[2] // k.shape[2], axis=2)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
     behind = jnp.arange(q.shape[1])[:, None] - jnp.arange(k.shape[1])[None]
-    keep = (behind >= 0) & (behind < window)
-    probs = jax.nn.softmax(jnp.where(keep, scores, -jnp.inf), axis=-1)
+    keep = jnp.ones_like(behind, bool)
+    if causal:
+        keep &= behind >= 0
+    if window is not None:
+        keep &= behind < window
+    return jnp.where(keep, scores, -jnp.inf)
+
+
+def masked_attention(q, k, v, window):
+    """Softmax attention under ``j <= i`` and ``i - j < window``."""
+    probs = jax.nn.softmax(masked_scores(q, k, True, window), axis=-1)
+    v = jnp.repeat(v, q.shape[2] // v.shape[2], axis=2)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
@@ -36,7 +45,7 @@ def inputs(seq, heads=4, kv_heads=2, dim=16, seed=0):
 # the sequence; not a multiple of either block; one key; blocks either way.
 CASES = [(128, 40, 32, 64), (128, 128, 32, 64), (128, 200, 32, 64),
          (128, 1, 32, 64), (256, 50, 16, 64), (256, 100, 64, 32),
-         (256, 33, 32, 32), (256, 64, 32, 128)]
+         (256, 33, 32, 32), (256, 64, 32, 128), (256, 1000, 32, 64)]
 
 
 @pytest.mark.parametrize("seq,window,block_q,block_k", CASES)
@@ -56,7 +65,7 @@ def test_windowed_kernels_match_masked_attention(seq, window, block_q,
         np.testing.assert_allclose(mine, theirs, atol=2e-5)
 
 
-@pytest.mark.parametrize("window", [1, 7, 64, 100])
+@pytest.mark.parametrize("window", [1, 7, 64, 100, 1000])
 def test_dense_fallback_takes_the_same_window(window):
     q, k, v, _ = inputs(64, seed=window)
     np.testing.assert_allclose(
@@ -79,24 +88,39 @@ def test_a_window_needs_the_causal_mask():
         flash_attention(q, k, v, causal=True, window=0)
 
 
-@pytest.mark.parametrize("window,block_in,block_out,n_out,behind,first,walked",
-                         [(None, 256, 1024, 16, 0, [0, 0, 0], 16),
-                          # kv walked from a q tile, the token cell's tiles
-                          (4096, 256, 1024, 16, 4095, [0, 0, 4, 11], 6),
-                          # q walked from a kv tile
-                          (4096, 1024, 256, 64, 0, [0, 4, 32, 60], 21),
-                          # a window past the sequence walks every tile
-                          (1 << 20, 256, 1024, 16, (1 << 20) - 1,
-                           [0, 0, 0, 0], 16)])
-def test_the_band_walks_only_the_tiles_it_can_see(window, block_in, block_out,
-                                                  n_out, behind, first,
-                                                  walked):
-    first_of, n = flash_attn._band(window, block_in, block_out, n_out, behind)
-    assert n == walked
-    outer = [0, 1, 8, 15] if window else [0, 1, 8]
-    if window == 4096 and block_in == 256:
-        outer = [0, 15, 32, 63]
-    assert [int(first_of(i)) for i in outer] == first
+# (heads, kv_heads, causal, window): every group width the cells run (the
+# dense decoder's 4, the sparse one's 7, one head a group), with no mask,
+# the causal one, a band, and a band whose last key tiles' queries run past
+# the sequence's end.
+GROUPS = [(2, 2, False, None), (4, 1, False, None), (2, 2, True, None),
+          (4, 1, True, None), (7, 1, True, None), (4, 1, True, 40),
+          (7, 1, True, 100), (2, 2, True, 250), (7, 1, True, 1000)]
+
+
+@pytest.mark.parametrize("heads,kv_heads,causal,window", GROUPS)
+def test_kernels_match_dense_attention_for_every_group_width(
+        heads, kv_heads, causal, window):
+    """o, lse, dq, dk, dv of the scheduled kernels against the dense route,
+    tiles of 32 x 64 over 256 positions."""
+    q, k, v, g = inputs(256, heads=heads, kv_heads=kv_heads, seed=heads)
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=32,
+                               block_k=64, window=window)
+
+    want, pull = jax.vjp(lambda q, k, v: dense_attention(
+        q, k, v, causal=causal, window=window), q, k, v)
+    got, pull_kernel = jax.vjp(kernel, q, k, v)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    for mine, theirs in zip(pull_kernel(g), pull(g)):
+        np.testing.assert_allclose(mine, theirs, atol=2e-5)
+    # lse: the rows' logsumexp of the masked scores.
+    _, lse = flash_attn._flash_forward_lse(q, k, v, causal, 32, 64, True,
+                                           window)
+    np.testing.assert_allclose(
+        lse[..., 0],
+        jax.nn.logsumexp(masked_scores(q, k, causal, window), axis=-1),
+        atol=2e-5)
 
 
 def test_windowed_calls_carry_names_of_their_own():

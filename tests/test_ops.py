@@ -183,14 +183,16 @@ def test_flash_attention_untileable_falls_back(monkeypatch):
 
 
 def test_flash_attention_block_fallback_keeps_kernel_path(monkeypatch):
-    """seq=1280 divides the 128 granule but not the 256/512 launch
-    defaults: _pick_block must step the blocks down to 128 and stay on
+    """seq=1280 divides the 128 granule but not the 1024 x 1024 launch
+    defaults: _pick_block must halve the blocks down to 256 and stay on
     the kernel path (regression: raising the defaults silently pushed
     these seqs onto the O(seq^2) dense fallback)."""
     import importlib
 
     fa_mod = importlib.import_module("petastorm_tpu.ops.flash_attn")
-    assert fa_mod._pick_block(fa_mod._DEFAULT_BLOCK_K, 1280) == 128
+    assert fa_mod._pick_block(fa_mod._DEFAULT_BLOCK_K, 1280) == 256
+    assert fa_mod._pick_block(fa_mod._DEFAULT_BLOCK_K, 1152) == 128
+    assert fa_mod._pick_block(96, 256) == 128     # no power of two: granule
     assert fa_mod._pick_block(fa_mod._DEFAULT_BLOCK_K, 4096) \
         == fa_mod._DEFAULT_BLOCK_K  # divides: launch default stays
     assert fa_mod._pick_block(fa_mod._DEFAULT_BLOCK_Q, 100) == 100  # -> dense
@@ -205,9 +207,8 @@ def test_flash_attention_block_fallback_keeps_kernel_path(monkeypatch):
     monkeypatch.setattr(fa_mod, "_flash_forward", spy)
     q, k, v = _attn_inputs(s=1280)
     out = fa_mod.flash_attention(q, k, v, causal=True)
-    # 1280 = 5*256 so block_q keeps the 256 default; block_k steps
-    # 512 -> 128 (1280 % 512 != 0)
-    assert calls["blocks"] == (256, 128)
+    # 1280 % 1024 != 0: both blocks halve 1024 -> 512 -> 256
+    assert calls["blocks"] == (256, 256)
     from petastorm_tpu.parallel.attention import dense_attention
     ref = dense_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -388,10 +389,10 @@ def test_pallas_interpret_is_chosen_on_cpu_only(monkeypatch):
 
 
 def test_flash_launch_tiles_hold_at_the_smoke_windows():
-    """4k and 32k — the chip legs — tile at the launch defaults, 256x1024."""
+    """4k and 32k — the chip legs — tile at the launch defaults, 1024x1024."""
     from petastorm_tpu.ops.flash_attn import require_flash_tiles
 
-    assert require_flash_tiles(4096, 4096, causal=True) == (256, 1024)
-    assert require_flash_tiles(32768, 32768, causal=True) == (256, 1024)
+    assert require_flash_tiles(4096, 4096, causal=True) == (1024, 1024)
+    assert require_flash_tiles(32768, 32768, causal=True) == (1024, 1024)
     with pytest.raises(ValueError, match="cannot tile"):
         require_flash_tiles(4096, 2048, causal=True)   # causal needs sq == sk
