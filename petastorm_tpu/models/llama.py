@@ -6,10 +6,16 @@
   rotary positions or none and full or sliding-window attention
   (``rope_layout`` / ``sliding_window_layout``), or for the whole model
   EVA's one softmax over a block's own keys and the earlier blocks' chunk
-  summaries (``attention="eva"``, :mod:`petastorm_tpu.ops.eva_attn`); per
+  summaries (``attention="eva"``, :mod:`petastorm_tpu.ops.eva_attn`) or
+  latent attention (``attention="mla"``: keys and values made from one
+  normed low-rank latent, one rotary key head for all query heads, scores
+  wider than values); per
   model, a dense MLP,
   the ``soft`` / ``switch`` expert paths, or the dropless top-k expert
-  layer that is told which experts it holds (``n_router_outputs``);
+  layer that is told which experts it holds (``n_router_outputs``), after
+  ``n_dense_layers`` leading dense layers, beside ``n_shared_experts``
+  experts every token meets, its router a softmax over the selected
+  logits or sigmoid scores selected through a bias (``router_score``);
 * optionally RMSNorm scales stored as offsets from one
   (``norm_unit_offset``) and ``n_pred_heads`` output heads that predict
   the next 1..n tokens in the loss head's one pass;
@@ -77,26 +83,51 @@ class LlamaConfig:
     rope_layout: Optional[tuple] = None
     sliding_window_layout: Optional[tuple] = None
     sliding_window: Optional[int] = None
-    # Dropless top-k expert FFN in every layer (n_router_outputs > 0): the
-    # router scores all n_router_outputs experts, softmax over the top_k
-    # selected; of them this shard holds experts_held = (first, count) and
-    # computes their part of the result, no assignment dropped
+    # Dropless top-k expert FFN (n_router_outputs > 0) in every layer after
+    # the first n_dense_layers, which keep the dense MLP of ``hidden``: the
+    # router scores all n_router_outputs experts and selects top_k; of them
+    # this shard holds experts_held = (first, count) and computes their
+    # part of the result, no assignment dropped
     # (:func:`_dropless_moe_block`). router_input: "layer_input" routes on
     # the block's input, before attention; "mlp_norm" on the normed
-    # post-attention stream.
+    # post-attention stream. router_score: "softmax_topk" selects the
+    # largest logits and softmaxes them; "sigmoid" scores every expert
+    # s = sigmoid(logit), selects the largest of s + b (b the layer's
+    # ``router_bias`` leaf, drawn as zeros; no gradient reaches it and
+    # :func:`make_train_step` keeps it out of the optimizer) and weighs
+    # the selected by s / sum(s) * router_scale. n_shared_experts
+    # experts of expert_hidden each, as one MLP of their summed width,
+    # serve every token beside the routed ones, whole on every shard.
     n_router_outputs: int = 0
     top_k: int = 1
     experts_held: Optional[tuple] = None
     expert_hidden: int = 0
     expert_act: str = "silu"
     router_input: str = "mlp_norm"
+    n_dense_layers: int = 0
+    n_shared_experts: int = 0
+    router_score: str = "softmax_topk"
+    router_scale: float = 1.0
     # "softmax": one softmax over every earlier key (or the window's).
     # "eva": every layer attends within blocks of eva_window positions and
     # to one learned summary per eva_chunk keys of each earlier block
     # (per-head ``eva_phi``, ``eva_mu`` leaves; one KV head a query head).
+    # "mla": latent attention. ``wq`` makes n_heads queries of qk_nope_dim
+    # + qk_rope_dim; ``wkv_a`` a latent of kv_lora_rank and ONE rotary key
+    # of qk_rope_dim for all heads; the latent is normed (``kv_norm``) and
+    # ``wkv_b`` makes each head's qk_nope_dim key and v_dim value from it;
+    # the rotary parts alone are rotated (adjacent pairs where
+    # rope_interleave, else the two halves). Scores contract over
+    # qk_nope_dim + qk_rope_dim and are scaled by its root; ``wo`` reads
+    # n_heads x v_dim. head_dim and n_kv_heads are not read.
     attention: str = "softmax"
     eva_window: int = 0
     eva_chunk: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_dim: int = 0
+    rope_interleave: bool = False
     # RMSNorm multiplies by 1 + g, g drawn as zeros (else by g, drawn as
     # ones).
     norm_unit_offset: bool = False
@@ -123,8 +154,15 @@ class LlamaConfig:
         if self.sliding_window_layout and any(self.sliding_window_layout) \
                 and not self.sliding_window:
             raise ValueError("sliding_window_layout needs sliding_window")
-        if self.attention not in ("softmax", "eva"):
+        if self.attention not in ("softmax", "eva", "mla"):
             raise ValueError(f"unknown attention {self.attention!r}")
+        if self.attention == "mla" and (
+                min(self.kv_lora_rank, self.qk_nope_dim, self.v_dim) < 1
+                or self.qk_rope_dim < 2 or self.qk_rope_dim % 2):
+            raise ValueError(
+                f"latent attention needs kv_lora_rank ({self.kv_lora_rank}), "
+                f"qk_nope_dim ({self.qk_nope_dim}), v_dim ({self.v_dim}) and "
+                f"an even qk_rope_dim ({self.qk_rope_dim})")
         if self.attention == "eva":
             if (self.eva_chunk < 1 or self.eva_window % self.eva_chunk
                     or self.eva_window < self.eva_chunk):
@@ -157,6 +195,14 @@ class LlamaConfig:
                 raise ValueError(f"unknown expert_act {self.expert_act!r}")
             if self.router_input not in ("layer_input", "mlp_norm"):
                 raise ValueError(f"unknown router_input {self.router_input!r}")
+            if self.router_score not in ("softmax_topk", "sigmoid"):
+                raise ValueError(f"unknown router_score {self.router_score!r}")
+            if not 0 <= self.n_dense_layers < self.n_layers:
+                raise ValueError(f"n_dense_layers ({self.n_dense_layers}) of "
+                                 f"{self.n_layers} layers")
+        elif self.n_dense_layers or self.n_shared_experts:
+            raise ValueError("n_dense_layers and n_shared_experts belong to "
+                             "the dropless expert layers (n_router_outputs)")
 
     def attention_kind(self, layer_idx: int) -> tuple:
         """``(rope, window)`` of layer ``layer_idx``: whether it rotates q
@@ -166,6 +212,15 @@ class LlamaConfig:
         windowed = (self.sliding_window_layout is not None
                     and self.sliding_window_layout[layer_idx])
         return rope, (self.sliding_window if windowed else None)
+
+    def holds_experts(self, layer_idx: int) -> bool:
+        """Whether layer ``layer_idx``'s FFN is an expert layer of any
+        kind (dropless after the leading dense layers, or every
+        ``moe_every``-th ``soft`` / ``switch`` one)."""
+        if self.n_router_outputs:
+            return layer_idx >= self.n_dense_layers
+        return (self.n_experts > 0
+                and layer_idx % self.moe_every == self.moe_every - 1)
 
 
 _EXPERT_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
@@ -181,9 +236,9 @@ def init_params(rng_key, cfg: LlamaConfig):
     def mat(key, fan_in, fan_out):
         return jax.random.normal(key, (fan_in, fan_out), jnp.float32) / np.sqrt(fan_in)
 
-    def norm_scale():
+    def norm_scale(width=cfg.dim):
         return (jnp.zeros if cfg.norm_unit_offset else jnp.ones)(
-            (cfg.dim,), jnp.float32)
+            (width,), jnp.float32)
 
     params = {
         "embed": jax.random.normal(next(keys), (cfg.vocab, cfg.dim),
@@ -194,15 +249,26 @@ def init_params(rng_key, cfg: LlamaConfig):
     }
     hd = cfg.head_dim
     for li in range(cfg.n_layers):
-        layer = {
-            "attn_norm": norm_scale(),
-            "wq": mat(next(keys), cfg.dim, cfg.n_heads * hd),
-            "wk": mat(next(keys), cfg.dim, cfg.n_kv_heads * hd),
-            "wv": mat(next(keys), cfg.dim, cfg.n_kv_heads * hd),
-            "wo": mat(next(keys), cfg.n_heads * hd, cfg.dim),
-            "mlp_norm": norm_scale(),
-        }
-        if cfg.n_router_outputs or _is_moe_layer(cfg, li):
+        if cfg.attention == "mla":
+            attn = {
+                "wq": mat(next(keys), cfg.dim,
+                          cfg.n_heads * (cfg.qk_nope_dim + cfg.qk_rope_dim)),
+                "wkv_a": mat(next(keys), cfg.dim,
+                             cfg.kv_lora_rank + cfg.qk_rope_dim),
+                "kv_norm": norm_scale(cfg.kv_lora_rank),
+                "wkv_b": mat(next(keys), cfg.kv_lora_rank,
+                             cfg.n_heads * (cfg.qk_nope_dim + cfg.v_dim)),
+                "wo": mat(next(keys), cfg.n_heads * cfg.v_dim, cfg.dim),
+            }
+        else:
+            attn = {
+                "wq": mat(next(keys), cfg.dim, cfg.n_heads * hd),
+                "wk": mat(next(keys), cfg.dim, cfg.n_kv_heads * hd),
+                "wv": mat(next(keys), cfg.dim, cfg.n_kv_heads * hd),
+                "wo": mat(next(keys), cfg.n_heads * hd, cfg.dim),
+            }
+        layer = {"attn_norm": norm_scale(), **attn, "mlp_norm": norm_scale()}
+        if cfg.holds_experts(li):
             # Dropless: the router keeps its full width, the experts are
             # the ``count`` held here at their own width.
             n_out, E, width = (
@@ -217,6 +283,14 @@ def init_params(rng_key, cfg: LlamaConfig):
                                              jnp.float32) / np.sqrt(cfg.dim)
             layer["ew2"] = jax.random.normal(k4, (E, width, cfg.dim),
                                              jnp.float32) / np.sqrt(width)
+            if cfg.router_score == "sigmoid":
+                layer["router_bias"] = jnp.zeros((n_out,), jnp.float32)
+            if cfg.n_shared_experts:
+                shared = cfg.n_shared_experts * cfg.expert_hidden
+                k1, k2, k3 = jax.random.split(next(keys), 3)
+                layer["sw1"] = mat(k1, cfg.dim, shared)       # gate
+                layer["sw3"] = mat(k2, cfg.dim, shared)       # up
+                layer["sw2"] = mat(k3, shared, cfg.dim)       # down
         else:
             layer["w1"] = mat(next(keys), cfg.dim, cfg.hidden)   # gate
             layer["w3"] = mat(next(keys), cfg.dim, cfg.hidden)   # up
@@ -232,28 +306,24 @@ def init_params(rng_key, cfg: LlamaConfig):
     return params
 
 
-def _is_moe_layer(cfg: LlamaConfig, layer_idx: int) -> bool:
-    return cfg.n_experts > 0 and layer_idx % cfg.moe_every == cfg.moe_every - 1
-
-
 def _param_pspec_tuples(cfg: LlamaConfig, model_axis):
     """PartitionSpec entry tuples per parameter (Megatron TP layout when
     ``model_axis`` is an axis name; all-replicated when None). Empty tuple =
     fully replicated (norm scales, router)."""
     m = model_axis
-    dense_layer = {
-        "attn_norm": (),
-        "wq": (None, m), "wk": (None, m),
-        "wv": (None, m), "wo": (m, None),
-        "mlp_norm": (),
-        "w1": (None, m), "w3": (None, m),
-        "w2": (m, None),
-    }
-    moe_layer = {
-        "attn_norm": (),
-        "wq": (None, m), "wk": (None, m),
-        "wv": (None, m), "wo": (m, None),
-        "mlp_norm": (),
+    attn = {"attn_norm": (), "wq": (None, m), "wo": (m, None),
+            "mlp_norm": ()}
+    if cfg.attention == "mla":
+        # The latent and its norm are whole on every shard; heads are
+        # sharded with the up-projection's columns.
+        attn.update(wkv_a=(None, None), kv_norm=(), wkv_b=(None, m))
+    else:
+        attn.update(wk=(None, m), wv=(None, m))
+    if cfg.attention == "eva":
+        # Heads are sharded with the projections' columns.
+        attn.update(eva_phi=(m, None), eva_mu=(m, None))
+    dense_ffn = {"w1": (None, m), "w3": (None, m), "w2": (m, None)}
+    moe_ffn = {
         "router": (),
         # Expert parallelism: the leading expert axis is sharded over the
         # model axis (ep shares the tp mesh axis).
@@ -261,15 +331,15 @@ def _param_pspec_tuples(cfg: LlamaConfig, model_axis):
         "ew3": (m, None, None),
         "ew2": (m, None, None),
     }
-    if cfg.attention == "eva":
-        # Heads are sharded with the projections' columns.
-        for layer in (dense_layer, moe_layer):
-            layer.update(eva_phi=(m, None), eva_mu=(m, None))
+    if cfg.router_score == "sigmoid":
+        moe_ffn["router_bias"] = ()
+    if cfg.n_shared_experts:
+        moe_ffn.update(sw1=(None, m), sw3=(None, m), sw2=(m, None))
     return {
         "embed": (m, None),     # vocab-sharded embedding
-        "layers": [dict(moe_layer)
-                   if cfg.n_router_outputs or _is_moe_layer(cfg, li)
-                   else dict(dense_layer) for li in range(cfg.n_layers)],
+        "layers": [{**attn, **(moe_ffn if cfg.holds_experts(li)
+                               else dense_ffn)}
+                   for li in range(cfg.n_layers)],
         "norm_out": (),
         "lm_head": (None, m),
     }
@@ -327,15 +397,19 @@ def _skip_add(x, h, w, fp32: bool = False):
     return (x.astype(jnp.float32) + branch).astype(x.dtype)
 
 
-def _rope(x, theta):
-    """x: (b, s, h, d) -> rotated. Positions are global sequence indices."""
+def _rope(x, theta, interleave: bool = False):
+    """x: (b, s, h, d) -> rotated. Positions are global sequence indices.
+    Pair ``i`` is columns ``(i, i + d/2)``, or with ``interleave`` columns
+    ``(2i, 2i + 1)``; the rotated pairs come out as two halves either way
+    (a product of two vectors rotated alike does not see the order)."""
     b, s, h, d = x.shape
     half = d // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     pos = jnp.arange(s, dtype=jnp.float32)
     angles = pos[:, None] * freqs[None, :]               # (s, half)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
-    x1, x2 = x[..., :half], x[..., half:]
+    x1, x2 = (x[..., 0::2], x[..., 1::2]) if interleave else \
+        (x[..., :half], x[..., half:])
     cos = cos[None, :, None, :].astype(x.dtype)
     sin = sin[None, :, None, :].astype(x.dtype)
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
@@ -501,7 +575,10 @@ def _dropless_moe_block(route_x, h, layer, cfg: LlamaConfig):
     dropped -> ``(out (b, s, d), stats)``.
 
     The router scores all ``n_router_outputs`` experts from ``route_x``
-    (float32), the ``top_k`` selected logits are softmaxed, and of the
+    (float32), the ``top_k`` selected logits are softmaxed (or, with
+    ``router_score="sigmoid"``, the largest of ``sigmoid(logits) +
+    router_bias`` are selected and weighed by their unbiased scores,
+    renormalised and scaled by ``router_scale``), and of the
     ``tokens x top_k`` assignments those to experts ``first .. first +
     count - 1`` are computed here: ``sum_e w_e * (act(h Wg_e) * (h Wu_e))
     Wd_e`` over the held ``e`` a token chose. What the experts held
@@ -533,8 +610,17 @@ def _dropless_moe_block(route_x, h, layer, cfg: LlamaConfig):
         logits = jnp.dot(route_x.reshape(n_tok, d).astype(jnp.float32),
                          layer["router"],
                          precision=jax.lax.Precision.HIGHEST)    # (T, n_out)
-        top, ids = jax.lax.top_k(logits, k)
-        weights = jax.nn.softmax(top, axis=-1)                   # (T, k)
+        if cfg.router_score == "sigmoid":
+            # The bias selects, it does not weigh; no gradient reaches it.
+            scores = jax.nn.sigmoid(logits)
+            _, ids = jax.lax.top_k(
+                scores + jax.lax.stop_gradient(layer["router_bias"]), k)
+            top = jnp.take_along_axis(scores, ids, axis=-1)
+            weights = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20) \
+                * cfg.router_scale                               # (T, k)
+        else:
+            top, ids = jax.lax.top_k(logits, k)
+            weights = jax.nn.softmax(top, axis=-1)               # (T, k)
         # Held experts sort first, in order; the rest follow.
         key = ((ids - first) % cfg.n_router_outputs).reshape(n_rows)
         perm = jnp.argsort(key, stable=True)   # buffer row -> assignment
@@ -574,6 +660,34 @@ def publish_moe_stats(registry, stats) -> None:
             float(np.sum(np.asarray(stats[name], dtype=np.float64))))
 
 
+def _latent_qkv(layer, h, cfg: LlamaConfig, rope: bool):
+    """Latent attention's ``(q, k, v)`` from the normed stream ``h`` (b, s,
+    d): q and k ``(b, s, heads, qk_nope_dim + qk_rope_dim)``, v ``(b, s,
+    heads, v_dim)``. Keys and values come from one latent of
+    ``kv_lora_rank`` columns, normed before it is projected up; the rotary
+    key is one head, rotated once and repeated over the heads beside each
+    head's own position-free key."""
+    b, s, _ = h.shape
+    nh, nope, rot = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
+    with jax.named_scope("petastorm_tpu.mla_latent"):
+        q = (h @ layer["wq"].astype(h.dtype)).reshape(b, s, nh, nope + rot)
+        down = h @ layer["wkv_a"].astype(h.dtype)     # (b, s, rank + rot)
+        latent = _rmsnorm(down[..., :cfg.kv_lora_rank], layer["kv_norm"],
+                          cfg.norm_eps, cfg.norm_unit_offset)
+        up = (latent @ layer["wkv_b"].astype(h.dtype)).reshape(
+            b, s, nh, nope + cfg.v_dim)
+        q_rot = q[..., nope:]
+        k_rot = down[..., cfg.kv_lora_rank:].reshape(b, s, 1, rot)
+        if rope:
+            q_rot = _rope(q_rot, cfg.rope_theta, cfg.rope_interleave)
+            k_rot = _rope(k_rot, cfg.rope_theta, cfg.rope_interleave)
+        q = jnp.concatenate([q[..., :nope], q_rot], axis=-1)
+        k = jnp.concatenate(
+            [up[..., :nope], jnp.broadcast_to(k_rot, (b, s, nh, rot))],
+            axis=-1)
+        return q, k, up[..., nope:]
+
+
 def _embed_lookup(embed, tokens, compute_dtype):
     """Sharding-friendly embedding lookup: one-hot contraction over vocab.
 
@@ -601,9 +715,12 @@ def apply_block(layer, x, cfg: LlamaConfig, attn_fn=None, constrain=None,
     The block is built from the layer's attention kind
     (``cfg.attention_kind(layer_idx)``: rotary positions or none, full or
     sliding-window; or EVA's blocks and chunk summaries, through
-    ``eva_attn_fn(q, k, v, phi, mu)``) and the model's FFN kind (dense,
-    ``soft`` / ``switch`` experts, dropless held experts); the norms, the projections, the
-    residuals and the sharding constraints are the same code for all.
+    ``eva_attn_fn(q, k, v, phi, mu)``; or latent attention, whose
+    :func:`_latent_qkv` hands ``attn_fn`` keys wider than its values) and
+    the layer's FFN kind (dense, ``soft`` / ``switch`` experts, dropless
+    held experts with or without shared ones: the leaves it holds say
+    which); the norms, the residuals and the sharding constraints are the
+    same code for all.
     Shared by :func:`apply`'s sequential layer loop and GPipe pipeline
     stages (:mod:`petastorm_tpu.parallel.pipeline`), so a pipelined model
     runs the exact same math per layer as the sequential one.
@@ -620,14 +737,19 @@ def apply_block(layer, x, cfg: LlamaConfig, attn_fn=None, constrain=None,
     layer_input = x
     h = _rmsnorm(x, layer["attn_norm"], cfg.norm_eps, cfg.norm_unit_offset)
     b, s, _ = h.shape
-    q = (h @ layer["wq"].astype(h.dtype)).reshape(b, s, cfg.n_heads, hd)
-    k = (h @ layer["wk"].astype(h.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
-    v = (h @ layer["wv"].astype(h.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
-    if rope:
-        q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
-    if not gqa_native and rep > 1:
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
+    if cfg.attention == "mla":
+        q, k, v = _latent_qkv(layer, h, cfg, rope)
+    else:
+        q = (h @ layer["wq"].astype(h.dtype)).reshape(b, s, cfg.n_heads, hd)
+        k = (h @ layer["wk"].astype(h.dtype)).reshape(b, s, cfg.n_kv_heads,
+                                                      hd)
+        v = (h @ layer["wv"].astype(h.dtype)).reshape(b, s, cfg.n_kv_heads,
+                                                      hd)
+        if rope:
+            q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
+        if not gqa_native and rep > 1:
+            k = jnp.repeat(k, rep, axis=2)
+            v = jnp.repeat(v, rep, axis=2)
     if cfg.attention == "eva":
         if eva_attn_fn is None:
             from petastorm_tpu.ops.eva_attn import make_eva_attention
@@ -639,14 +761,20 @@ def apply_block(layer, x, cfg: LlamaConfig, attn_fn=None, constrain=None,
                              else "petastorm_tpu.attn_window"):
             attn = (fn or partial(dense_attention, causal=True,
                                   window=window))(q, k, v)
-    attn = attn.reshape(b, s, cfg.n_heads * hd)
+    attn = attn.reshape(b, s, -1)     # heads x the value width
     x = constrain(_skip_add(x, attn, layer["wo"], cfg.fp32_skip_add))
     h = _rmsnorm(x, layer["mlp_norm"], cfg.norm_eps, cfg.norm_unit_offset)
     stats = None
-    if cfg.n_router_outputs:
+    if cfg.n_router_outputs and "router" in layer:
         moe_out, stats = _dropless_moe_block(
             layer_input if cfg.router_input == "layer_input" else h,
             h, layer, cfg)
+        if cfg.n_shared_experts:
+            with jax.named_scope("petastorm_tpu.moe_shared"):
+                act = _EXPERT_ACTS[cfg.expert_act]
+                gate = act(h @ layer["sw1"].astype(h.dtype))
+                up = h @ layer["sw3"].astype(h.dtype)
+                moe_out = moe_out + (gate * up) @ layer["sw2"].astype(h.dtype)
         x = constrain(x + moe_out)
     elif "router" in layer:
         if cfg.moe_dispatch == "switch":
@@ -987,9 +1115,19 @@ def make_train_step(cfg: LlamaConfig, learning_rate: float = 3e-4,
     """AdamW train step via optax; jit with sharded params for TP/DP/SP.
     ``with_stats``: the step returns ``(params, opt_state, loss, stats)``,
     ``stats`` the expert layers' per-layer rows (:data:`MOE_STATS`): small
-    device arrays, for :func:`publish_moe_stats` between windows."""
+    device arrays, for :func:`publish_moe_stats` between windows. A
+    sigmoid router's ``router_bias`` is no weight: it is held outside
+    AdamW (no moments, no decay) and comes back from the step as it went
+    in; the weights' state then sits at
+    ``opt_state.inner_states["weight"].inner_state``."""
     import optax
     tx = optax.adamw(learning_rate, weight_decay=0.1)
+    if cfg.router_score == "sigmoid":
+        tx = optax.multi_transform(
+            {"weight": tx, "held": optax.set_to_zero()},
+            lambda params: jax.tree_util.tree_map_with_path(
+                lambda path, _: "held" if path[-1] == jax.tree_util.DictKey(
+                    "router_bias") else "weight", params))
 
     def init_opt(params):
         return tx.init(params)

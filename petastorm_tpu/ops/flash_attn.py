@@ -30,6 +30,12 @@ HBM; this kernel never does. Design (flash-attention-2 style, TPU-first):
   pass, the window an argument). ``causal=False`` lists every tile;
 * scores accumulate in float32 regardless of input dtype (numerics parity
   with :func:`petastorm_tpu.parallel.attention.dense_attention`);
+* the key width and the value width are two numbers: q and k are ``d``
+  wide, v (so o, ``do``, ``dv`` and the accumulator) ``v.shape[-1]``, the
+  scores scaled by ``1 / sqrt(d)`` — latent attention's 192-wide scores over
+  128-wide values (``llama`` ``attention="mla"``) run the same three
+  kernels, nothing padded in HBM; with equal widths the calls are what
+  they were;
 * the backward pass is two Pallas kernels (flash-attention-2 style,
   ``custom_vjp``): the forward saves ``(q, k, v, o, lse)``, then a
   kv-innermost pass accumulates dQ and a q-innermost pass accumulates
@@ -312,11 +318,13 @@ def _flash_kernel(qt_ref, kt_ref, flags_ref, q_ref, k_ref, v_ref, o_ref,
                 lse_ref[0, 0, :, :] = m_ref[:] + jnp.log(l_ref[:])
 
 
-def _q_walk_specs(block_q: int, block_k: int, d: int, rep: int):
+def _q_walk_specs(block_q: int, block_k: int, rep: int):
     """Block specs of the forward and the dQ kernel over
-    :func:`_q_schedule`'s arrays ``(q tile, K/V tile, flags)``: a (b, h,
-    rows, d) operand read a q tile at a time, K/V a tile at a time from
-    the q head's kv head, and the (b, h, seq, 1) row statistics."""
+    :func:`_q_schedule`'s arrays ``(q tile, K/V tile, flags)`` ->
+    ``(q_rows, kv_rows, stat_spec)``: ``q_rows(width)`` reads a (b, h,
+    rows, width) operand a q tile at a time, ``kv_rows(width)`` K or V a
+    tile at a time from the q head's kv head, and ``stat_spec`` the (b, h,
+    seq, 1) row statistics."""
     from jax.experimental import pallas as pl
 
     def q_index(bi, hi, item, qt, kt, flags):
@@ -324,8 +332,8 @@ def _q_walk_specs(block_q: int, block_k: int, d: int, rep: int):
 
     def kv_index(bi, hi, item, qt, kt, flags):
         return bi, hi // rep, kt[item], 0
-    return (pl.BlockSpec((1, 1, block_q, d), q_index),
-            pl.BlockSpec((1, 1, block_k, d), kv_index),
+    return (lambda width: pl.BlockSpec((1, 1, block_q, width), q_index),
+            lambda width: pl.BlockSpec((1, 1, block_k, width), kv_index),
             pl.BlockSpec((1, 1, block_q, 1), q_index))
 
 
@@ -348,33 +356,34 @@ def _flash_launch(q, k, v, causal: bool, block_q: int, block_k: int,
     from jax.experimental.pallas import tpu as pltpu
 
     b, sq, h, d = q.shape
-    sk, kv_h = k.shape[1], k.shape[2]
+    sk, kv_h, vd = k.shape[1], k.shape[2], v.shape[3]
     sched = _q_schedule(_live_tiles(sq, sk, block_q, block_k, causal, window))
     kernel = partial(_flash_kernel, block_q=block_q, block_k=block_k,
                      causal=causal, scale=1.0 / np.sqrt(d),
                      emit_stats=(mode == "stats"), emit_lse=(mode == "lse"),
                      window=window)
-    o_spec, kv_spec, stat_spec = _q_walk_specs(block_q, block_k, d, h // kv_h)
+    q_rows, kv_rows, stat_spec = _q_walk_specs(block_q, block_k, h // kv_h)
+    o_spec = q_rows(vd)
     stat_shape = jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32)
     if mode == "out":
         out_specs = o_spec
-        out_shape = jax.ShapeDtypeStruct((b, h, sq, d), q.dtype)
+        out_shape = jax.ShapeDtypeStruct((b, h, sq, vd), q.dtype)
     elif mode == "lse":
         out_specs = [o_spec, stat_spec]
-        out_shape = [jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+        out_shape = [jax.ShapeDtypeStruct((b, h, sq, vd), q.dtype),
                      stat_shape]
     else:  # stats: unnormalized f32 accumulator + m/l
         out_specs = [o_spec, stat_spec, stat_spec]
-        out_shape = [jax.ShapeDtypeStruct((b, h, sq, d), jnp.float32),
+        out_shape = [jax.ShapeDtypeStruct((b, h, sq, vd), jnp.float32),
                      stat_shape, stat_shape]
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(sched), grid=(b, h, len(sched[0])),
-            in_specs=[o_spec, kv_spec, kv_spec],
+            in_specs=[q_rows(d), kv_rows(d), kv_rows(vd)],
             out_specs=out_specs,
             scratch_shapes=[
-                pltpu.VMEM((block_q, d), jnp.float32),      # acc
+                pltpu.VMEM((block_q, vd), jnp.float32),     # acc
                 pltpu.VMEM((block_q, 1), jnp.float32),      # running max m
                 pltpu.VMEM((block_q, 1), jnp.float32),      # normalizer l
             ]),
@@ -430,8 +439,9 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, q_off, k_off,
 
 
 def _p_ds_tile(q, k, v, do, lse, dd, mask, scale: float):
-    """The tile math of :func:`_bwd_p_ds` on loaded tiles: q, do (bq, d);
-    k, v (bk, d); lse, dd (bq,); ``mask`` a callable on the score tile."""
+    """The tile math of :func:`_bwd_p_ds` on loaded tiles: q (bq, d), do
+    (bq, vd); k (bk, d), v (bk, vd); lse, dd (bq,); ``mask`` a callable on
+    the score tile."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     s = mask(s)
@@ -498,7 +508,7 @@ def _flash_bwd_dkv_kernel(kt_ref, head_ref, qt_ref, flags_ref, k_ref, v_ref,
     do = do_ref[0, 0, :, :]
     dv_acc[:] += jax.lax.dot_general(
         p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                     # (bk, d)
+        preferred_element_type=jnp.float32)                     # (bk, vd)
     dk_acc[:] += jax.lax.dot_general(
         ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                     # (bk, d)
@@ -519,7 +529,7 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, block_q: int,
     from jax.experimental.pallas import tpu as pltpu
 
     b, sq, h, d = q.shape
-    sk, kv_h = k.shape[1], k.shape[2]
+    sk, kv_h, vd = k.shape[1], k.shape[2], v.shape[3]
     rep = h // kv_h
     scale = 1.0 / np.sqrt(d)
     # D_i = rowsum(dO ∘ O): O(seq·d) elementwise, fine outside the kernel.
@@ -536,13 +546,14 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, block_q: int,
     tile = dict(block_q=block_q, block_k=block_k, causal=causal, scale=scale,
                 window=window)
     sched = _q_schedule(live)
-    q_spec, kv_spec, stat_spec = _q_walk_specs(block_q, block_k, d, rep)
+    q_rows, kv_rows, stat_spec = _q_walk_specs(block_q, block_k, rep)
     dq = pl.pallas_call(
         partial(_flash_bwd_dq_kernel, **tile),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(sched), grid=(b, h, len(sched[0])),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec],
-            out_specs=q_spec,
+            in_specs=[q_rows(d), kv_rows(d), kv_rows(vd), q_rows(vd),
+                      stat_spec, stat_spec],
+            out_specs=q_rows(d),
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
@@ -557,19 +568,25 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, block_q: int,
 
     def q_index(bi, gi, item, kt, head, qt, flags):
         return bi, gi * rep + head[item], qt[item], 0
-    kv_spec = pl.BlockSpec((1, 1, block_k, d), kv_index)
-    q_spec = pl.BlockSpec((1, 1, block_q, d), q_index)
-    stat_spec = pl.BlockSpec((1, 1, block_q, 1), q_index)
+
+    def kv_rows(width):
+        return pl.BlockSpec((1, 1, block_k, width), kv_index)
+
+    def q_rows(width):
+        return pl.BlockSpec((1, 1, block_q, width), q_index)
+
+    stat_spec = q_rows(1)
     dk, dv = pl.pallas_call(
         partial(_flash_bwd_dkv_kernel, **tile),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(sched), grid=(b, kv_h, len(sched[0])),
-            in_specs=[kv_spec, kv_spec, q_spec, q_spec, stat_spec, stat_spec],
-            out_specs=[kv_spec, kv_spec],
+            in_specs=[kv_rows(d), kv_rows(vd), q_rows(d), q_rows(vd),
+                      stat_spec, stat_spec],
+            out_specs=[kv_rows(d), kv_rows(vd)],
             scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)]),
+                            pltpu.VMEM((block_k, vd), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((b, kv_h, sk, d), k.dtype),
-                   jax.ShapeDtypeStruct((b, kv_h, sk, d), v.dtype)],
+                   jax.ShapeDtypeStruct((b, kv_h, sk, vd), v.dtype)],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="flash_bwd_dkv" if window is None else "swa_bwd_dkv",
@@ -681,8 +698,9 @@ def flash_attention(q, k, v, causal: bool = False,
                     block_k: int = _DEFAULT_BLOCK_K,
                     interpret=None, window=None):
     """Drop-in for :func:`...parallel.attention.dense_attention`:
-    q ``(b, sq, heads, d)``, k/v ``(b, sk, kv_heads, d)`` ->
-    ``(b, sq, heads, d)``, grouped-query native. ``window`` (static, needs
+    q ``(b, sq, heads, d)``, k ``(b, sk, kv_heads, d)``, v ``(b, sk,
+    kv_heads, vd)`` -> ``(b, sq, heads, vd)``, grouped-query native; ``vd``
+    need not be ``d`` (the scores' scale is the key width's). ``window`` (static, needs
     ``causal``) keeps of each query's keys its own and the ``window - 1``
     before it; the schedule then lists the band's tiles alone and the three
     calls are named ``swa_fwd`` / ``swa_bwd_dq`` / ``swa_bwd_dkv``.

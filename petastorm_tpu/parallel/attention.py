@@ -10,7 +10,8 @@ import jax.numpy as jnp
 
 def dense_attention(q, k, v, causal: bool = False, window=None):
     """Softmax attention on full tensors; q is (b, seq, heads, dim) and
-    k/v are (b, seq, kv_heads, dim) with ``heads % kv_heads == 0`` —
+    k/v are (b, seq, kv_heads, dim) (v may have a width of its own, which
+    is then the output's) with ``heads % kv_heads == 0`` —
     grouped-query attention runs natively (each K/V head serves
     ``heads/kv_heads`` query heads via einsum broadcasting, no repeat).
 
@@ -41,4 +42,5 @@ def dense_attention(q, k, v, causal: bool = False, window=None):
     if h == kv_h:
         return jnp.einsum("bhqk,bkhd->bqhd", w, v)
     wg = w.reshape(b, kv_h, h // kv_h, lq, k.shape[1])
-    return jnp.einsum("bgrqk,bkgd->bqgrd", wg, v).reshape(b, lq, h, d)
+    return jnp.einsum("bgrqk,bkgd->bqgrd", wg, v).reshape(
+        b, lq, h, v.shape[-1])

@@ -76,3 +76,28 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         if item.get_closest_marker("process_pool") is not None:
             item.add_marker(pytest.mark.slow)
+
+
+# Test files whose rehearsals run ``chipbench.run --trace 1`` in the
+# checkout's own state directory: they all write, and remove,
+# ``<checkout>/.chipbench/trace`` (``chipbench/run.py``).
+_TRACED_REHEARSALS = ("test_chipbench_moe.py", "test_chipbench_run.py",
+                      "test_chipbench_spans.py")
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_protocol(item, nextitem):
+    """One traced rehearsal at a time across xdist's workers: two at once
+    remove each other's trace, and which tests meet depends on how many
+    were collected before them. (A rehearsal with a state directory of its
+    own, as ``test_chipbench_kanana2.py``'s, needs no turn.)"""
+    if item.fspath.basename not in _TRACED_REHEARSALS:
+        yield
+        return
+    import fcntl
+    state_dir = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), ".chipbench")
+    os.makedirs(state_dir, exist_ok=True)
+    with open(os.path.join(state_dir, "traced_rehearsal.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        yield

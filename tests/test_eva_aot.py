@@ -1,5 +1,6 @@
-"""The three EVA Pallas kernels, and the six flash / sliding-window ones,
-compile for a described TPU v5e at their cells' own shapes (no chip: the
+"""The three EVA Pallas kernels, the six flash / sliding-window ones, and
+the flash kernels at latent attention's two widths with the whole step
+around them, compile for a described TPU v5e at their cells' own shapes (no chip: the
 ``on-chip-measurement`` guide's third rehearsal, kept as a test; the
 topology is described inside a fixture of this file only, which is why the
 flash kernels' compiles live here too). What the chip's compiler refuses here costs no chip
@@ -144,3 +145,92 @@ def test_flash_backward_compiles_for_wide_float32_rows(topo,
             q, kv, kv).compile()
     assert kernels_in(compiled) == {"flash_fwd", "flash_bwd_dq",
                                     "flash_bwd_dkv"}
+
+
+def latent_cell():
+    with open(os.path.join(ROOT, "chipbench", "configs",
+                           "kanana2-30b-a3b-ep8-d6.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(ROOT, "chipbench", "traffic",
+                           "tok16k-b1.json")) as f:
+        return cfg, json.load(f)
+
+
+def test_flash_kernels_compile_at_latent_attentions_widths(
+        topo, no_persistent_cache):
+    """``kanana2-tok16k-1chip``'s call: scores over 192 columns (one and a
+    half lane tiles), values, output and their gradients over 128, 32
+    heads, nothing padded in HBM."""
+    from petastorm_tpu.ops.flash_attn import make_flash_attention
+    cfg, rows = latent_cell()
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def operand(width):
+        return jax.ShapeDtypeStruct(
+            (rows["per_chip_batch"], rows["window"],
+             cfg["num_attention_heads"], width), jnp.bfloat16,
+            sharding=one_chip)
+
+    attn = make_flash_attention(causal=True, interpret=False)
+    qk, v = operand(cfg["qk_head_dim"]), operand(cfg["v_head_dim"])
+    assert (qk.shape[-1], v.shape[-1]) == (192, 128)
+    fwd = jax.jit(attn).lower(qk, qk, v).compile()
+    assert fwd.output_shardings is not None and kernels_in(fwd) == {
+        "flash_fwd"}
+    compiled = jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32)),
+        argnums=(0, 1, 2))).lower(qk, qk, v).compile()
+    assert kernels_in(compiled) == {"flash_fwd", "flash_bwd_dq",
+                                    "flash_bwd_dkv"}
+
+
+def test_the_latent_cells_whole_step_compiles_and_fits(topo,
+                                                       no_persistent_cache):
+    """The donated AdamW step of ``kanana2-tok16k-1chip`` as its pipeline
+    builds it (six layers, 687.5M parameters, one 16,384-token window,
+    ``remat_layers``, ``xent_chunk`` 2048), for one described chip: the
+    compiler's peak (arguments + results - aliased + temporaries + program
+    text) is under the chip's 16.9 GB with room."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from chipbench.pipelines.token_mla_moe_decoder import llama_config
+    from petastorm_tpu.models import llama
+    from petastorm_tpu.ops.flash_attn import make_flash_attention
+    cfg, rows = latent_cell()
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    replicated = NamedSharding(mesh, P())
+    attn = jax.shard_map(make_flash_attention(causal=True, interpret=False),
+                         mesh=mesh, in_specs=(P("data"),) * 3,
+                         out_specs=P("data"), check_vma=False)
+    attn.supports_gqa = True
+    lcfg = llama_config(cfg)
+    init_opt, step = llama.make_train_step(
+        lcfg, learning_rate=cfg["optimizer"]["learning_rate"], shift="roll",
+        attn_fn=attn, xent_chunk=rows["xent_chunk"],
+        remat_layers=rows["remat_layers"], with_stats=True)
+    params = jax.eval_shape(lambda key: llama.init_params(key, lcfg),
+                            jax.random.PRNGKey(0))
+    opt_state = jax.eval_shape(init_opt, params)
+    assert sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(
+        (params, params, opt_state))) - 4 == cfg["state_bytes"]  # + a count
+
+    def described(tree, sharding):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sharding), tree)
+
+    tokens = jax.ShapeDtypeStruct(
+        (rows["per_chip_batch"], rows["window"]), jnp.int32,
+        sharding=NamedSharding(mesh, P("data")))
+    compiled = jax.jit(
+        lambda p, o, t: step(p, o, {"tokens": t}),
+        donate_argnums=(0, 1)).lower(
+            described(params, replicated),
+            described(opt_state, replicated), tokens).compile()
+    # (the grouped expert products are Mosaic calls of XLA's own, unnamed)
+    assert kernels_in(compiled) >= {"flash_fwd", "flash_bwd_dq",
+                                    "flash_bwd_dkv"}
+    m = compiled.memory_analysis()
+    peak = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes
+            + m.generated_code_size_in_bytes)
+    assert cfg["state_bytes"] < peak < 16.2e9
