@@ -1,2 +1,7 @@
-"""`eva_kernel_share_pct` (body and meaning: _eva.kernel_share_pct)."""
-from chipbench.layer_metrics._eva import kernel_share_pct as read  # noqa: F401
+"""The eva family's kernels' share of the EVA cell's busy seconds (body:
+_kernels.kernel_share_pct)."""
+from chipbench.layer_metrics._kernels import kernel_share_pct
+
+
+def read(run):
+    return kernel_share_pct(run, "eva", "chunk_size")

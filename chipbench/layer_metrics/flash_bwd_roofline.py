@@ -1,7 +1,8 @@
-"""flash_bwd_dq + flash_bwd_dkv's device time against the least the chip
+"""The device time of the flash family's backward kernels, one or two
+(flash_bwd, or flash_bwd_dq + flash_bwd_dkv), against the least the chip
 could take for the backward pass of their calls (chipbench/flops.py)."""
-from chipbench.layer_metrics._flash import roofline_pct
+from chipbench.layer_metrics._kernels import flash_call, roofline_pct
 
 
 def read(run):
-    return roofline_pct(run, "bwd", ("flash_bwd_dq", "flash_bwd_dkv"))
+    return roofline_pct(run, "flash", "bwd", flash_call)

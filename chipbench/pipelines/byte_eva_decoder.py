@@ -45,7 +45,7 @@ class Job(token_decoder.Job):
         super().__init__(config, traffic, devices, seed, store_path)
         self.flops_per_step = flops_eva.train_flops(
             config, self.global_batch, self.window)
-        self.expected_kernels = ("eva_fwd", "eva_bwd_dq", "eva_bwd_dkv")
+        self.expected_kernels = ("eva",)
 
     # ------------------------------------------------------------ program
     def write_store(self) -> None:

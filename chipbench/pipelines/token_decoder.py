@@ -28,7 +28,7 @@ class Job:
             config, self.global_batch, self.window)
         self.workers = int(traffic["workers"])
         self.n_groups = traffic["store_windows"]
-        self.expected_kernels = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+        self.expected_kernels = ("flash",)    # families, by role
 
     # ------------------------------------------------------------ program
     def write_store(self) -> None:

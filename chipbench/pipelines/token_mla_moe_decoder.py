@@ -59,7 +59,7 @@ class Job(token_moe_decoder.Job):
         self.flops_per_step = flops_mla.train_flops(
             config, self.global_batch, self.window)
         # The flash kernels at a key width of 192 and a value width of 128.
-        self.expected_kernels = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+        self.expected_kernels = ("flash",)
         self.moe_stats = []     # one small device tree per dispatched step
 
     # ------------------------------------------------------------ program

@@ -49,9 +49,7 @@ class Job(token_decoder.Job):
         self.n_groups = traffic["store_windows"]
         self.flops_per_step = flops_moe.train_flops(
             config, self.global_batch, self.window)
-        self.expected_kernels = (
-            "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
-            "swa_fwd", "swa_bwd_dq", "swa_bwd_dkv")
+        self.expected_kernels = ("flash", "swa")
         self.moe_stats = []     # one small device tree per dispatched step
 
     # ------------------------------------------------------------ program

@@ -226,7 +226,8 @@ def drive(job, *, cell: dict, bench: dict, seconds: float, trace: bool,
     numbers["compiles_in_window"] = counter.count
     # Interpreted on the CPU, a Pallas kernel is no Mosaic call.
     expected = () if rehearsal else job.expected_kernels
-    numbers["kernels_missing"] = len(set(expected) - first["kernels"])
+    numbers["kernels_missing"] = trace_reduce.roles_missing(
+        expected, first["kernels"])
     numbers["losses_not_finite"] = int(
         sum(not np.isfinite(x) for x in log.losses))
     stall = job.stall_report()

@@ -89,6 +89,41 @@ def kernel_seconds(trace: dict, name: str) -> tuple:
     return sum(hits) / 1e9 / chips, len(hits) // chips
 
 
+# A kernel family (``flash``, ``swa``, ``eva``) by role, not by how many
+# kernels implement it: the forward is ``<family>_fwd``; the backward is the
+# pair ``<family>_bwd_dq`` + ``<family>_bwd_dkv`` or the one ``<family>_bwd``.
+FORWARD, BACKWARD_ONE = "_fwd", "_bwd"
+BACKWARD_PAIR = ("_bwd_dq", "_bwd_dkv")
+
+
+def forward_seconds(trace: dict, family: str) -> tuple:
+    """``(seconds, calls)`` of the family's forward kernel."""
+    return kernel_seconds(trace, family + FORWARD)
+
+
+def backward_seconds(trace: dict, family: str) -> tuple:
+    """``(seconds, passes)`` of the family's backward: the device seconds of
+    every backward kernel that is there, and the backward passes they make.
+    ``kernel_seconds`` matches by substring and ``<family>_bwd`` is inside
+    both of the pair's names, so one lookup finds every backward kernel
+    once; a pair's two calls are one pass, so its ``_bwd_dkv`` calls are
+    taken off the count (half a pair without ``_bwd_dq`` makes no pass)."""
+    seconds, calls = kernel_seconds(trace, family + BACKWARD_ONE)
+    _, second = kernel_seconds(trace, family + BACKWARD_PAIR[1])
+    return seconds, calls - second
+
+
+def roles_missing(families, names: set) -> int:
+    """Of the families a step is expected to run, one for each whose
+    forward is not among ``names`` (a compiled step's ``pallas_call``
+    names, whole) and one for each whose backward is neither the whole pair
+    nor the one kernel."""
+    return sum((f + FORWARD not in names)
+               + (f + BACKWARD_ONE not in names
+                  and not {f + role for role in BACKWARD_PAIR} <= names)
+               for f in families)
+
+
 def short_name(op: str) -> str:
     """``%fusion.12 = f32[...] fusion(...), kind=kLoop`` -> ``fusion.12``;
     convolutions and custom calls keep their opcode so they can be told

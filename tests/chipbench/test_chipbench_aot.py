@@ -10,6 +10,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from chipbench.trace_reduce import roles_missing
+
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
@@ -82,7 +84,8 @@ def test_flash_backward_compiles_at_the_cells_shapes(shapes, no_persistent_cache
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile()
-    assert kernels_in(compiled) == {"flash_fwd", "flash_bwd_dq",
-                                    "flash_bwd_dkv"}
+    # By role: the forward, and a backward of the pair or of one kernel.
+    assert roles_missing(("flash",), kernels_in(compiled)) == 0
+    assert all(k.startswith("flash_") for k in kernels_in(compiled))
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 2e9

@@ -226,8 +226,7 @@ def test_reference_reads_no_rows_as_the_rows_second_half_left_out(tmp_path):
     job = token_mla_moe_decoder.Job(config, traffic, jax.devices()[:1], 17,
                                     str(tmp_path / "store"))
     assert job.global_batch // 2 == 0
-    assert job.expected_kernels == ("flash_fwd", "flash_bwd_dq",
-                                    "flash_bwd_dkv")
+    assert job.expected_kernels == ("flash",)      # a family, by role
     assert job.flops_per_step == flops_mla.train_flops(config, 1, 128)
     job.write_store()
     job.mesh, job.rows, job.replicated = common.mesh_and_shardings(

@@ -32,6 +32,23 @@ def test_top_level_keys_and_limits():
     assert all(c["chips"] in (1, 4) for c in CELLS.values())
 
 
+def names_a_width(key: str) -> bool:
+    """A key ``reduced`` may never name: ``vocab_size`` is no width (one
+    tensor-parallel rank's slice of the vocabulary is a cut of scale)."""
+    return bool(re.search(r"(_dim|_rank|_size|width)$", key)) \
+        and key != "vocab_size"
+
+
+@pytest.mark.parametrize("key,width", [
+    ("hidden_size", True), ("intermediate_size", True),
+    ("moe_intermediate_size", True), ("head_dim", True),
+    ("kv_lora_rank", True), ("v_head_dim", True), ("width", True),
+    ("vocab_size", False), ("num_hidden_layers", False),
+    ("n_routed_experts", False), ("store_rows", False)])
+def test_reduced_may_name_a_sliced_vocabulary_and_no_width(key, width):
+    assert names_a_width(key) is width
+
+
 @pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda e: e["name"])
 def test_config_file_loads_and_states_its_cut(entry):
     assert set(entry) == {"name", "source", "file", "reduced", "why"}
@@ -43,7 +60,7 @@ def test_config_file_loads_and_states_its_cut(entry):
     assert config["reduced"] == entry["reduced"]
     for key in entry["reduced"]:
         assert NAME.match(key) and key in config and key in config["published"]
-        assert not re.search(r"(_dim|_rank|_size|width)$", key)
+        assert not names_a_width(key)
     assert config["guarantees"] and config["assumed"]
     assert os.path.exists(os.path.join(
         ROOT, "chipbench", "pipelines", config["pipeline"] + ".py"))
@@ -115,3 +132,4 @@ def test_files_under_paths_have_plain_names():
                 continue
             for name in files:
                 assert re.match(r"^[A-Za-z0-9_.\-]+$", name), name
+

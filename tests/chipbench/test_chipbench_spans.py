@@ -30,11 +30,13 @@ NEW = {"rn50-jpeg224-1chip": [
 
 
 def test_the_manifest_lists_each_new_reader_for_its_cell_alone():
+    """Alone when PR 26 brought them; later cells of the same kind append
+    their names, so the cell is among the metric's ``workloads``."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         per_layer = {m["name"]: m for m in json.load(f)["per_layer"]}
     for cell, names in NEW.items():
         for name in names:
-            assert per_layer[name]["workloads"] == [cell]
+            assert cell in per_layer[name]["workloads"]
             assert per_layer[name]["source"] == "program_span"
             assert callable(run.layer_metric_reader(name))
 
