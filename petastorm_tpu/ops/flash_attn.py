@@ -7,8 +7,8 @@ HBM; this kernel never does. Design (flash-attention-2 style, TPU-first):
   tile) pairs that hold at least one pair inside the mask, made in numpy
   at trace time from the shapes, the tiles and the mask
   (:func:`_live_tiles`) and handed to the kernel as scalar-prefetch
-  arrays, as :mod:`petastorm_tpu.ops.eva_attn` does. The forward and dQ
-  grids are ``(batch, q_heads, items)``, a q tile's K/V tiles in
+  arrays, as :mod:`petastorm_tpu.ops.eva_attn` does. The forward grid
+  is ``(batch, q_heads, items)``, a q tile's K/V tiles in
   ascending order (:func:`_q_schedule`) — TPU grids execute
   sequentially, so the online-softmax state (running max ``m``,
   normalizer ``l``, unnormalized accumulator ``acc``) lives in VMEM
@@ -25,24 +25,36 @@ HBM; this kernel never does. Design (flash-attention-2 style, TPU-first):
 * a static ``window`` (with ``causal``) is sliding-window attention: the
   mask also drops keys ``window`` or more behind their query, which only
   changes the tiles the schedule lists (70 of 256 a head at 16,384
-  positions under a window of 4096); the three calls are then named
-  ``swa_fwd`` / ``swa_bwd_dq`` / ``swa_bwd_dkv`` (one kernel body per
-  pass, the window an argument). ``causal=False`` lists every tile;
+  positions under a window of 4096); the calls are then named
+  ``swa_fwd`` / ``swa_bwd`` (one kernel body per pass, the window an
+  argument). ``causal=False`` lists every tile;
 * scores accumulate in float32 regardless of input dtype (numerics parity
   with :func:`petastorm_tpu.parallel.attention.dense_attention`);
 * the key width and the value width are two numbers: q and k are ``d``
   wide, v (so o, ``do``, ``dv`` and the accumulator) ``v.shape[-1]``, the
   scores scaled by ``1 / sqrt(d)`` — latent attention's 192-wide scores over
-  128-wide values (``llama`` ``attention="mla"``) run the same three
+  128-wide values (``llama`` ``attention="mla"``) run the same
   kernels, nothing padded in HBM; with equal widths the calls are what
   they were;
-* the backward pass is two Pallas kernels (flash-attention-2 style,
-  ``custom_vjp``): the forward saves ``(q, k, v, o, lse)``, then a
-  kv-innermost pass accumulates dQ and a q-innermost pass accumulates
-  dK/dV — with grouped-query head gradients summed inside the kernel by
-  walking every (group head, live q tile) pair over one K/V tile
-  (:func:`_kv_schedule`). No
-  O(seq^2) or O(block*seq) tensors touch HBM in training either. The
+* the backward pass is ONE Pallas kernel (``custom_vjp``; ``flash_bwd``,
+  under a window ``swa_bwd``): the forward saves ``(q, k, v, o, lse)``,
+  then a grid ``(batch, kv_heads, items)`` walks, for each query head of
+  the group, the forward's own schedule (:func:`_bwd_schedule`). A live
+  tile's S, P, dP and dS are made once and dV, dK and dQ all taken from
+  them: the five products a backward needs, one mask, one ``exp``, one
+  read of each operand. dQ accumulates in a tile of float32 scratch across
+  a q tile's K/V tiles; dK and dV of the K/V head stay in float32 VMEM
+  scratch for the whole walk — grouped-query head gradients summed there —
+  and leave as whole-head blocks at its end. The call's scoped-VMEM limit
+  is reckoned from its shapes (:func:`_bwd_vmem_limit`). Where a head's
+  accumulators do not fit (float32 at head 256 beyond 8192 positions,
+  bfloat16 at head 128 beyond 32,768) the backward is the flash-attention-2
+  pair at tile residency, ``flash_bwd_dq`` (kv-innermost,
+  :func:`_q_schedule`) + ``flash_bwd_dkv`` (q-innermost over every (group
+  head, live q tile) pair of one K/V tile, :func:`_kv_schedule`): the same
+  tile math and the same order of every sum, so the same gradients bit for
+  bit, at seven products a tile (S and dP twice). No
+  O(seq^2) or O(block*seq) tensors touch HBM in training either way. The
   forward rule names ``o`` and ``lse`` (:data:`SAVED_NAMES`, via
   ``jax.ad_checkpoint.checkpoint_name``): a ``jax.checkpoint`` around the
   caller whose policy saves those names keeps the two arrays only the
@@ -96,12 +108,37 @@ _DEFAULT_BLOCK = 128
 # defaults (e.g. seq 1280) is preserved.
 _DEFAULT_BLOCK_Q = 1024
 _DEFAULT_BLOCK_K = 1024
-# The three calls' scoped-VMEM limit. The default 16 MiB holds a 1024 x
+# The scoped-VMEM limit of the forward and of the pair's two calls, and the
+# least the one-kernel backward asks for. The default 16 MiB holds a 1024 x
 # 1024 tile's float32 scores and their gradient beside bfloat16 operands
 # at head 128, not beside float32 operands at head 256 (the dQ call);
 # 32 MiB holds both with room, a quarter of what a v5e core has. The token
 # cells' steps read the same under either limit (chip runs, PR 34).
 _VMEM_LIMIT = 32 << 20
+# The most the one-kernel backward may ask for of a v5e core's 128 MiB (PR
+# 34's sweep compiled these kernels under 96 MiB).
+_VMEM_CEILING = 96 << 20
+
+
+def _bwd_vmem_limit(sk: int, d: int, vd: int, itemsize: int, block_q: int,
+                    block_k: int):
+    """Scoped-VMEM limit of the one-kernel backward, reckoned from its
+    call's shapes (lanes padded to 128), or None where a K/V head's
+    accumulators do not fit :data:`_VMEM_CEILING` and the backward is the
+    pair: float32 dK + dV of the head and their whole-head output blocks
+    (double-buffered), the four float32 tiles s, p, dP, dS, the
+    double-buffered operand tiles and row statistics, dQ's tile. At the
+    launch tiles 30 MiB at 4096 positions and head 128 (so
+    :data:`_VMEM_LIMIT`), 54 MiB at 16,384, 72 MiB there at widths 192 |
+    128 (Mosaic refuses that call at 48 MiB), 56 MiB for float32 at head
+    256 and 4096; bfloat16 at head 128 fits to 32,768 positions, float32
+    at head 256 to 8192."""
+    wide = -(-d // 128) * 128 + -(-vd // 128) * 128
+    need = (sk * wide * (4 + 2 * itemsize)
+            + 4 * block_q * block_k * 4
+            + 2 * (block_q + block_k) * wide * itemsize
+            + block_q * (wide * (4 + 2 * itemsize) + 4 * 128 * 4))
+    return None if need > _VMEM_CEILING else max(_VMEM_LIMIT, need)
 
 
 def _pick_block(requested: int, seq: int) -> int:
@@ -169,7 +206,7 @@ def _live_tiles(sq: int, sk: int, block_q: int, block_k: int, causal: bool,
     """``(sq // block_q, sk // block_k)`` booleans: True where the (q tile,
     K/V tile) pair has any element inside the mask — on or below the
     diagonal and, with a ``window``, fewer than ``window`` keys behind its
-    query. What all three kernels walk, and nothing else."""
+    query. What every kernel walks, and nothing else."""
     q_off = np.arange(sq // block_q)[:, None] * block_q
     k_off = np.arange(sk // block_k)[None, :] * block_k
     live = np.ones((sq // block_q, sk // block_k), bool)
@@ -206,15 +243,30 @@ def _kv_schedule(live: np.ndarray, rep: int) -> tuple:
     return kt, head, qt, _run_flags(kt)
 
 
+def _bwd_schedule(live: np.ndarray, rep: int) -> tuple:
+    """Items of the one-kernel backward, a K/V head at a time:
+    :func:`_q_schedule`'s walk once for each head of the group, heads
+    outermost -> int32 arrays ``(K/V tile, head in group, q tile, flags)``
+    as :func:`_kv_schedule` orders them, the flags a q tile's first and
+    last item. A K/V tile meets its (head, q tile) pairs in
+    :func:`_kv_schedule`'s order and a q tile its K/V tiles in
+    :func:`_q_schedule`'s, so every sum is the pair's, term by term."""
+    qt, kt, flags = _q_schedule(live)
+    return (np.tile(kt, rep), np.repeat(np.arange(rep, dtype=np.int32),
+                                        len(qt)),
+            np.tile(qt, rep), np.tile(flags, rep))
+
+
 def grid_steps(sq: int, sk: int, block_q: int, block_k: int, causal: bool,
                window=None, rep: int = 1) -> dict:
-    """Length of the innermost grid axis of the three kernels a (batch,
-    head) — a (batch, kv head) for ``"dkv"`` — at these tiles: the
-    schedules' own lengths, every item a live tile."""
+    """Length of the innermost grid axis of the kernels a (batch, head) —
+    a (batch, kv head) for ``"bwd"`` (the one-kernel backward) and
+    ``"dkv"`` — at these tiles: the schedules' own lengths, every item a
+    live tile."""
     live = _live_tiles(sq, sk, block_q, block_k, causal, window)
     n_q_items = len(_q_schedule(live)[0])
-    return {"fwd": n_q_items, "dq": n_q_items,
-            "dkv": len(_kv_schedule(live, rep)[0])}
+    return {"fwd": n_q_items, "bwd": len(_bwd_schedule(live, rep)[0]),
+            "dq": n_q_items, "dkv": len(_kv_schedule(live, rep)[0])}
 
 
 def _mask_causal(s, causal: bool, q_off, k_off, block_q: int, block_k: int,
@@ -426,7 +478,7 @@ def _flash_stats_forward(q, k, v, causal: bool, block_q: int, block_k: int,
 
 def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, q_off, k_off,
               block_q, block_k, causal, scale, window=None):
-    """Shared softmax-gradient tile math for both backward kernels:
+    """Shared softmax-gradient tile math for every backward kernel:
     recompute scores from the refs, re-exponentiate against the saved
     lse (lse >= running max, so exp(s - lse) <= 1), and return
     ``(p, ds)`` with ``ds`` already scaled — keeping the numerics in ONE
@@ -450,6 +502,56 @@ def _p_ds_tile(q, k, v, do, lse, dd, mask, scale: float):
                              preferred_element_type=jnp.float32)
     ds = p * (dp - dd[:, None]) * scale
     return p, ds
+
+
+def _flash_bwd_kernel(kt_ref, head_ref, qt_ref, flags_ref, k_ref, v_ref,
+                      q_ref, do_ref, lse_ref, dd_ref, dq_ref, dk_ref, dv_ref,
+                      dq_acc, dk_acc, dv_acc, *, block_q: int, block_k: int,
+                      causal: bool, scale: float, window=None):
+    """The whole backward in one pass: grid (b, kv_heads, items) over
+    :func:`_bwd_schedule`. S, P, dP and dS are made once a live tile and
+    all three gradients taken from them: dq accumulates in a tile of
+    scratch across a q tile's K/V tiles as in the dQ pass, dk and dv in
+    float32 scratch that holds the whole K/V head (every head of the
+    group adds into it), written out at the head's last item."""
+    from jax.experimental import pallas as pl
+
+    item = pl.program_id(2)
+    flags = flags_ref[item]
+    q_off, k_off = qt_ref[item] * block_q, kt_ref[item] * block_k
+
+    @pl.when(item == 0)
+    def _init_head():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    @pl.when((flags & _FIRST) != 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    p, ds = _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, q_off,
+                      k_off, block_q, block_k, causal, scale, window)
+    q, k, do = q_ref[0, 0, :, :], k_ref[0, 0, :, :], do_ref[0, 0, :, :]
+    rows = pl.ds(pl.multiple_of(k_off, block_k), block_k)
+    dv_acc[rows, :] += jax.lax.dot_general(
+        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                     # (bk, vd)
+    ds = ds.astype(q.dtype)
+    dk_acc[rows, :] += jax.lax.dot_general(
+        ds, q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                     # (bk, d)
+    dq_acc[:] += jax.lax.dot_general(
+        ds, k, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                     # (bq, d)
+
+    @pl.when((flags & _LAST) != 0)
+    def _emit():
+        dq_ref[0, 0, :, :] = dq_acc[:].astype(dq_ref.dtype)
+
+    @pl.when(item == pl.num_programs(2) - 1)
+    def _emit_head():
+        dk_ref[0, 0, :, :] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0, 0, :, :] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _flash_bwd_dq_kernel(qt_ref, kt_ref, flags_ref, q_ref, k_ref, v_ref,
@@ -519,32 +621,99 @@ def _flash_bwd_dkv_kernel(kt_ref, head_ref, qt_ref, flags_ref, k_ref, v_ref,
         dv_ref[0, 0, :, :] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def _kv_walk_specs(block_q: int, block_k: int, rep: int):
+    """Block specs of the backward kernels whose grid is ``(b, kv_heads,
+    items)`` over arrays ``(K/V tile, head in group, q tile, flags)``
+    (:func:`_kv_schedule`, :func:`_bwd_schedule`) -> ``(q_rows, kv_rows,
+    stat_spec)`` as :func:`_q_walk_specs` gives them: the q-side operands
+    are read from the item's head of the group."""
+    from jax.experimental import pallas as pl
+
+    def kv_index(bi, gi, item, kt, head, qt, flags):
+        return bi, gi, kt[item], 0
+
+    def q_index(bi, gi, item, kt, head, qt, flags):
+        return bi, gi * rep + head[item], qt[item], 0
+    return (lambda width: pl.BlockSpec((1, 1, block_q, width), q_index),
+            lambda width: pl.BlockSpec((1, 1, block_k, width), kv_index),
+            pl.BlockSpec((1, 1, block_q, 1), q_index))
+
+
 def _flash_backward(q, k, v, o, lse, do, causal: bool, block_q: int,
                     block_k: int, interpret: bool, window=None):
-    """Pallas flash backward: dq via a kv-innermost pass, dk/dv via a
-    q-innermost pass with in-kernel GQA group accumulation. O(block)
-    VMEM per program, no O(seq^2) or O(block*seq) HBM tensors — the
-    memory story of the forward, extended to training."""
+    """Pallas flash backward: one kernel that makes S, P, dP and dS once a
+    live tile (:func:`_flash_backward_one`) where a K/V head's float32 dK
+    and dV fit VMEM (:func:`_bwd_vmem_limit`), else the pair of a
+    kv-innermost dQ pass and a q-innermost dK/dV pass
+    (:func:`_flash_backward_pair`); in-kernel GQA group accumulation and
+    no O(seq^2) or O(block*seq) HBM tensors either way — the memory story
+    of the forward, extended to training."""
+    d, sk, vd = q.shape[3], k.shape[1], v.shape[3]
+    # D_i = rowsum(dO ∘ O): O(seq·d) elementwise, fine outside the kernel.
+    dd = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    operands = (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                v.transpose(0, 2, 1, 3), do.transpose(0, 2, 1, 3),
+                lse,                            # already (b, h, sq, 1)
+                dd.transpose(0, 2, 1)[..., None])
+    tile = dict(block_q=block_q, block_k=block_k, causal=causal,
+                scale=1.0 / np.sqrt(d), window=window)
+    limit = _bwd_vmem_limit(sk, d, vd, k.dtype.itemsize, block_q, block_k)
+    grads = (_flash_backward_pair(*operands, tile, interpret) if limit is None
+             else _flash_backward_one(*operands, tile, interpret, limit))
+    return tuple(g.transpose(0, 2, 1, 3) for g in grads)
+
+
+def _flash_backward_one(qT, kT, vT, doT, lseT, ddT, tile: dict,
+                        interpret: bool, vmem_limit: int):
+    """``flash_bwd`` (with a window ``swa_bwd``): grid (b, kv_heads,
+    items) over :func:`_bwd_schedule`; a K/V head's dK and dV stay in
+    float32 scratch for the whole walk and leave as whole-head blocks."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, sq, h, d = q.shape
-    sk, kv_h, vd = k.shape[1], k.shape[2], v.shape[3]
+    (b, h, sq, d), (_, kv_h, sk, vd) = qT.shape, vT.shape
+    block_q, block_k, rep = tile["block_q"], tile["block_k"], h // kv_h
+    sched = _bwd_schedule(_live_tiles(sq, sk, block_q, block_k,
+                                      tile["causal"], tile["window"]), rep)
+    q_rows, kv_rows, stat_spec = _kv_walk_specs(block_q, block_k, rep)
+
+    def kv_head(width):
+        return pl.BlockSpec((1, 1, sk, width),
+                            lambda bi, gi, item, *sched: (bi, gi, 0, 0))
+    return pl.pallas_call(
+        partial(_flash_bwd_kernel, **tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(sched), grid=(b, kv_h, len(sched[0])),
+            in_specs=[kv_rows(d), kv_rows(vd), q_rows(d), q_rows(vd),
+                      stat_spec, stat_spec],
+            out_specs=[q_rows(d), kv_head(d), kv_head(vd)],
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+                            pltpu.VMEM((sk, d), jnp.float32),
+                            pltpu.VMEM((sk, vd), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((b, h, sq, d), qT.dtype),
+                   jax.ShapeDtypeStruct((b, kv_h, sk, d), kT.dtype),
+                   jax.ShapeDtypeStruct((b, kv_h, sk, vd), vT.dtype)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
+        interpret=interpret,
+        name="flash_bwd" if tile["window"] is None else "swa_bwd",
+    )(*sched, kT, vT, qT, doT, lseT, ddT)
+
+
+def _flash_backward_pair(qT, kT, vT, doT, lseT, ddT, tile: dict,
+                         interpret: bool):
+    """The backward at tile residency, for a K/V head whose float32 dK and
+    dV do not fit VMEM: ``flash_bwd_dq`` over :func:`_q_schedule` and
+    ``flash_bwd_dkv`` over :func:`_kv_schedule` (``swa_*`` with a
+    window), each making S, P, dP and dS for itself."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (b, h, sq, d), (_, kv_h, sk, vd) = qT.shape, vT.shape
+    block_q, block_k = tile["block_q"], tile["block_k"]
     rep = h // kv_h
-    scale = 1.0 / np.sqrt(d)
-    # D_i = rowsum(dO ∘ O): O(seq·d) elementwise, fine outside the kernel.
-    dd = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-
-    qT = q.transpose(0, 2, 1, 3)
-    kT = k.transpose(0, 2, 1, 3)
-    vT = v.transpose(0, 2, 1, 3)
-    doT = do.transpose(0, 2, 1, 3)
-    lseT = lse                                  # already (b, h, sq, 1)
-    ddT = dd.transpose(0, 2, 1)[..., None]
-
-    live = _live_tiles(sq, sk, block_q, block_k, causal, window)
-    tile = dict(block_q=block_q, block_k=block_k, causal=causal, scale=scale,
-                window=window)
+    prefix = "flash" if tile["window"] is None else "swa"
+    live = _live_tiles(sq, sk, block_q, block_k, tile["causal"],
+                       tile["window"])
     sched = _q_schedule(live)
     q_rows, kv_rows, stat_spec = _q_walk_specs(block_q, block_k, rep)
     dq = pl.pallas_call(
@@ -555,27 +724,13 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, block_q: int,
                       stat_spec, stat_spec],
             out_specs=q_rows(d),
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), qT.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret,
-        name="flash_bwd_dq" if window is None else "swa_bwd_dq",
+        interpret=interpret, name=f"{prefix}_bwd_dq",
     )(*sched, qT, kT, vT, doT, lseT, ddT)
 
     sched = _kv_schedule(live, rep)     # (K/V tile, head in group, q tile, .)
-
-    def kv_index(bi, gi, item, kt, head, qt, flags):
-        return bi, gi, kt[item], 0
-
-    def q_index(bi, gi, item, kt, head, qt, flags):
-        return bi, gi * rep + head[item], qt[item], 0
-
-    def kv_rows(width):
-        return pl.BlockSpec((1, 1, block_k, width), kv_index)
-
-    def q_rows(width):
-        return pl.BlockSpec((1, 1, block_q, width), q_index)
-
-    stat_spec = q_rows(1)
+    q_rows, kv_rows, stat_spec = _kv_walk_specs(block_q, block_k, rep)
     dk, dv = pl.pallas_call(
         partial(_flash_bwd_dkv_kernel, **tile),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -585,14 +740,12 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, block_q: int,
             out_specs=[kv_rows(d), kv_rows(vd)],
             scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                             pltpu.VMEM((block_k, vd), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct((b, kv_h, sk, d), k.dtype),
-                   jax.ShapeDtypeStruct((b, kv_h, sk, vd), v.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((b, kv_h, sk, d), kT.dtype),
+                   jax.ShapeDtypeStruct((b, kv_h, sk, vd), vT.dtype)],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret,
-        name="flash_bwd_dkv" if window is None else "swa_bwd_dkv",
+        interpret=interpret, name=f"{prefix}_bwd_dkv",
     )(*sched, kT, vT, qT, doT, lseT, ddT)
-    return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
-            dv.transpose(0, 2, 1, 3))
+    return dq, dk, dv
 
 
 def _dense_stats(q, k, v, causal: bool, block_q: int):
@@ -702,8 +855,9 @@ def flash_attention(q, k, v, causal: bool = False,
     kv_heads, vd)`` -> ``(b, sq, heads, vd)``, grouped-query native; ``vd``
     need not be ``d`` (the scores' scale is the key width's). ``window`` (static, needs
     ``causal``) keeps of each query's keys its own and the ``window - 1``
-    before it; the schedule then lists the band's tiles alone and the three
-    calls are named ``swa_fwd`` / ``swa_bwd_dq`` / ``swa_bwd_dkv``.
+    before it; the schedule then lists the band's tiles alone and the
+    calls are named ``swa_fwd`` / ``swa_bwd`` (``swa_bwd_dq`` /
+    ``swa_bwd_dkv`` where a K/V head's gradients do not fit VMEM).
 
     Falls back to the dense path when the shape can't tile onto the
     hardware (:func:`_tiles`). ``interpret=None`` selects the Pallas

@@ -1,4 +1,5 @@
-"""The three EVA Pallas kernels, the six flash / sliding-window ones, and
+"""The three EVA Pallas kernels, the flash / sliding-window ones (forward,
+the one backward kernel, and the pair it falls back to), and
 the flash kernels at latent attention's two widths with the whole step
 around them, compile for a described TPU v5e at their cells' own shapes (no chip: the
 ``on-chip-measurement`` guide's third rehearsal, kept as a test; the
@@ -88,6 +89,41 @@ def test_eva_backward_compiles_at_the_cells_shapes(cell, no_persistent_cache):
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
+def flash_backward_compiled(q, k, v, window=None):
+    """The gradient of a flash attention call (launch tiles) compiled for
+    the described chip; a fresh callable a compile, so that nothing traced
+    before is found again."""
+    from petastorm_tpu.ops.flash_attn import make_flash_attention
+    attn = make_flash_attention(causal=True, interpret=False, window=window)
+    return jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32)),
+        argnums=(0, 1, 2))).lower(q, k, v).compile()
+
+
+def assert_the_backward_is_one_kernel(prefix, q, k, v, window, monkeypatch):
+    """The call's backward compiles as ``<prefix>_bwd`` under the scoped
+    limit reckoned from its shapes, with neither of the pair's kernels in
+    it, and keeps no more temporaries in HBM than the pair's program but
+    for dk and dv in kernel layout (they leave one call together with dq,
+    each to be transposed, where the pair's second call could reuse the
+    room of the first's: AOT 839.2 against 805.9 MB at the dense cell's
+    call, 1,745.0 against 1,611.0 MB at the latent one's, 235.3 against
+    235.4 MB at the sparse one's; a whole step's peak is elsewhere)."""
+    from petastorm_tpu.ops import flash_attn
+    limit = flash_attn._bwd_vmem_limit(k.shape[1], k.shape[3], v.shape[3],
+                                       k.dtype.itemsize, 1024, 1024)
+    assert flash_attn._VMEM_LIMIT <= limit <= flash_attn._VMEM_CEILING
+    one = flash_backward_compiled(q, k, v, window)
+    assert kernels_in(one) == {f"{prefix}_fwd", f"{prefix}_bwd"}
+    monkeypatch.setattr(flash_attn, "_bwd_vmem_limit", lambda *a: None)
+    pair = flash_backward_compiled(q, k, v, window)
+    assert kernels_in(pair) == {f"{prefix}_fwd", f"{prefix}_bwd_dq",
+                                f"{prefix}_bwd_dkv"}
+    dk_and_dv = (k.size + v.size) * k.dtype.itemsize
+    assert (one.memory_analysis().temp_size_in_bytes
+            <= pair.memory_analysis().temp_size_in_bytes + dk_and_dv)
+
+
 # (configuration, traffic, windowed): the dense decoder's call and the sparse
 # one's full and windowed layers' (``chipbench/pipelines/token_decoder.py``,
 # ``token_moe_decoder.py``: default tiles, the window the configuration's).
@@ -98,11 +134,11 @@ FLASH_CALLS = [("mistral7b-v03-d2", "tok4k-b4", False, "flash"),
 
 @pytest.mark.parametrize("config,traffic,windowed,prefix", FLASH_CALLS)
 def test_flash_kernels_compile_at_the_cells_shapes(topo, no_persistent_cache,
-                                                   config, traffic, windowed,
-                                                   prefix):
-    """Forward and both backward kernels on their scalar-prefetch
-    schedules, at the launch defaults and the calls' VMEM limit."""
-    from petastorm_tpu.ops.flash_attn import make_flash_attention
+                                                   monkeypatch, config,
+                                                   traffic, windowed, prefix):
+    """The forward and the one backward kernel on their scalar-prefetch
+    schedules, at the launch defaults and the calls' VMEM limits; the
+    pair, kept for heads that do not fit, beside it."""
     with open(os.path.join(ROOT, "chipbench", "configs",
                            f"{config}.json")) as f:
         cfg = json.load(f)
@@ -116,33 +152,39 @@ def test_flash_kernels_compile_at_the_cells_shapes(topo, no_persistent_cache,
             (rows["per_chip_batch"], rows["window"], heads, cfg["head_dim"]),
             jnp.bfloat16, sharding=one_chip)
 
-    attn = make_flash_attention(
-        causal=True, interpret=False,
-        window=cfg["sliding_window_size"] if windowed else None)
     q, kv = (operand(cfg["num_attention_heads"]),
              operand(cfg["num_key_value_heads"]))
-    compiled = jax.jit(jax.grad(
-        lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32)),
-        argnums=(0, 1, 2))).lower(q, kv, kv).compile()
-    assert kernels_in(compiled) == {f"{prefix}_fwd", f"{prefix}_bwd_dq",
-                                    f"{prefix}_bwd_dkv"}
+    assert_the_backward_is_one_kernel(
+        prefix, q, kv, kv,
+        cfg["sliding_window_size"] if windowed else None, monkeypatch)
 
 
 def test_flash_backward_compiles_for_wide_float32_rows(topo,
-                                                       no_persistent_cache):
+                                                       no_persistent_cache,
+                                                       monkeypatch):
     """Float32 operands at head 256 need more scoped VMEM at the launch
-    tiles than the default 16 MiB holds (the dQ call): what the calls'
-    ``_VMEM_LIMIT`` is for."""
-    from petastorm_tpu.ops.flash_attn import make_flash_attention
+    tiles than the default 16 MiB holds: the pair's dQ call is what
+    ``_VMEM_LIMIT`` is for, the one kernel asks for 56 MiB of its own
+    reckoning."""
     one_chip = SingleDeviceSharding(topo.devices[0])
     q = jax.ShapeDtypeStruct((1, 4096, 8, 256), jnp.float32,
                              sharding=one_chip)
     kv = jax.ShapeDtypeStruct((1, 4096, 2, 256), jnp.float32,
                               sharding=one_chip)
-    attn = make_flash_attention(causal=True, interpret=False)
-    compiled = jax.jit(jax.grad(
-        lambda q, k, v: jnp.sum(attn(q, k, v)), argnums=(0, 1, 2))).lower(
-            q, kv, kv).compile()
+    assert_the_backward_is_one_kernel("flash", q, kv, kv, None, monkeypatch)
+
+
+def test_the_pair_compiles_where_a_head_does_not_fit(topo,
+                                                     no_persistent_cache):
+    """Float32 at head 256 over 16,384 positions: 96 MiB do not hold the
+    head's dK and dV, the reckoning says so and the backward is the pair
+    at tile residency, as before."""
+    from petastorm_tpu.ops import flash_attn
+    assert flash_attn._bwd_vmem_limit(16384, 256, 256, 4, 1024, 1024) is None
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((1, 16384, 2, 256), jnp.float32,
+                             sharding=one_chip)
+    compiled = flash_backward_compiled(q, q, q)
     assert kernels_in(compiled) == {"flash_fwd", "flash_bwd_dq",
                                     "flash_bwd_dkv"}
 
@@ -157,10 +199,11 @@ def latent_cell():
 
 
 def test_flash_kernels_compile_at_latent_attentions_widths(
-        topo, no_persistent_cache):
+        topo, no_persistent_cache, monkeypatch):
     """``kanana2-tok16k-1chip``'s call: scores over 192 columns (one and a
     half lane tiles), values, output and their gradients over 128, 32
-    heads, nothing padded in HBM."""
+    heads, nothing padded in HBM; the backward one kernel with 25 MB of
+    float32 dK + dV resident, under 72 MiB."""
     from petastorm_tpu.ops.flash_attn import make_flash_attention
     cfg, rows = latent_cell()
     one_chip = SingleDeviceSharding(topo.devices[0])
@@ -177,11 +220,7 @@ def test_flash_kernels_compile_at_latent_attentions_widths(
     fwd = jax.jit(attn).lower(qk, qk, v).compile()
     assert fwd.output_shardings is not None and kernels_in(fwd) == {
         "flash_fwd"}
-    compiled = jax.jit(jax.grad(
-        lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32)),
-        argnums=(0, 1, 2))).lower(qk, qk, v).compile()
-    assert kernels_in(compiled) == {"flash_fwd", "flash_bwd_dq",
-                                    "flash_bwd_dkv"}
+    assert_the_backward_is_one_kernel("flash", qk, qk, v, None, monkeypatch)
 
 
 def test_the_latent_cells_whole_step_compiles_and_fits(topo,
@@ -227,8 +266,8 @@ def test_the_latent_cells_whole_step_compiles_and_fits(topo,
             described(params, replicated),
             described(opt_state, replicated), tokens).compile()
     # (the grouped expert products are Mosaic calls of XLA's own, unnamed)
-    assert kernels_in(compiled) >= {"flash_fwd", "flash_bwd_dq",
-                                    "flash_bwd_dkv"}
+    assert kernels_in(compiled) >= {"flash_fwd", "flash_bwd"}
+    assert not {"flash_bwd_dq", "flash_bwd_dkv"} & kernels_in(compiled)
     m = compiled.memory_analysis()
     peak = (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes

@@ -98,8 +98,7 @@ def test_the_launched_grids_are_the_schedules_at_unequal_widths(window,
     steps = grid_steps(256, 256, 32, 64, True, window, rep=2)
     assert pallas_calls(jaxpr.jaxpr) == {
         f"{prefix}_fwd": (2, 4, steps["fwd"]),
-        f"{prefix}_bwd_dq": (2, 4, steps["dq"]),
-        f"{prefix}_bwd_dkv": (2, 2, steps["dkv"])}
+        f"{prefix}_bwd": (2, 2, steps["bwd"])}
 
 
 # (seq, heads, kv heads, width, window, dtype) at the launch tiles, 1024 x

@@ -1,6 +1,8 @@
 """Sliding-window attention: the three Pallas kernels under a static
 ``window`` (interpret mode) and the dense fallback, against plain masked
 attention written out here."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -133,9 +135,7 @@ def test_windowed_calls_carry_names_of_their_own():
             q, k, v, causal=True, block_q=32, block_k=64, window=window,
             interpret=True).sum(), (0, 1, 2))).lower(q, k, v).as_text(
                 debug_info=True)
-        return {n for n in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
-                            "swa_fwd", "swa_bwd_dq", "swa_bwd_dkv")
-                if n in text}
+        return set(re.findall(r"\b(?:flash|swa)_(?:fwd|bwd\w*)\b", text))
 
-    assert names(None) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
-    assert names(32) == {"swa_fwd", "swa_bwd_dq", "swa_bwd_dkv"}
+    assert names(None) == {"flash_fwd", "flash_bwd"}
+    assert names(32) == {"swa_fwd", "swa_bwd"}
