@@ -1,0 +1,2 @@
+"""`bwd_ms_per_step` of the tokens cells (body and meaning: _scopes.bwd_ms_per_step)."""
+from chipbench.layer_metrics._scopes import bwd_ms_per_step as read  # noqa: F401

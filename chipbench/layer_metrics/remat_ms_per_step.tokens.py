@@ -1,0 +1,2 @@
+"""`remat_ms_per_step` of the tokens cells (body and meaning: _scopes.remat_ms_per_step)."""
+from chipbench.layer_metrics._scopes import remat_ms_per_step as read  # noqa: F401

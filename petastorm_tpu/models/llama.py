@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from petastorm_tpu import device_scopes as scopes
 from petastorm_tpu.ops.flash_attn import SAVED_NAMES
 
 # The policy of ``apply(remat_layers=True)``: recompute the block, except
@@ -523,15 +524,18 @@ def _expert_rows(n_buf: int, cfg: LlamaConfig, h, weights, w_gate, w_up,
     # chip they come back as whatever the buffer held), so they are masked
     # on the way in, for dx, and on the way out.
     live = (jnp.arange(n_buf) < group_sizes.sum())[:, None]
-    row_weights = _to_buffer(weights.reshape(n_tok * k, 1), head, 1)
-    xs = jnp.where(live, _to_buffer(h, head, k), 0)              # (n_buf, d)
+    with jax.named_scope(scopes.MOE_ROWS_IN):
+        row_weights = _to_buffer(weights.reshape(n_tok * k, 1), head, 1)
+        xs = _to_buffer(h, head, k)                              # (n_buf, d)
+    xs = jnp.where(live, xs, 0)
     gate = jax.lax.ragged_dot(xs, w_gate.astype(h.dtype), group_sizes)
     up = jax.lax.ragged_dot(xs, w_up.astype(h.dtype), group_sizes)
     rows = jax.lax.ragged_dot(act(gate) * up, w_down.astype(h.dtype),
                               group_sizes)
     rows = jnp.where(live, rows * row_weights, 0).astype(h.dtype)
     # Back to the tokens: each sums the weighted rows of its k choices.
-    return _from_buffer(rows, head, k, n_tok)
+    with jax.named_scope(scopes.MOE_ROWS_BACK):
+        return _from_buffer(rows, head, k, n_tok)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -606,7 +610,7 @@ def _dropless_moe_block(route_x, h, layer, cfg: LlamaConfig):
     first, count = cfg.experts_held
     n_rows = n_tok * k
     n_short = _short_buffer_rows(n_rows, cfg)
-    with jax.named_scope("petastorm_tpu.moe_route"):
+    with jax.named_scope(scopes.MOE_ROUTE):
         logits = jnp.dot(route_x.reshape(n_tok, d).astype(jnp.float32),
                          layer["router"],
                          precision=jax.lax.Precision.HIGHEST)    # (T, n_out)
@@ -630,7 +634,7 @@ def _dropless_moe_block(route_x, h, layer, cfg: LlamaConfig):
         rows_held = group_sizes.sum()
         # Each assignment's weight: nought where its expert is not held.
         weights = jnp.where(key.reshape(n_tok, k) < count, weights, 0.0)
-    with jax.named_scope("petastorm_tpu.moe_experts"):
+    with jax.named_scope(scopes.MOE_EXPERTS):
         operands = (h.reshape(n_tok, d), weights, layer["ew1"], layer["ew3"],
                     layer["ew2"], perm, group_sizes)
         if n_short == n_rows:
@@ -669,7 +673,7 @@ def _latent_qkv(layer, h, cfg: LlamaConfig, rope: bool):
     head's own position-free key."""
     b, s, _ = h.shape
     nh, nope, rot = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
-    with jax.named_scope("petastorm_tpu.mla_latent"):
+    with jax.named_scope(scopes.MLA_LATENT):
         q = (h @ layer["wq"].astype(h.dtype)).reshape(b, s, nh, nope + rot)
         down = h @ layer["wkv_a"].astype(h.dtype)     # (b, s, rank + rot)
         latent = _rmsnorm(down[..., :cfg.kv_lora_rank], layer["kv_norm"],
@@ -735,63 +739,71 @@ def apply_block(layer, x, cfg: LlamaConfig, attn_fn=None, constrain=None,
     gqa_native = fn is None or getattr(fn, "supports_gqa", False)
     aux = jnp.zeros((), jnp.float32)
     layer_input = x
-    h = _rmsnorm(x, layer["attn_norm"], cfg.norm_eps, cfg.norm_unit_offset)
-    b, s, _ = h.shape
-    if cfg.attention == "mla":
-        q, k, v = _latent_qkv(layer, h, cfg, rope)
-    else:
-        q = (h @ layer["wq"].astype(h.dtype)).reshape(b, s, cfg.n_heads, hd)
-        k = (h @ layer["wk"].astype(h.dtype)).reshape(b, s, cfg.n_kv_heads,
-                                                      hd)
-        v = (h @ layer["wv"].astype(h.dtype)).reshape(b, s, cfg.n_kv_heads,
-                                                      hd)
-        if rope:
-            q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
-        if not gqa_native and rep > 1:
-            k = jnp.repeat(k, rep, axis=2)
-            v = jnp.repeat(v, rep, axis=2)
+    with jax.named_scope(scopes.ATTN_QKV):
+        h = _rmsnorm(x, layer["attn_norm"], cfg.norm_eps,
+                     cfg.norm_unit_offset)
+        b, s, _ = h.shape
+        if cfg.attention == "mla":
+            q, k, v = _latent_qkv(layer, h, cfg, rope)
+        else:
+            q = (h @ layer["wq"].astype(h.dtype)).reshape(b, s, cfg.n_heads,
+                                                          hd)
+            k = (h @ layer["wk"].astype(h.dtype)).reshape(
+                b, s, cfg.n_kv_heads, hd)
+            v = (h @ layer["wv"].astype(h.dtype)).reshape(
+                b, s, cfg.n_kv_heads, hd)
+            if rope:
+                q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
+            if not gqa_native and rep > 1:
+                k = jnp.repeat(k, rep, axis=2)
+                v = jnp.repeat(v, rep, axis=2)
     if cfg.attention == "eva":
         if eva_attn_fn is None:
             from petastorm_tpu.ops.eva_attn import make_eva_attention
             eva_attn_fn = make_eva_attention(cfg.eva_window, cfg.eva_chunk)
-        with jax.named_scope("petastorm_tpu.attn_eva"):
+        with jax.named_scope(scopes.ATTN_EVA):
             attn = eva_attn_fn(q, k, v, layer["eva_phi"], layer["eva_mu"])
     else:
-        with jax.named_scope("petastorm_tpu.attn_full" if window is None
-                             else "petastorm_tpu.attn_window"):
+        with jax.named_scope(scopes.ATTN_FULL if window is None
+                             else scopes.ATTN_WINDOW):
             attn = (fn or partial(dense_attention, causal=True,
                                   window=window))(q, k, v)
-    attn = attn.reshape(b, s, -1)     # heads x the value width
-    x = constrain(_skip_add(x, attn, layer["wo"], cfg.fp32_skip_add))
-    h = _rmsnorm(x, layer["mlp_norm"], cfg.norm_eps, cfg.norm_unit_offset)
+    with jax.named_scope(scopes.ATTN_OUT):
+        attn = attn.reshape(b, s, -1)     # heads x the value width
+        x = constrain(_skip_add(x, attn, layer["wo"], cfg.fp32_skip_add))
     stats = None
-    if cfg.n_router_outputs and "router" in layer:
-        moe_out, stats = _dropless_moe_block(
-            layer_input if cfg.router_input == "layer_input" else h,
-            h, layer, cfg)
-        if cfg.n_shared_experts:
-            with jax.named_scope("petastorm_tpu.moe_shared"):
-                act = _EXPERT_ACTS[cfg.expert_act]
-                gate = act(h @ layer["sw1"].astype(h.dtype))
-                up = h @ layer["sw3"].astype(h.dtype)
-                moe_out = moe_out + (gate * up) @ layer["sw2"].astype(h.dtype)
-        x = constrain(x + moe_out)
-    elif "router" in layer:
-        if cfg.moe_dispatch == "switch":
-            from petastorm_tpu.parallel.moe import switch_moe_block
-            moe_out, layer_aux = switch_moe_block(
-                h, layer["router"], layer["ew1"], layer["ew3"],
-                layer["ew2"], top_k=cfg.moe_top_k,
-                capacity_factor=cfg.moe_capacity_factor,
-                expert_spec=expert_spec)
-            aux = aux + layer_aux
+    with jax.named_scope(scopes.FFN):
+        h = _rmsnorm(x, layer["mlp_norm"], cfg.norm_eps,
+                     cfg.norm_unit_offset)
+        if cfg.n_router_outputs and "router" in layer:
+            moe_out, stats = _dropless_moe_block(
+                layer_input if cfg.router_input == "layer_input" else h,
+                h, layer, cfg)
+            if cfg.n_shared_experts:
+                with jax.named_scope(scopes.MOE_SHARED):
+                    act = _EXPERT_ACTS[cfg.expert_act]
+                    gate = act(h @ layer["sw1"].astype(h.dtype))
+                    up = h @ layer["sw3"].astype(h.dtype)
+                    moe_out = moe_out + (gate * up) @ layer["sw2"].astype(
+                        h.dtype)
             x = constrain(x + moe_out)
+        elif "router" in layer:
+            if cfg.moe_dispatch == "switch":
+                from petastorm_tpu.parallel.moe import switch_moe_block
+                moe_out, layer_aux = switch_moe_block(
+                    h, layer["router"], layer["ew1"], layer["ew3"],
+                    layer["ew2"], top_k=cfg.moe_top_k,
+                    capacity_factor=cfg.moe_capacity_factor,
+                    expert_spec=expert_spec)
+                aux = aux + layer_aux
+                x = constrain(x + moe_out)
+            else:
+                x = constrain(x + _moe_block(h, layer))
         else:
-            x = constrain(x + _moe_block(h, layer))
-    else:
-        gate = jax.nn.silu(h @ layer["w1"].astype(h.dtype))
-        up = h @ layer["w3"].astype(h.dtype)
-        x = constrain(_skip_add(x, gate * up, layer["w2"], cfg.fp32_skip_add))
+            gate = jax.nn.silu(h @ layer["w1"].astype(h.dtype))
+            up = h @ layer["w3"].astype(h.dtype)
+            x = constrain(_skip_add(x, gate * up, layer["w2"],
+                                    cfg.fp32_skip_add))
     if not with_stats:
         return x, aux
     if stats is None:
@@ -867,9 +879,10 @@ def apply(params, tokens, cfg: LlamaConfig, attn_fn=None,
     aux = jnp.zeros((), jnp.float32)
     if embed_lookup not in ("gather", "onehot"):
         raise ValueError(f"unknown embed_lookup {embed_lookup!r}")
-    x = constrain(_embed_lookup(params["embed"], tokens, compute_dtype)
-                  if embed_lookup == "onehot"
-                  else params["embed"].astype(compute_dtype)[tokens])
+    with jax.named_scope(scopes.EMBED):
+        x = constrain(_embed_lookup(params["embed"], tokens, compute_dtype)
+                      if embed_lookup == "onehot"
+                      else params["embed"].astype(compute_dtype)[tokens])
     stats = []
     if layers_fn is not None:
         if with_stats:
@@ -896,16 +909,20 @@ def apply(params, tokens, cfg: LlamaConfig, attn_fn=None,
                 # recomputes the rest of each block, but launches no
                 # second forward kernel to remake what the first one wrote.
                 one_block = jax.checkpoint(one_block, policy=_SAVE_ATTENTION)
-            x, layer_aux, *layer_stats = one_block(layer, x)
-            aux = aux + layer_aux
+            with jax.named_scope(scopes.BLOCK):
+                x, layer_aux, *layer_stats = one_block(layer, x)
+                aux = aux + layer_aux
             stats.extend(layer_stats)
-    x = _rmsnorm(x, params["norm_out"], cfg.norm_eps, cfg.norm_unit_offset)
-    if not return_hidden:
-        x = (x @ params["lm_head"].astype(x.dtype)).astype(jnp.float32)
+    with jax.named_scope(scopes.LOSS_HEAD):
+        x = _rmsnorm(x, params["norm_out"], cfg.norm_eps,
+                     cfg.norm_unit_offset)
+        if not return_hidden:
+            x = (x @ params["lm_head"].astype(x.dtype)).astype(jnp.float32)
     out = (x, aux) if with_aux else (x,)
     if with_stats:
-        out += ({name: jnp.stack([st[name] for st in stats])
-                 for name in MOE_STATS},)
+        with jax.named_scope(scopes.BLOCK):
+            out += ({name: jnp.stack([st[name] for st in stats])
+                     for name in MOE_STATS},)
     return out if len(out) > 1 else out[0]
 
 
@@ -1063,45 +1080,47 @@ def loss_fn(params, batch, cfg: LlamaConfig, attn_fn=None, activation_spec=None,
         # 593.79 ms a step at Mistral-7B widths, 16k tokens, chunk 2048
         # (ledger, PR 27, mistral7b-tok4k-1chip, resident_step_ms.tokens).
         x, aux, *stats = run(return_hidden=True)
-        b, s, dm = x.shape
-        if cfg.n_pred_heads > 1:
-            targets, mask = _multi_head_targets(tokens, cfg.n_pred_heads)
-        elif shift == "roll":
-            targets = jnp.roll(tokens, -1, axis=1)
-            mask = (jnp.arange(s) < s - 1).astype(jnp.float32)
-        else:
-            targets = tokens[:, 1:]
-            mask = jnp.ones((s,), jnp.float32)
-        n_tok = b * s
-        if n_tok % xent_chunk:
-            raise ValueError(f"xent_chunk ({xent_chunk}) must divide "
-                             f"batch*seq ({n_tok})")
-        # The weights carry the roll mask and the mean's denominator.
-        weights = jnp.broadcast_to(mask / (mask.sum() * b), (b, *mask.shape))
-        chunks = (n_tok // xent_chunk, xent_chunk, *mask.shape[1:])
-        nll = _chunked_xent(x.reshape(*chunks[:2], dm), params["lm_head"],
-                            targets.reshape(chunks), weights.reshape(chunks))
-        loss = nll + aux_weight * aux
+        with jax.named_scope(scopes.LOSS_HEAD):
+            b, s, dm = x.shape
+            if cfg.n_pred_heads > 1:
+                targets, mask = _multi_head_targets(tokens, cfg.n_pred_heads)
+            elif shift == "roll":
+                targets = jnp.roll(tokens, -1, axis=1)
+                mask = (jnp.arange(s) < s - 1).astype(jnp.float32)
+            else:
+                targets = tokens[:, 1:]
+                mask = jnp.ones((s,), jnp.float32)
+            n_tok = b * s
+            if n_tok % xent_chunk:
+                raise ValueError(f"xent_chunk ({xent_chunk}) must divide "
+                                 f"batch*seq ({n_tok})")
+            # The weights carry the roll mask and the mean's denominator.
+            weights = jnp.broadcast_to(mask / (mask.sum() * b), (b, *mask.shape))
+            chunks = (n_tok // xent_chunk, xent_chunk, *mask.shape[1:])
+            nll = _chunked_xent(x.reshape(*chunks[:2], dm), params["lm_head"],
+                                targets.reshape(chunks), weights.reshape(chunks))
+            loss = nll + aux_weight * aux
         return (loss, stats[0]) if with_stats else loss
     logits, aux, *stats = run()
-    # Fused form: nll = logsumexp(logits) - logits[target]. Identical math
-    # to log_softmax + gather (log_softmax = logits - lse), but XLA skips
-    # materializing the full (b, s, V) log-prob tensor — measured 13%
-    # faster for the 4k-token loss+grad on TPU v5 lite (10.8 -> 9.4 ms;
-    # a chunked/remat variant measured slower at this scale, 11.4 ms).
-    lse = jax.nn.logsumexp(logits, axis=-1)                      # (b, s)
-    if shift == "roll":
-        targets = jnp.roll(tokens, -1, axis=1)
-        tl = jnp.take_along_axis(logits, targets[..., None],
-                                 axis=-1)[..., 0]                # (b, s)
-        nll_tok = lse - tl
-        mask = (jnp.arange(tokens.shape[1]) < tokens.shape[1] - 1)
-        nll = (nll_tok * mask).sum() / (mask.sum() * tokens.shape[0])
-    else:
-        targets = tokens[:, 1:]
-        tl = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-        nll = (lse - tl).mean()
-    loss = nll + aux_weight * aux
+    with jax.named_scope(scopes.LOSS_HEAD):
+        # Fused form: nll = logsumexp(logits) - logits[target]. Identical math
+        # to log_softmax + gather (log_softmax = logits - lse), but XLA skips
+        # materializing the full (b, s, V) log-prob tensor — measured 13%
+        # faster for the 4k-token loss+grad on TPU v5 lite (10.8 -> 9.4 ms;
+        # a chunked/remat variant measured slower at this scale, 11.4 ms).
+        lse = jax.nn.logsumexp(logits, axis=-1)                      # (b, s)
+        if shift == "roll":
+            targets = jnp.roll(tokens, -1, axis=1)
+            tl = jnp.take_along_axis(logits, targets[..., None],
+                                     axis=-1)[..., 0]                # (b, s)
+            nll_tok = lse - tl
+            mask = (jnp.arange(tokens.shape[1]) < tokens.shape[1] - 1)
+            nll = (nll_tok * mask).sum() / (mask.sum() * tokens.shape[0])
+        else:
+            targets = tokens[:, 1:]
+            tl = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+            nll = (lse - tl).mean()
+        loss = nll + aux_weight * aux
     return (loss, stats[0]) if with_stats else loss
 
 
@@ -1143,8 +1162,9 @@ def make_train_step(cfg: LlamaConfig, learning_rate: float = 3e-4,
                     window_attn_fn=window_attn_fn, with_stats=with_stats,
                     eva_attn_fn=eva_attn_fn),
             has_aux=with_stats)(params, batch)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope(scopes.OPTIMIZER):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return (params, opt_state) + (out if with_stats else (out,))
 
     return init_opt, train_step

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from petastorm_tpu import device_scopes as scopes
+
 Params = Dict[str, Any]
 
 # (blocks per stage, bottleneck mid-channels per stage)
@@ -106,22 +108,26 @@ def apply(params: Params, images, train: bool = False, compute_dtype=jnp.bfloat1
     """
     block_fn = jax.checkpoint(_bottleneck, static_argnums=(2, 3)) if remat \
         else _bottleneck
-    x = images.astype(compute_dtype)
     new_stats: Params = {"stem": {}}
-    x, new_stats["stem"]["bn"] = _batch_norm(_conv(x, params["stem"]["conv"], 2),
-                                             params["stem"]["bn"], train)
-    x = jax.nn.relu(x)
-    x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 3, 3, 1), (1, 2, 2, 1),
-                              "SAME")
+    with jax.named_scope(scopes.STEM):
+        x = images.astype(compute_dtype)
+        x, new_stats["stem"]["bn"] = _batch_norm(
+            _conv(x, params["stem"]["conv"], 2), params["stem"]["bn"], train)
+        x = jax.nn.relu(x)
+        x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 3, 3, 1),
+                                  (1, 2, 2, 1), "SAME")
     for stage_idx, (blocks, _) in enumerate(_RESNET50_STAGES):
         stage_stats = []
-        for block_idx in range(blocks):
-            stride = 2 if (block_idx == 0 and stage_idx > 0) else 1
-            x, s = block_fn(x, params[f"stage{stage_idx}"][block_idx], stride, train)
-            stage_stats.append(s)
+        with jax.named_scope(scopes.STAGES[stage_idx]):
+            for block_idx in range(blocks):
+                stride = 2 if (block_idx == 0 and stage_idx > 0) else 1
+                x, s = block_fn(x, params[f"stage{stage_idx}"][block_idx],
+                                stride, train)
+                stage_stats.append(s)
         new_stats[f"stage{stage_idx}"] = stage_stats
-    x = jnp.mean(x.astype(jnp.float32), axis=(1, 2))
-    logits = x @ params["head"]["w"] + params["head"]["b"]
+    with jax.named_scope(scopes.HEAD):
+        x = jnp.mean(x.astype(jnp.float32), axis=(1, 2))
+        logits = x @ params["head"]["w"] + params["head"]["b"]
     return logits, new_stats
 
 
@@ -143,9 +149,10 @@ def merge_bn_stats(params: Params, new_stats: Params) -> Params:
 def loss_fn(params, batch, train: bool = True, remat: bool = False):
     logits, new_stats = apply(params, batch["image"], train=train, remat=remat)
     labels = batch["label"]
-    logp = jax.nn.log_softmax(logits)
-    nll = -jnp.take_along_axis(logp, labels[:, None], axis=1).mean()
-    acc = (logits.argmax(-1) == labels).mean()
+    with jax.named_scope(scopes.HEAD):
+        logp = jax.nn.log_softmax(logits)
+        nll = -jnp.take_along_axis(logp, labels[:, None], axis=1).mean()
+        acc = (logits.argmax(-1) == labels).mean()
     return nll, (acc, new_stats)
 
 
@@ -157,9 +164,12 @@ def make_train_step(learning_rate: float = 0.1, weight_decay: float = 1e-4,
     def train_step(params, velocity, batch):
         (loss, (acc, new_stats)), grads = jax.value_and_grad(
             partial(loss_fn, remat=remat), has_aux=True)(params, batch)
-        velocity = jax.tree.map(lambda v, g, p: momentum * v + g + weight_decay * p,
-                                velocity, grads, params)
-        params = jax.tree.map(lambda p, v: p - learning_rate * v, params, velocity)
-        params = merge_bn_stats(params, new_stats)
+        with jax.named_scope(scopes.OPTIMIZER):
+            velocity = jax.tree.map(
+                lambda v, g, p: momentum * v + g + weight_decay * p,
+                velocity, grads, params)
+            params = jax.tree.map(lambda p, v: p - learning_rate * v,
+                                  params, velocity)
+            params = merge_bn_stats(params, new_stats)
         return params, velocity, loss, acc
     return train_step

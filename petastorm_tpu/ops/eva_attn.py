@@ -48,6 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from petastorm_tpu.device_scopes import EVA_PREP
 from petastorm_tpu.ops.flash_attn import (SAVED_NAMES, _mask_causal,
                                           _p_ds_tile, _resolve_interpret,
                                           _softmax_tile)
@@ -477,12 +478,12 @@ def eva_attention(q, k, v, phi, mu, *, window: int, chunk: int,
     ``mu`` ``(h, d)`` -> ``(b, s, h, d)``. ``s`` is a whole number of
     ``window``-long blocks and ``window`` of ``chunk``-long chunks; a shape
     the tiles cannot divide raises (there is no dense route). The summaries
-    run under ``jax.named_scope("petastorm_tpu.eva_prep")``."""
+    run under ``jax.named_scope(device_scopes.EVA_PREP)``."""
     if k.shape != q.shape or v.shape != q.shape:
         raise ValueError(f"EVA takes one key/value head a query head: q "
                          f"{q.shape}, k {k.shape}, v {v.shape}")
     t = _shape(q.shape[1], window, chunk, *_tiles)
-    with jax.named_scope("petastorm_tpu.eva_prep"):
+    with jax.named_scope(EVA_PREP):
         kbar, vbar = eva_summaries(k, v, phi, mu, chunk)
     return _eva_vjp(t, _resolve_interpret(interpret), q, k, v, kbar, vbar)
 
