@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from petastorm_tpu import device_scopes as scopes
+from petastorm_tpu.ops import moe_rows
 from petastorm_tpu.ops.flash_attn import SAVED_NAMES
 
 # The policy of ``apply(remat_layers=True)``: recompute the block, except
@@ -448,61 +449,72 @@ def _short_buffer_rows(n_rows: int, cfg: LlamaConfig) -> int:
     return min(n_rows, tiles * _ROW_TILE)
 
 
-def _buffer_rows(x, head, k: int):
+def _buffer_rows(x, head, k: int, live):
     """Token rows ``x`` (T, d) into the expert buffer: buffer row ``r`` is
     assignment ``head[r]``, which is token ``head[r] // k``'s (``k = 1``:
     the rows themselves). ``head`` is the sort's permutation of the
-    ``T k`` assignments or its first rows."""
-    return x[head // k]
+    ``T k`` assignments or its first rows. One DMA copy a buffer row
+    (``moe_rows.gather_rows``) where the shapes tile onto the hardware;
+    else, and for the row weights (one column), XLA's gather. The rows
+    from ``live`` (the held rows, a traced count) on are the caller's to
+    mask: the kernel need not copy them."""
+    tile = moe_rows.gather_tile(head.shape[0], *x.shape, x.dtype)
+    if tile is None:
+        return x[head // k]
+    return moe_rows.gather_rows(x, head // k, live, tile=tile)
 
 
-def _token_sums(rows, head, k: int, n_tok: int):
+def _token_sums(rows, head, k: int, n_tok: int, live):
     """The transpose of :func:`_buffer_rows`: each token's sum (float32)
     over the buffer rows of its assignments. Of a whole permutation every
     token has exactly ``k`` rows, found through its inverse: a gather and
-    a sum, and no scatter. Of its first rows only, the rows are added
-    where they belong."""
+    a sum, and no scatter. Of its first rows only, the buffer rows sorted
+    by assignment are copied to their tokens and added in assignment order
+    (``moe_rows.gather_sum``); the rows from ``live`` on are zeros (the
+    caller's mask) and are not read. Where the shapes do not tile onto
+    the hardware, the rows are added where they belong (XLA's
+    scatter-add)."""
     if head.shape[0] == n_tok * k:
         rows = rows[jnp.argsort(head)].reshape(n_tok, k, rows.shape[-1])
         return jnp.sum(rows, axis=1, dtype=jnp.float32).astype(rows.dtype)
-    # The scatter-add moves the rows held and no others. The other exact
-    # way, k gathers of a token's rows from the buffer with a zero row
-    # for the assignments it lacks, still reads T k rows: 17.8 against
-    # 9.6 ms a call at 73,728 of 196,608 rows x 2,560 (chip probes, PR 30).
-    return jax.ops.segment_sum(rows.astype(jnp.float32), head // k,
-                               num_segments=n_tok).astype(rows.dtype)
+    tile = moe_rows.sum_tile(n_tok, *rows.shape, k, rows.dtype)
+    if tile is None:
+        return jax.ops.segment_sum(rows.astype(jnp.float32), head // k,
+                                   num_segments=n_tok).astype(rows.dtype)
+    return moe_rows.gather_sum(rows, head, live, k, n_tok, tile=tile)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _to_buffer(x, head, k):
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _to_buffer(x, head, live, k):
     """:func:`_buffer_rows` whose backward is :func:`_token_sums`."""
-    return _buffer_rows(x, head, k)
+    return _buffer_rows(x, head, k, live)
 
 
-def _to_buffer_fwd(x, head, k):
-    return _buffer_rows(x, head, k), (head, x.shape[0])
+def _to_buffer_fwd(x, head, live, k):
+    return _buffer_rows(x, head, k, live), (head, live, x.shape[0])
 
 
 def _to_buffer_bwd(k, residuals, g):
-    head, n_tok = residuals
-    return _token_sums(g, head, k, n_tok), None
+    head, live, n_tok = residuals
+    return _token_sums(g, head, k, n_tok, live), None, None
 
 
 _to_buffer.defvjp(_to_buffer_fwd, _to_buffer_bwd)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _from_buffer(rows, head, k, n_tok):
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _from_buffer(rows, head, live, k, n_tok):
     """:func:`_token_sums` whose backward is :func:`_buffer_rows`."""
-    return _token_sums(rows, head, k, n_tok)
+    return _token_sums(rows, head, k, n_tok, live)
 
 
-def _from_buffer_fwd(rows, head, k, n_tok):
-    return _token_sums(rows, head, k, n_tok), head
+def _from_buffer_fwd(rows, head, live, k, n_tok):
+    return _token_sums(rows, head, k, n_tok, live), (head, live)
 
 
-def _from_buffer_bwd(k, n_tok, head, g):
-    return _buffer_rows(g, head, k), None
+def _from_buffer_bwd(k, n_tok, residuals, g):
+    head, live = residuals
+    return _buffer_rows(g, head, k, live), None, None
 
 
 _from_buffer.defvjp(_from_buffer_fwd, _from_buffer_bwd)
@@ -522,11 +534,14 @@ def _expert_rows(n_buf: int, cfg: LlamaConfig, h, weights, w_gate, w_up,
     # Rows past the last held group belong to no group: the grouped
     # product never writes them, in its result or in its transpose (on the
     # chip they come back as whatever the buffer held), so they are masked
-    # on the way in, for dx, and on the way out.
-    live = (jnp.arange(n_buf) < group_sizes.sum())[:, None]
+    # on the way in, for dx, and on the way out. The row movers need not
+    # move them.
+    held = group_sizes.sum()
+    live = (jnp.arange(n_buf) < held)[:, None]
     with jax.named_scope(scopes.MOE_ROWS_IN):
-        row_weights = _to_buffer(weights.reshape(n_tok * k, 1), head, 1)
-        xs = _to_buffer(h, head, k)                              # (n_buf, d)
+        row_weights = _to_buffer(weights.reshape(n_tok * k, 1), head, held,
+                                 1)
+        xs = _to_buffer(h, head, held, k)                        # (n_buf, d)
     xs = jnp.where(live, xs, 0)
     gate = jax.lax.ragged_dot(xs, w_gate.astype(h.dtype), group_sizes)
     up = jax.lax.ragged_dot(xs, w_up.astype(h.dtype), group_sizes)
@@ -535,7 +550,7 @@ def _expert_rows(n_buf: int, cfg: LlamaConfig, h, weights, w_gate, w_up,
     rows = jnp.where(live, rows * row_weights, 0).astype(h.dtype)
     # Back to the tokens: each sums the weighted rows of its k choices.
     with jax.named_scope(scopes.MOE_ROWS_BACK):
-        return _from_buffer(rows, head, k, n_tok)
+        return _from_buffer(rows, head, held, k, n_tok)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1))
