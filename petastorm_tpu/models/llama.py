@@ -399,19 +399,19 @@ def _skip_add(x, h, w, fp32: bool = False):
     return (x.astype(jnp.float32) + branch).astype(x.dtype)
 
 
-def _rope(x, theta, interleave: bool = False):
+def _rope(x, theta):
     """x: (b, s, h, d) -> rotated. Positions are global sequence indices.
-    Pair ``i`` is columns ``(i, i + d/2)``, or with ``interleave`` columns
-    ``(2i, 2i + 1)``; the rotated pairs come out as two halves either way
-    (a product of two vectors rotated alike does not see the order)."""
+    Pair ``i`` is columns ``(i, i + d/2)``; a model whose pairs are
+    adjacent columns (``rope_interleave``) puts its weights' columns in
+    this order first (:func:`_pairs_to_halves`: a product of two vectors
+    rotated alike does not see the order of their pairs)."""
     b, s, h, d = x.shape
     half = d // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     pos = jnp.arange(s, dtype=jnp.float32)
     angles = pos[:, None] * freqs[None, :]               # (s, half)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
-    x1, x2 = (x[..., 0::2], x[..., 1::2]) if interleave else \
-        (x[..., :half], x[..., half:])
+    x1, x2 = x[..., :half], x[..., half:]
     cos = cos[None, :, None, :].astype(x.dtype)
     sin = sin[None, :, None, :].astype(x.dtype)
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
@@ -679,32 +679,53 @@ def publish_moe_stats(registry, stats) -> None:
             float(np.sum(np.asarray(stats[name], dtype=np.float64))))
 
 
+def _pairs_to_halves(w):
+    """A weight's last axis of 2n columns from pair order ``(2i, 2i + 1)``
+    to halves order ``(i, n + i)``: a permutation of the columns, so the
+    product's columns are the same columns permuted."""
+    *lead, width = w.shape
+    return w.reshape(*lead, width // 2, 2).swapaxes(-1, -2).reshape(
+        *lead, width)
+
+
 def _latent_qkv(layer, h, cfg: LlamaConfig, rope: bool):
-    """Latent attention's ``(q, k, v)`` from the normed stream ``h`` (b, s,
-    d): q and k ``(b, s, heads, qk_nope_dim + qk_rope_dim)``, v ``(b, s,
-    heads, v_dim)``. Keys and values come from one latent of
-    ``kv_lora_rank`` columns, normed before it is projected up; the rotary
-    key is one head, rotated once and repeated over the heads beside each
-    head's own position-free key."""
-    b, s, _ = h.shape
+    """Latent attention's operands from the normed stream ``h`` (b, s, d),
+    in the flash kernels' split form (``ops/flash_attn.flash_attention``):
+    q ``(q_nope (b, s, heads, qk_nope_dim), q_rope (b, s, heads,
+    qk_rope_dim))``, k ``(k_nope (b, s, heads, qk_nope_dim), k_rope (b, s,
+    1, qk_rope_dim))`` — ONE rotary key, rotated once, for every head —
+    and v ``(b, s, heads, v_dim)``. Keys and values come from one latent
+    of ``kv_lora_rank`` columns, normed before it is projected up.
+
+    Each operand is the product of its own columns of ``wq`` / ``wkv_a`` /
+    ``wkv_b`` (viewed a head at a time; the parameters keep their
+    published layout), so no head of qk_nope_dim + qk_rope_dim columns is
+    made, sliced or joined. Under ``rope_interleave`` the rotary columns
+    of ``wq`` and ``wkv_a`` are put from pair order into halves order
+    before their products (:func:`_pairs_to_halves`): the rotation of
+    halves then gives, column for column, what rotating adjacent pairs of
+    the product gives."""
     nh, nope, rot = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
+    rank = cfg.kv_lora_rank
+    heads = partial(jnp.einsum, "bsd,dhe->bshe")
     with jax.named_scope(scopes.MLA_LATENT):
-        q = (h @ layer["wq"].astype(h.dtype)).reshape(b, s, nh, nope + rot)
-        down = h @ layer["wkv_a"].astype(h.dtype)     # (b, s, rank + rot)
-        latent = _rmsnorm(down[..., :cfg.kv_lora_rank], layer["kv_norm"],
+        wq = layer["wq"].astype(h.dtype).reshape(-1, nh, nope + rot)
+        wkv_a = layer["wkv_a"].astype(h.dtype)
+        wkv_b = layer["wkv_b"].astype(h.dtype).reshape(rank, nh,
+                                                       nope + cfg.v_dim)
+        wq_rot, wk_rot = wq[..., nope:], wkv_a[:, rank:]
+        if cfg.rope_interleave:
+            wq_rot, wk_rot = _pairs_to_halves(wq_rot), _pairs_to_halves(wk_rot)
+        q_nope, q_rot = heads(h, wq[..., :nope]), heads(h, wq_rot)
+        k_rot = (h @ wk_rot)[:, :, None, :]               # (b, s, 1, rot)
+        latent = _rmsnorm(h @ wkv_a[:, :rank], layer["kv_norm"],
                           cfg.norm_eps, cfg.norm_unit_offset)
-        up = (latent @ layer["wkv_b"].astype(h.dtype)).reshape(
-            b, s, nh, nope + cfg.v_dim)
-        q_rot = q[..., nope:]
-        k_rot = down[..., cfg.kv_lora_rank:].reshape(b, s, 1, rot)
+        k_nope = heads(latent, wkv_b[..., :nope])
+        v = heads(latent, wkv_b[..., nope:])
         if rope:
-            q_rot = _rope(q_rot, cfg.rope_theta, cfg.rope_interleave)
-            k_rot = _rope(k_rot, cfg.rope_theta, cfg.rope_interleave)
-        q = jnp.concatenate([q[..., :nope], q_rot], axis=-1)
-        k = jnp.concatenate(
-            [up[..., :nope], jnp.broadcast_to(k_rot, (b, s, nh, rot))],
-            axis=-1)
-        return q, k, up[..., nope:]
+            q_rot, k_rot = (_rope(q_rot, cfg.rope_theta),
+                            _rope(k_rot, cfg.rope_theta))
+        return (q_nope, q_rot), (k_nope, k_rot), v
 
 
 def _embed_lookup(embed, tokens, compute_dtype):
@@ -735,7 +756,9 @@ def apply_block(layer, x, cfg: LlamaConfig, attn_fn=None, constrain=None,
     (``cfg.attention_kind(layer_idx)``: rotary positions or none, full or
     sliding-window; or EVA's blocks and chunk summaries, through
     ``eva_attn_fn(q, k, v, phi, mu)``; or latent attention, whose
-    :func:`_latent_qkv` hands ``attn_fn`` keys wider than its values) and
+    :func:`_latent_qkv` hands ``attn_fn`` q and k as (position-free part,
+    rotary part) pairs, the rotary key one head, which
+    ``flash_attention`` and ``dense_attention`` take) and
     the layer's FFN kind (dense, ``soft`` / ``switch`` experts, dropless
     held experts with or without shared ones: the leaves it holds say
     which); the norms, the residuals and the sharding constraints are the

@@ -32,10 +32,20 @@ HBM; this kernel never does. Design (flash-attention-2 style, TPU-first):
   with :func:`petastorm_tpu.parallel.attention.dense_attention`);
 * the key width and the value width are two numbers: q and k are ``d``
   wide, v (so o, ``do``, ``dv`` and the accumulator) ``v.shape[-1]``, the
-  scores scaled by ``1 / sqrt(d)`` — latent attention's 192-wide scores over
-  128-wide values (``llama`` ``attention="mla"``) run the same
-  kernels, nothing padded in HBM; with equal widths the calls are what
+  scores scaled by ``1 / sqrt(d)``; with equal widths the calls are what
   they were;
+* q and k may each be a pair, ``(position-free part, rotary part)``, the
+  key's rotary part ONE head that every query head shares — latent
+  attention's operands in their own form (``llama`` ``attention="mla"``:
+  32 heads of 128 + 64 over one rotary key of 64, values of 128). A
+  kernel joins a split tile along the lanes in VMEM (the position-free
+  part fills whole lane tiles) and makes each product once over the
+  summed width, scaled by its root: the tile math of the joined call, bit
+  for bit. The gradients come back as pairs, the rotary key's summed over
+  every query head inside the kernel (the pair's dK/dV call leaves it a
+  key/value head at a time, summed after it). No 192-wide q or k and no
+  repeated rotary key exists in HBM. Arrays, not pairs, lower to the
+  kernels they lowered to before the split form was added;
 * the backward pass is ONE Pallas kernel (``custom_vjp``; ``flash_bwd``,
   under a window ``swa_bwd``): the forward saves ``(q, k, v, o, lse)``,
   then a grid ``(batch, kv_heads, items)`` walks, for each query head of
@@ -120,20 +130,22 @@ _VMEM_LIMIT = 32 << 20
 _VMEM_CEILING = 96 << 20
 
 
-def _bwd_vmem_limit(sk: int, d: int, vd: int, itemsize: int, block_q: int,
+def _bwd_vmem_limit(sk: int, d, vd: int, itemsize: int, block_q: int,
                     block_k: int):
     """Scoped-VMEM limit of the one-kernel backward, reckoned from its
-    call's shapes (lanes padded to 128), or None where a K/V head's
+    call's shapes (lanes padded to 128; ``d`` the key width, or the tuple
+    of a split key's part widths, each padded), or None where a K/V head's
     accumulators do not fit :data:`_VMEM_CEILING` and the backward is the
     pair: float32 dK + dV of the head and their whole-head output blocks
     (double-buffered), the four float32 tiles s, p, dP, dS, the
     double-buffered operand tiles and row statistics, dQ's tile. At the
     launch tiles 30 MiB at 4096 positions and head 128 (so
     :data:`_VMEM_LIMIT`), 54 MiB at 16,384, 72 MiB there at widths 192 |
-    128 (Mosaic refuses that call at 48 MiB), 56 MiB for float32 at head
-    256 and 4096; bfloat16 at head 128 fits to 32,768 positions, float32
-    at head 256 to 8192."""
-    wide = -(-d // 128) * 128 + -(-vd // 128) * 128
+    128 or (128, 64) | 128 (Mosaic refuses that call at 48 MiB), 56 MiB
+    for float32 at head 256 and 4096; bfloat16 at head 128 fits to 32,768
+    positions, float32 at head 256 to 8192."""
+    wide = sum(-(-w // 128) * 128
+               for w in (*(d if isinstance(d, tuple) else (d,)), vd))
     need = (sk * wide * (4 + 2 * itemsize)
             + 4 * block_q * block_k * 4
             + 2 * (block_q + block_k) * wide * itemsize
@@ -286,6 +298,56 @@ def _mask_causal(s, causal: bool, q_off, k_off, block_q: int, block_k: int,
     return jnp.where(keep, s, -jnp.inf)
 
 
+def _parts(x) -> tuple:
+    """A split operand's pair, or the one array as a tuple of one."""
+    return x if isinstance(x, tuple) else (x,)
+
+
+def _lead(x):
+    """An operand's first part: the array itself, or a split one's
+    position-free part (its heads, positions and batch are the operand's)."""
+    return _parts(x)[0]
+
+
+def _tile(ref):
+    """The (rows, width) tile of a (1, 1, rows, width) block, or the
+    pair of tiles of a split operand's pair of blocks."""
+    if isinstance(ref, tuple):
+        return tuple(_tile(r) for r in ref)
+    return ref[0, 0, :, :]
+
+
+def _joined(x):
+    """A tile, or a split operand's pair of tiles joined along the lanes:
+    the position-free part fills whole lane tiles, so the join is aligned,
+    and the joined tile is the one the unsplit operand would have loaded
+    (the rotary key's one head beside each head's own part)."""
+    return jnp.concatenate(x, axis=1) if isinstance(x, tuple) else x
+
+
+def _scores(q, k):
+    """``q k^T`` of a tile in float32, split operands joined first: one
+    product over their summed width, as for an unsplit operand."""
+    return jax.lax.dot_general(_joined(q), _joined(k),
+                               (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _accumulate(acc, rows, product):
+    """``acc[rows, :] += product()`` (``rows`` None: ``acc[:]``); a split
+    operand's pair of accumulators each adds its own columns of the one
+    product."""
+    index = slice(None) if rows is None else (rows, slice(None))
+    if not isinstance(acc, tuple):
+        acc[index] += product()
+        return
+    full, start = product(), 0
+    for part in acc:
+        width = part.shape[1]
+        part[index] += full[:, start:start + width]
+        start += width
+
+
 def _softmax_tile(q, k, v, mask, acc_ref, m_ref, l_ref, scale: float,
                   rows_may_be_dead: bool = False):
     """One (q tile, key tile) step of the online softmax, the tile body of
@@ -293,14 +355,13 @@ def _softmax_tile(q, k, v, mask, acc_ref, m_ref, l_ref, scale: float,
     scores ``q k^T * scale`` through ``mask`` (a callable on the float32
     score tile), then the running max ``m_ref``, normalizer ``l_ref`` and
     unnormalized accumulator ``acc_ref`` (VMEM scratch) take the tile in.
+    q and k may be split operands' pairs of tiles (:func:`_scores`).
 
     Matmuls stay in the input dtype (bf16 on the training path) with f32
     accumulation — the MXU's native mode; upcasting the operands to f32
     first would run the systolic array at a fraction of peak. All softmax
     bookkeeping (max, exp, normalizer) is f32."""
-    s = jax.lax.dot_general(                                     # (bq, bk)
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
+    s = _scores(q, k) * scale                                    # (bq, bk)
     s = mask(s)
     m_prev, l_prev = m_ref[:, 0], l_ref[:, 0]
     m_new = jnp.maximum(m_prev, s.max(axis=-1))
@@ -347,7 +408,7 @@ def _flash_kernel(qt_ref, kt_ref, flags_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
 
     _softmax_tile(
-        q_ref[0, 0, :, :], k_ref[0, 0, :, :], v_ref[0, 0, :, :],
+        _tile(q_ref), _tile(k_ref), v_ref[0, 0, :, :],
         lambda s: _mask_causal(s, causal, q_off, k_off, block_q, block_k,
                                window),
         acc_ref, m_ref, l_ref, scale, rows_may_be_dead=window is not None)
@@ -375,8 +436,9 @@ def _q_walk_specs(block_q: int, block_k: int, rep: int):
     :func:`_q_schedule`'s arrays ``(q tile, K/V tile, flags)`` ->
     ``(q_rows, kv_rows, stat_spec)``: ``q_rows(width)`` reads a (b, h,
     rows, width) operand a q tile at a time, ``kv_rows(width)`` K or V a
-    tile at a time from the q head's kv head, and ``stat_spec`` the (b, h,
-    seq, 1) row statistics."""
+    tile at a time from the q head's kv head (``kv_rows(width, one=True)``
+    from head 0: a split key's one rotary head), and ``stat_spec`` the (b,
+    h, seq, 1) row statistics."""
     from jax.experimental import pallas as pl
 
     def q_index(bi, hi, item, qt, kt, flags):
@@ -384,9 +446,36 @@ def _q_walk_specs(block_q: int, block_k: int, rep: int):
 
     def kv_index(bi, hi, item, qt, kt, flags):
         return bi, hi // rep, kt[item], 0
+
+    def one_index(bi, hi, item, qt, kt, flags):
+        return bi, 0, kt[item], 0
     return (lambda width: pl.BlockSpec((1, 1, block_q, width), q_index),
-            lambda width: pl.BlockSpec((1, 1, block_k, width), kv_index),
+            lambda width, one=False: pl.BlockSpec(
+                (1, 1, block_k, width), one_index if one else kv_index),
             pl.BlockSpec((1, 1, block_q, 1), q_index))
+
+
+def _q_specs(q, q_rows):
+    """``q_rows`` of each part of a (b, h, seq, width) q-side operand."""
+    if isinstance(q, tuple):
+        return tuple(q_rows(part.shape[3]) for part in q)
+    return q_rows(q.shape[3])
+
+
+def _key_specs(k, kv_rows):
+    """``kv_rows`` of a (b, kv_h, seq, width) key, or of a split key's
+    parts: its position-free part a head per kv head, its rotary part the
+    one head all share."""
+    if isinstance(k, tuple):
+        return (kv_rows(k[0].shape[3]), kv_rows(k[1].shape[3], one=True))
+    return kv_rows(k.shape[3])
+
+
+def _to_kernel(x):
+    """(b, seq, heads, width) -> the kernels' (b, heads, seq, width), a
+    split operand part by part (and back: the transpose is its own
+    inverse)."""
+    return jax.tree.map(lambda a: a.transpose(0, 2, 1, 3), x)
 
 
 def _flash_launch(q, k, v, causal: bool, block_q: int, block_k: int,
@@ -407,8 +496,9 @@ def _flash_launch(q, k, v, causal: bool, block_q: int, block_k: int,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, sq, h, d = q.shape
-    sk, kv_h, vd = k.shape[1], k.shape[2], v.shape[3]
+    b, sq, h, _ = _lead(q).shape
+    sk, kv_h, vd = _lead(k).shape[1], _lead(k).shape[2], v.shape[3]
+    d = sum(part.shape[3] for part in _parts(q))
     sched = _q_schedule(_live_tiles(sq, sk, block_q, block_k, causal, window))
     kernel = partial(_flash_kernel, block_q=block_q, block_k=block_k,
                      causal=causal, scale=1.0 / np.sqrt(d),
@@ -417,12 +507,13 @@ def _flash_launch(q, k, v, causal: bool, block_q: int, block_k: int,
     q_rows, kv_rows, stat_spec = _q_walk_specs(block_q, block_k, h // kv_h)
     o_spec = q_rows(vd)
     stat_shape = jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32)
+    dtype = _lead(q).dtype
     if mode == "out":
         out_specs = o_spec
-        out_shape = jax.ShapeDtypeStruct((b, h, sq, vd), q.dtype)
+        out_shape = jax.ShapeDtypeStruct((b, h, sq, vd), dtype)
     elif mode == "lse":
         out_specs = [o_spec, stat_spec]
-        out_shape = [jax.ShapeDtypeStruct((b, h, sq, vd), q.dtype),
+        out_shape = [jax.ShapeDtypeStruct((b, h, sq, vd), dtype),
                      stat_shape]
     else:  # stats: unnormalized f32 accumulator + m/l
         out_specs = [o_spec, stat_spec, stat_spec]
@@ -432,7 +523,8 @@ def _flash_launch(q, k, v, causal: bool, block_q: int, block_k: int,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(sched), grid=(b, h, len(sched[0])),
-            in_specs=[q_rows(d), kv_rows(d), kv_rows(vd)],
+            in_specs=[_q_specs(q, q_rows), _key_specs(k, kv_rows),
+                      kv_rows(vd)],
             out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((block_q, vd), jnp.float32),     # acc
@@ -443,8 +535,7 @@ def _flash_launch(q, k, v, causal: bool, block_q: int, block_k: int,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="flash_fwd" if window is None else "swa_fwd",
-    )(*sched, q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-      v.transpose(0, 2, 1, 3))
+    )(*sched, _to_kernel(q), _to_kernel(k), v.transpose(0, 2, 1, 3))
 
 
 def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
@@ -484,7 +575,7 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, q_off, k_off,
     ``(p, ds)`` with ``ds`` already scaled — keeping the numerics in ONE
     place so dQ and dK/dV cannot drift apart."""
     return _p_ds_tile(
-        q_ref[0, 0, :, :], k_ref[0, 0, :, :], v_ref[0, 0, :, :],
+        _tile(q_ref), _tile(k_ref), v_ref[0, 0, :, :],
         do_ref[0, 0, :, :], lse_ref[0, 0, :, 0], dd_ref[0, 0, :, 0],
         lambda s: _mask_causal(s, causal, q_off, k_off, block_q, block_k,
                                window), scale)
@@ -493,9 +584,9 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, q_off, k_off,
 def _p_ds_tile(q, k, v, do, lse, dd, mask, scale: float):
     """The tile math of :func:`_bwd_p_ds` on loaded tiles: q (bq, d), do
     (bq, vd); k (bk, d), v (bk, vd); lse, dd (bq,); ``mask`` a callable on
-    the score tile."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+    the score tile. q and k may be split operands' pairs of tiles
+    (:func:`_scores`)."""
+    s = _scores(q, k) * scale
     s = mask(s)
     p = jnp.exp(s - lse[:, None])                               # (bq, bk)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
@@ -513,45 +604,64 @@ def _flash_bwd_kernel(kt_ref, head_ref, qt_ref, flags_ref, k_ref, v_ref,
     all three gradients taken from them: dq accumulates in a tile of
     scratch across a q tile's K/V tiles as in the dQ pass, dk and dv in
     float32 scratch that holds the whole K/V head (every head of the
-    group adds into it), written out at the head's last item."""
+    group adds into it), written out at the head's last item. With split
+    operands q, k, dq, dk and their scratch are pairs; the rotary key's dk,
+    one head for every query head, holds the batch row's whole walk, every
+    K/V head adding into it, and is written out at its last item."""
     from jax.experimental import pallas as pl
 
     item = pl.program_id(2)
     flags = flags_ref[item]
     q_off, k_off = qt_ref[item] * block_q, kt_ref[item] * block_k
+    dk_head, dk_row = (dk_acc if isinstance(dk_acc, tuple)
+                       else (dk_acc, None))
 
     @pl.when(item == 0)
     def _init_head():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dk_head[:] = jnp.zeros_like(dk_head)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    if dk_row is not None:
+        @pl.when(jnp.logical_and(item == 0, pl.program_id(1) == 0))
+        def _init_row():
+            dk_row[:] = jnp.zeros_like(dk_row)
 
     @pl.when((flags & _FIRST) != 0)
     def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+        for acc in _parts(dq_acc):
+            acc[:] = jnp.zeros_like(acc)
 
     p, ds = _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, q_off,
                       k_off, block_q, block_k, causal, scale, window)
-    q, k, do = q_ref[0, 0, :, :], k_ref[0, 0, :, :], do_ref[0, 0, :, :]
+    q, k, do = _tile(q_ref), _tile(k_ref), do_ref[0, 0, :, :]
     rows = pl.ds(pl.multiple_of(k_off, block_k), block_k)
     dv_acc[rows, :] += jax.lax.dot_general(
         p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                     # (bk, vd)
-    ds = ds.astype(q.dtype)
-    dk_acc[rows, :] += jax.lax.dot_general(
-        ds, q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                     # (bk, d)
-    dq_acc[:] += jax.lax.dot_general(
-        ds, k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                     # (bq, d)
+    ds = ds.astype(_lead(q).dtype)
+    _accumulate(dk_acc, rows, lambda: jax.lax.dot_general(
+        ds, _joined(q), (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32))                    # (bk, d)
+    _accumulate(dq_acc, None, lambda: jax.lax.dot_general(
+        ds, _joined(k), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32))                    # (bq, d)
 
     @pl.when((flags & _LAST) != 0)
     def _emit():
-        dq_ref[0, 0, :, :] = dq_acc[:].astype(dq_ref.dtype)
+        for out, acc in zip(_parts(dq_ref), _parts(dq_acc)):
+            out[0, 0, :, :] = acc[:].astype(out.dtype)
 
     @pl.when(item == pl.num_programs(2) - 1)
     def _emit_head():
-        dk_ref[0, 0, :, :] = dk_acc[:].astype(dk_ref.dtype)
+        out = _lead(dk_ref)
+        out[0, 0, :, :] = dk_head[:].astype(out.dtype)
         dv_ref[0, 0, :, :] = dv_acc[:].astype(dv_ref.dtype)
+
+    if dk_row is not None:
+        @pl.when(jnp.logical_and(item == pl.num_programs(2) - 1,
+                                 pl.program_id(1) == pl.num_programs(1) - 1))
+        def _emit_row():
+            dk_ref[1][0, 0, :, :] = dk_row[:].astype(dk_ref[1].dtype)
 
 
 def _flash_bwd_dq_kernel(qt_ref, kt_ref, flags_ref, q_ref, k_ref, v_ref,
@@ -560,8 +670,9 @@ def _flash_bwd_dq_kernel(qt_ref, kt_ref, flags_ref, q_ref, k_ref, v_ref,
                          scale: float, window=None):
     """dQ pass (flash-attention-2 backward): grid (b, h, items) over
     :func:`_q_schedule`, a q tile's K/V tiles innermost; dq accumulates in
-    VMEM scratch across them. P is re-exponentiated from the saved lse, so
-    no softmax state needs carrying."""
+    VMEM scratch across them (a pair of them for split operands). P is
+    re-exponentiated from the saved lse, so no softmax state needs
+    carrying."""
     from jax.experimental import pallas as pl
 
     item = pl.program_id(2)
@@ -570,18 +681,20 @@ def _flash_bwd_dq_kernel(qt_ref, kt_ref, flags_ref, q_ref, k_ref, v_ref,
 
     @pl.when((flags & _FIRST) != 0)
     def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+        for acc in _parts(dq_acc):
+            acc[:] = jnp.zeros_like(acc)
 
     _, ds = _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, q_off,
                       k_off, block_q, block_k, causal, scale, window)
-    k = k_ref[0, 0, :, :]
-    dq_acc[:] += jax.lax.dot_general(
-        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    k = _tile(k_ref)
+    _accumulate(dq_acc, None, lambda: jax.lax.dot_general(
+        ds.astype(_lead(k).dtype), _joined(k), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32))
 
     @pl.when((flags & _LAST) != 0)
     def _emit():
-        dq_ref[0, 0, :, :] = dq_acc[:].astype(dq_ref.dtype)
+        for out, acc in zip(_parts(dq_ref), _parts(dq_acc)):
+            out[0, 0, :, :] = acc[:].astype(out.dtype)
 
 
 def _flash_bwd_dkv_kernel(kt_ref, head_ref, qt_ref, flags_ref, k_ref, v_ref,
@@ -592,7 +705,9 @@ def _flash_bwd_dkv_kernel(kt_ref, head_ref, qt_ref, flags_ref, k_ref, v_ref,
     the innermost dimension walks every (grouped-query head, live q tile)
     pair that attends to a K/V tile, accumulating dk/dv in VMEM scratch
     (GQA gradients sum over the head group here instead of a host-side
-    reduction over repeated K/V)."""
+    reduction over repeated K/V). A split key's two parts accumulate
+    alike: the rotary part's gradient leaves a K/V head at a time, for its
+    caller to sum over the heads."""
     from jax.experimental import pallas as pl
 
     item = pl.program_id(2)
@@ -601,23 +716,25 @@ def _flash_bwd_dkv_kernel(kt_ref, head_ref, qt_ref, flags_ref, k_ref, v_ref,
 
     @pl.when((flags & _FIRST) != 0)
     def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
+        for acc in _parts(dk_acc):
+            acc[:] = jnp.zeros_like(acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     p, ds = _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, q_off,
                       k_off, block_q, block_k, causal, scale, window)
-    q = q_ref[0, 0, :, :]
+    q = _tile(q_ref)
     do = do_ref[0, 0, :, :]
     dv_acc[:] += jax.lax.dot_general(
         p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                     # (bk, vd)
-    dk_acc[:] += jax.lax.dot_general(
-        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                     # (bk, d)
+    _accumulate(dk_acc, None, lambda: jax.lax.dot_general(
+        ds.astype(_lead(q).dtype), _joined(q), (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32))                    # (bk, d)
 
     @pl.when((flags & _LAST) != 0)
     def _emit():
-        dk_ref[0, 0, :, :] = dk_acc[:].astype(dk_ref.dtype)
+        for out, acc in zip(_parts(dk_ref), _parts(dk_acc)):
+            out[0, 0, :, :] = acc[:].astype(out.dtype)
         dv_ref[0, 0, :, :] = dv_acc[:].astype(dv_ref.dtype)
 
 
@@ -632,11 +749,19 @@ def _kv_walk_specs(block_q: int, block_k: int, rep: int):
     def kv_index(bi, gi, item, kt, head, qt, flags):
         return bi, gi, kt[item], 0
 
+    def one_index(bi, gi, item, kt, head, qt, flags):
+        return bi, 0, kt[item], 0
+
     def q_index(bi, gi, item, kt, head, qt, flags):
         return bi, gi * rep + head[item], qt[item], 0
     return (lambda width: pl.BlockSpec((1, 1, block_q, width), q_index),
-            lambda width: pl.BlockSpec((1, 1, block_k, width), kv_index),
+            lambda width, one=False: pl.BlockSpec(
+                (1, 1, block_k, width), one_index if one else kv_index),
             pl.BlockSpec((1, 1, block_q, 1), q_index))
+
+
+def _widths(x) -> tuple:
+    return tuple(part.shape[3] for part in _parts(x))
 
 
 def _flash_backward(q, k, v, o, lse, do, causal: bool, block_q: int,
@@ -647,31 +772,44 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, block_q: int,
     kv-innermost dQ pass and a q-innermost dK/dV pass
     (:func:`_flash_backward_pair`); in-kernel GQA group accumulation and
     no O(seq^2) or O(block*seq) HBM tensors either way — the memory story
-    of the forward, extended to training."""
-    d, sk, vd = q.shape[3], k.shape[1], v.shape[3]
+    of the forward, extended to training. Split q and k get split
+    gradients."""
+    widths, sk, vd = _widths(q), _lead(k).shape[1], v.shape[3]
+    d = sum(widths)
     # D_i = rowsum(dO ∘ O): O(seq·d) elementwise, fine outside the kernel.
     dd = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    operands = (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+    operands = (_to_kernel(q), _to_kernel(k),
                 v.transpose(0, 2, 1, 3), do.transpose(0, 2, 1, 3),
                 lse,                            # already (b, h, sq, 1)
                 dd.transpose(0, 2, 1)[..., None])
     tile = dict(block_q=block_q, block_k=block_k, causal=causal,
                 scale=1.0 / np.sqrt(d), window=window)
-    limit = _bwd_vmem_limit(sk, d, vd, k.dtype.itemsize, block_q, block_k)
+    limit = _bwd_vmem_limit(sk, widths, vd, _lead(k).dtype.itemsize,
+                            block_q, block_k)
     grads = (_flash_backward_pair(*operands, tile, interpret) if limit is None
              else _flash_backward_one(*operands, tile, interpret, limit))
-    return tuple(g.transpose(0, 2, 1, 3) for g in grads)
+    return tuple(_to_kernel(g) for g in grads)
+
+
+def _scratch(x, rows: int):
+    """float32 VMEM scratch of ``rows`` a part of operand ``x``."""
+    from jax.experimental.pallas import tpu as pltpu
+    if isinstance(x, tuple):
+        return tuple(_scratch(part, rows) for part in x)
+    return pltpu.VMEM((rows, x.shape[3]), jnp.float32)
 
 
 def _flash_backward_one(qT, kT, vT, doT, lseT, ddT, tile: dict,
                         interpret: bool, vmem_limit: int):
     """``flash_bwd`` (with a window ``swa_bwd``): grid (b, kv_heads,
     items) over :func:`_bwd_schedule`; a K/V head's dK and dV stay in
-    float32 scratch for the whole walk and leave as whole-head blocks."""
+    float32 scratch for the whole walk and leave as whole-head blocks; a
+    split key's rotary dK, summed over every head, leaves as one block a
+    batch row."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    (b, h, sq, d), (_, kv_h, sk, vd) = qT.shape, vT.shape
+    (b, h, sq, _), (_, kv_h, sk, vd) = _lead(qT).shape, vT.shape
     block_q, block_k, rep = tile["block_q"], tile["block_k"], h // kv_h
     sched = _bwd_schedule(_live_tiles(sq, sk, block_q, block_k,
                                       tile["causal"], tile["window"]), rep)
@@ -680,18 +818,29 @@ def _flash_backward_one(qT, kT, vT, doT, lseT, ddT, tile: dict,
     def kv_head(width):
         return pl.BlockSpec((1, 1, sk, width),
                             lambda bi, gi, item, *sched: (bi, gi, 0, 0))
+
+    own = _lead(kT)
+    dk_spec = kv_head(own.shape[3])
+    dk_shape = jax.ShapeDtypeStruct((b, kv_h, sk, own.shape[3]), own.dtype)
+    if isinstance(kT, tuple):   # the rotary key's dK: one block a batch row
+        rot = kT[1]
+        dk_spec = (dk_spec, pl.BlockSpec(
+            (1, 1, sk, rot.shape[3]),
+            lambda bi, gi, item, *sched: (bi, 0, 0, 0)))
+        dk_shape = (dk_shape, jax.ShapeDtypeStruct((b, 1, sk, rot.shape[3]),
+                                                   rot.dtype))
     return pl.pallas_call(
         partial(_flash_bwd_kernel, **tile),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(sched), grid=(b, kv_h, len(sched[0])),
-            in_specs=[kv_rows(d), kv_rows(vd), q_rows(d), q_rows(vd),
-                      stat_spec, stat_spec],
-            out_specs=[q_rows(d), kv_head(d), kv_head(vd)],
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
-                            pltpu.VMEM((sk, d), jnp.float32),
+            in_specs=[_key_specs(kT, kv_rows), kv_rows(vd),
+                      _q_specs(qT, q_rows), q_rows(vd), stat_spec, stat_spec],
+            out_specs=[_q_specs(qT, q_rows), dk_spec, kv_head(vd)],
+            scratch_shapes=[_scratch(qT, block_q), _scratch(kT, sk),
                             pltpu.VMEM((sk, vd), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct((b, h, sq, d), qT.dtype),
-                   jax.ShapeDtypeStruct((b, kv_h, sk, d), kT.dtype),
+        out_shape=[jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                       a.shape, a.dtype), qT),
+                   dk_shape,
                    jax.ShapeDtypeStruct((b, kv_h, sk, vd), vT.dtype)],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
@@ -704,11 +853,13 @@ def _flash_backward_pair(qT, kT, vT, doT, lseT, ddT, tile: dict,
     """The backward at tile residency, for a K/V head whose float32 dK and
     dV do not fit VMEM: ``flash_bwd_dq`` over :func:`_q_schedule` and
     ``flash_bwd_dkv`` over :func:`_kv_schedule` (``swa_*`` with a
-    window), each making S, P, dP and dS for itself."""
+    window), each making S, P, dP and dS for itself. A split key's rotary
+    dK leaves the second call a K/V head at a time, in float32, and is
+    summed over the heads here."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    (b, h, sq, d), (_, kv_h, sk, vd) = qT.shape, vT.shape
+    (b, h, sq, _), (_, kv_h, sk, vd) = _lead(qT).shape, vT.shape
     block_q, block_k = tile["block_q"], tile["block_k"]
     rep = h // kv_h
     prefix = "flash" if tile["window"] is None else "swa"
@@ -716,35 +867,43 @@ def _flash_backward_pair(qT, kT, vT, doT, lseT, ddT, tile: dict,
                        tile["window"])
     sched = _q_schedule(live)
     q_rows, kv_rows, stat_spec = _q_walk_specs(block_q, block_k, rep)
-    dq = pl.pallas_call(
+    dq, = pl.pallas_call(
         partial(_flash_bwd_dq_kernel, **tile),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(sched), grid=(b, h, len(sched[0])),
-            in_specs=[q_rows(d), kv_rows(d), kv_rows(vd), q_rows(vd),
-                      stat_spec, stat_spec],
-            out_specs=q_rows(d),
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), qT.dtype),
+            in_specs=[_q_specs(qT, q_rows), _key_specs(kT, kv_rows),
+                      kv_rows(vd), q_rows(vd), stat_spec, stat_spec],
+            out_specs=[_q_specs(qT, q_rows)],
+            scratch_shapes=[_scratch(qT, block_q)]),
+        out_shape=[jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype), qT)],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret, name=f"{prefix}_bwd_dq",
     )(*sched, qT, kT, vT, doT, lseT, ddT)
 
     sched = _kv_schedule(live, rep)     # (K/V tile, head in group, q tile, .)
     q_rows, kv_rows, stat_spec = _kv_walk_specs(block_q, block_k, rep)
+
+    def dk_part(part, dtype):
+        return jax.ShapeDtypeStruct((b, kv_h, sk, part.shape[3]), dtype)
+    dk_shape = ((dk_part(kT[0], kT[0].dtype), dk_part(kT[1], jnp.float32))
+                if isinstance(kT, tuple) else dk_part(kT, kT.dtype))
     dk, dv = pl.pallas_call(
         partial(_flash_bwd_dkv_kernel, **tile),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(sched), grid=(b, kv_h, len(sched[0])),
-            in_specs=[kv_rows(d), kv_rows(vd), q_rows(d), q_rows(vd),
-                      stat_spec, stat_spec],
-            out_specs=[kv_rows(d), kv_rows(vd)],
-            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+            in_specs=[_key_specs(kT, kv_rows), kv_rows(vd),
+                      _q_specs(qT, q_rows), q_rows(vd), stat_spec, stat_spec],
+            out_specs=[_q_specs(kT, kv_rows), kv_rows(vd)],
+            scratch_shapes=[_scratch(kT, block_k),
                             pltpu.VMEM((block_k, vd), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct((b, kv_h, sk, d), kT.dtype),
+        out_shape=[dk_shape,
                    jax.ShapeDtypeStruct((b, kv_h, sk, vd), vT.dtype)],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret, name=f"{prefix}_bwd_dkv",
     )(*sched, kT, vT, qT, doT, lseT, ddT)
+    if isinstance(kT, tuple):
+        dk = (dk[0], dk[1].sum(axis=1, keepdims=True).astype(kT[1].dtype))
     return dq, dk, dv
 
 
@@ -859,13 +1018,23 @@ def flash_attention(q, k, v, causal: bool = False,
     calls are named ``swa_fwd`` / ``swa_bwd`` (``swa_bwd_dq`` /
     ``swa_bwd_dkv`` where a K/V head's gradients do not fit VMEM).
 
+    q and k may instead be pairs, q ``(q_nope (b, sq, heads, dn), q_rope
+    (b, sq, heads, dr))`` and k ``(k_nope (b, sk, kv_heads, dn), k_rope
+    (b, sk, 1, dr))``: the scores are ``q_nope k_nope^T + q_rope
+    k_rope^T`` scaled by ``1 / sqrt(dn + dr)``, the rotary key one head
+    for every query head (latent attention, ``llama``
+    ``attention="mla"``), and the gradients of q and k come back as such
+    pairs. The calls keep their names.
+
     Falls back to the dense path when the shape can't tile onto the
     hardware (:func:`_tiles`). ``interpret=None`` selects the Pallas
     interpreter on the ``cpu`` backend only, so tests run there
     (:func:`_resolve_interpret`).
     """
-    b, sq, h, d = q.shape
-    sk, kv_h = k.shape[1], k.shape[2]
+    if isinstance(q, tuple) or isinstance(k, tuple):
+        _check_split(q, k)
+    b, sq, h, _ = _lead(q).shape
+    sk, kv_h = _lead(k).shape[1], _lead(k).shape[2]
     if h % kv_h:
         raise ValueError(f"heads ({h}) must be a multiple of kv_heads ({kv_h})")
     if window is not None and (not causal or window < 1):
@@ -877,17 +1046,31 @@ def flash_attention(q, k, v, causal: bool = False,
                       q, k, v)
 
 
+def _check_split(q, k):
+    """Split q and k are two pairs whose parts agree in width, the key's
+    rotary part one head."""
+    if not (isinstance(q, tuple) and isinstance(k, tuple)
+            and len(q) == len(k) == 2 and _widths(q) == _widths(k)
+            and k[1].shape[2] == 1):
+        raise ValueError(
+            "split operands are q = (q_nope, q_rope) and k = (k_nope, "
+            "k_rope) of equal part widths, k_rope one head: got q "
+            f"{jax.tree.map(jnp.shape, q)}, k {jax.tree.map(jnp.shape, k)}")
+
+
 def make_flash_attention(causal: bool = True, block_q: int = _DEFAULT_BLOCK_Q,
                          block_k: int = _DEFAULT_BLOCK_K, interpret=None,
                          window=None):
     """An ``attn_fn`` for :func:`petastorm_tpu.models.llama.apply`
-    (``supports_gqa``: K/V arrive at native kv-head width); with a
+    (``supports_gqa``: K/V arrive at native kv-head width; q and k as
+    :func:`flash_attention` takes them, arrays or split pairs); with a
     ``window`` the one for the model's sliding-window layers
     (``window_attn_fn``). Its caller asked for the kernel, so a shape the
     tiles cannot divide raises (:func:`require_flash_tiles`) instead of
     taking the dense route."""
     def attn(q, k, v):
-        require_flash_tiles(q.shape[1], k.shape[1], causal, block_q, block_k)
+        require_flash_tiles(_lead(q).shape[1], _lead(k).shape[1], causal,
+                            block_q, block_k)
         return flash_attention(q, k, v, causal=causal, block_q=block_q,
                                block_k=block_k, interpret=interpret,
                                window=window)

@@ -18,7 +18,18 @@ def dense_attention(q, k, v, causal: bool = False, window=None):
     Scores accumulate in float32 regardless of input dtype; the causal mask
     is position-based so it also holds for lq != lk. ``window`` (with
     ``causal``) keeps of each query's keys its own and the ``window - 1``
-    before it: sliding-window attention."""
+    before it: sliding-window attention.
+
+    q and k may be pairs ``(position-free part, rotary part)``, the key's
+    rotary part one head for every query head (latent attention's split
+    form, as :func:`petastorm_tpu.ops.flash_attn.flash_attention` takes
+    it): the parts are joined here, the rotary key repeated over the key
+    heads."""
+    if isinstance(q, tuple):
+        (q_nope, q_rope), (k_nope, k_rope) = q, k
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(
+            k_rope, k_nope.shape[:3] + k_rope.shape[3:])], axis=-1)
     b, lq, h, d = q.shape
     kv_h = k.shape[2]
     if window is not None and not causal:

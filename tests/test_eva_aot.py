@@ -223,6 +223,38 @@ def test_flash_kernels_compile_at_latent_attentions_widths(
     assert_the_backward_is_one_kernel("flash", qk, qk, v, None, monkeypatch)
 
 
+def test_split_flash_kernels_compile_at_latent_attentions_widths(
+        topo, no_persistent_cache, monkeypatch):
+    """``kanana2-tok16k-1chip``'s call in the split form the step hands
+    over: q of 32 heads of 128 + 64, k of 32 heads of 128 beside ONE
+    rotary head of 64, values of 128; the backward one kernel (the rotary
+    key's dK summed over the heads in its scratch) under the limit
+    reckoned from the parts' lanes, and the pair beside it."""
+    from petastorm_tpu.ops import flash_attn
+    cfg, rows = latent_cell()
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def operand(heads, width):
+        return jax.ShapeDtypeStruct(
+            (rows["per_chip_batch"], rows["window"], heads, width),
+            jnp.bfloat16, sharding=one_chip)
+
+    heads = cfg["num_attention_heads"]
+    nope, rot = cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"]
+    q = (operand(heads, nope), operand(heads, rot))
+    k = (operand(heads, nope), operand(1, rot))
+    v = operand(heads, cfg["v_head_dim"])
+    assert flash_attn._bwd_vmem_limit(
+        rows["window"], (nope, rot), v.shape[-1], 2, 1024, 1024) == \
+        flash_attn._bwd_vmem_limit(rows["window"], nope + rot, v.shape[-1],
+                                   2, 1024, 1024)
+    one = flash_backward_compiled(q, k, v)
+    assert kernels_in(one) == {"flash_fwd", "flash_bwd"}
+    monkeypatch.setattr(flash_attn, "_bwd_vmem_limit", lambda *a: None)
+    pair = flash_backward_compiled(q, k, v)
+    assert kernels_in(pair) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+
+
 def test_the_latent_cells_whole_step_compiles_and_fits(topo,
                                                        no_persistent_cache):
     """The donated AdamW step of ``kanana2-tok16k-1chip`` as its pipeline
@@ -273,3 +305,9 @@ def test_the_latent_cells_whole_step_compiles_and_fits(topo,
             - m.alias_size_in_bytes + m.temp_size_in_bytes
             + m.generated_code_size_in_bytes)
     assert cfg["state_bytes"] < peak < 16.2e9
+    # The operands reach the kernels in their split form: no q or k of
+    # 128 + 64 columns, and the rotary key one head.
+    text = compiled.as_text()
+    assert "bf16[1,32,16384,192]" not in text
+    assert "bf16[1,16384,32,192]" not in text
+    assert "bf16[1,1,16384,64]" in text

@@ -243,11 +243,12 @@ def test_a_wrong_scale_or_a_missing_latent_norm_would_show(monkeypatch):
     # Make the latent's scale matter: a norm that is not one.
     layer = {**layer, "kv_norm": 1.0 + 0.5 * jax.random.normal(
         jax.random.PRNGKey(4), layer["kv_norm"].shape)}
-    q, k, v = llama._latent_qkv(
+    (q_nope, q_rope), (k_nope, k_rope), v = llama._latent_qkv(
         layer, llama._rmsnorm(x, layer["attn_norm"], cfg.norm_eps), cfg, True)
-    assert q.shape == k.shape == (1, 64, 4, 12) and v.shape == (1, 64, 4, 8)
-    # One rotary key for every head.
-    assert not np.any(np.asarray(k[:, :, 1:, 8:] != k[:, :, :1, 8:]))
+    assert q_nope.shape == k_nope.shape == v.shape == (1, 64, 4, 8)
+    assert q_rope.shape == (1, 64, 4, 4)
+    # One rotary key for every head: one head, never repeated.
+    assert k_rope.shape == (1, 64, 1, 4)
 
     silent = {**layer, "ew2": jnp.zeros_like(layer["ew2"]),
               "sw2": jnp.zeros_like(layer["sw2"])}
@@ -275,8 +276,9 @@ def test_adjacent_pairs_rotate_to_the_same_scores():
     query with a rotated key is the same."""
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 32, 3, 8))
     y = jax.random.normal(jax.random.PRNGKey(1), (1, 32, 3, 8))
-    got = jnp.einsum("bqhd,bkhd->bhqk", llama._rope(x, 1e4, True),
-                     llama._rope(y, 1e4, True))
+    got = jnp.einsum("bqhd,bkhd->bhqk",
+                     llama._rope(llama._pairs_to_halves(x), 1e4),
+                     llama._rope(llama._pairs_to_halves(y), 1e4))
     want = jnp.einsum("qhd,khd->hqk", ref._rope_pairs(x[0], 1e4),
                       ref._rope_pairs(y[0], 1e4))
     assert_close(got[0], want, 1e-5)
@@ -314,3 +316,187 @@ def test_the_new_scopes_name_their_parts():
     for scope in ("mla_latent", "attn_full", "moe_route", "moe_experts",
                   "moe_shared"):
         assert f"petastorm_tpu.{scope}" in text, scope
+
+
+# --- Latent attention's operands in the kernels' split form.
+
+def joined_latent_qkv(layer, h, cfg, rope):
+    """The latent path as it was before the split form: q and k of
+    qk_nope_dim + qk_rope_dim columns a head, sliced from and joined back
+    into one product, the rotary key repeated over the heads, adjacent
+    pairs put in halves order on the product, not on the weights."""
+    b, s, _ = h.shape
+    nh, nope, rot = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
+    q = (h @ layer["wq"].astype(h.dtype)).reshape(b, s, nh, nope + rot)
+    down = h @ layer["wkv_a"].astype(h.dtype)
+    latent = llama._rmsnorm(down[..., :cfg.kv_lora_rank], layer["kv_norm"],
+                            cfg.norm_eps, cfg.norm_unit_offset)
+    up = (latent @ layer["wkv_b"].astype(h.dtype)).reshape(
+        b, s, nh, nope + cfg.v_dim)
+    q_rot = q[..., nope:]
+    k_rot = down[..., cfg.kv_lora_rank:].reshape(b, s, 1, rot)
+    if rope:
+        q_rot = llama._rope(llama._pairs_to_halves(q_rot), cfg.rope_theta)
+        k_rot = llama._rope(llama._pairs_to_halves(k_rot), cfg.rope_theta)
+    q = jnp.concatenate([q[..., :nope], q_rot], axis=-1)
+    k = jnp.concatenate(
+        [up[..., :nope], jnp.broadcast_to(k_rot, (b, s, nh, rot))], axis=-1)
+    return q, k, up[..., nope:]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_weight_side_deinterleave_rotates_the_pairs_bit_for_bit(dtype):
+    """The rotary columns of ``wq`` and ``wkv_a`` put in halves order and
+    rotated as halves are, column for column and bit for bit, the sliced
+    products de-interleaved and rotated as halves: a column gather of a
+    product's weights is the same gather of the product."""
+    sizes = toy_sizes()
+    cfg = program_config(sizes)
+    assert cfg.rope_interleave
+    layer, x = one_layer(sizes)
+    layer = jax.tree.map(lambda a: a.astype(dtype), layer)
+    h = x.astype(dtype)
+    (_, q_rope), (_, k_rope), _ = llama._latent_qkv(layer, h, cfg, True)
+    q_want, k_want, _ = joined_latent_qkv(layer, h, cfg, True)
+    assert q_rope.dtype == dtype
+    np.testing.assert_array_equal(q_rope, q_want[..., 8:])
+    np.testing.assert_array_equal(k_rope, k_want[:, :, :1, 8:])
+    # Unrotated layers hand over the columns in halves order: the scores
+    # do not see the order of the pairs.
+    (_, q_rope), (_, k_rope), _ = llama._latent_qkv(layer, h, cfg, False)
+    q_want, k_want, _ = joined_latent_qkv(layer, h, cfg, False)
+    np.testing.assert_array_equal(
+        q_rope, llama._pairs_to_halves(q_want[..., 8:]))
+    np.testing.assert_array_equal(
+        k_rope, llama._pairs_to_halves(k_want[:, :, :1, 8:]))
+
+
+@pytest.mark.parametrize("attention", ["dense", "flash"])
+def test_a_latent_layer_is_the_joined_forms(attention, monkeypatch):
+    """One expert layer's output and every parameter's gradient through
+    the split operands against the same layer with the joined operands,
+    through ``dense_attention`` and through the interpreted kernels."""
+    sizes = toy_sizes()
+    cfg = program_config(sizes)
+    layer, x = one_layer(sizes)
+    attn_fn = None if attention == "dense" else make_flash_attention(
+        causal=True, block_q=32, block_k=32)
+
+    def run():
+        def out(layer):
+            y, _ = llama.apply_block(layer, x, cfg, attn_fn=attn_fn,
+                                     layer_idx=1)
+            return y
+        y, pull = jax.vjp(out, layer)
+        return y, pull(jnp.cos(y))[0]
+
+    got, got_grads = run()
+    monkeypatch.setattr(llama, "_latent_qkv", joined_latent_qkv)
+    want, want_grads = run()
+    assert_close(got, want, 1e-5)
+    for name in ("wq", "wkv_a", "kv_norm", "wkv_b", "wo", "attn_norm"):
+        assert float(jnp.linalg.norm(got_grads[name] - want_grads[name])) \
+            <= 1e-5 * float(jnp.linalg.norm(want_grads[name])), name
+
+
+def test_the_latent_step_holds_no_joined_head_and_no_repeated_rotary_key():
+    """The lowered train step (kernels interpreted) holds no array of
+    qk_nope_dim + qk_rope_dim columns over the positions, and no broadcast
+    of the one rotary key over the heads; the joined form held both."""
+    import re
+    sizes = toy_sizes(hidden_size=80, num_hidden_layers=2)
+    cfg = program_config(sizes)
+    params = llama.init_params(jax.random.PRNGKey(3), cfg)
+    tokens = jnp.zeros((1, 64), jnp.int32)
+    attn_fn = make_flash_attention(causal=True, block_q=32, block_k=32)
+
+    def lowered():
+        return jax.jit(jax.grad(lambda p: llama.loss_fn(
+            p, {"tokens": tokens}, cfg, shift="roll", attn_fn=attn_fn,
+            xent_chunk=32, remat_layers=True))).lower(params).as_text()
+
+    def joined_heads(text):     # (.., 64 positions, .., 12 columns)
+        return re.findall(r"tensor<(?:\d+x)*64x(?:\d+x)*12x(?:f32|bf16)>",
+                          text)
+
+    def repeated_key(text):     # (1, 64, 1, 4) -> (1, 64, 4, 4)
+        return re.findall(r"broadcast_in_dim[^\n]*tensor<1x64x1x4x\w+>\)"
+                          r" -> tensor<1x64x4x4x", text)
+
+    text = lowered()
+    assert joined_heads(text) == [] and repeated_key(text) == []
+    kernels = str(jax.make_jaxpr(jax.grad(lambda p: llama.loss_fn(
+        p, {"tokens": tokens}, cfg, shift="roll", attn_fn=attn_fn,
+        xent_chunk=32, remat_layers=True)))(params))
+    assert "name=flash_fwd" in kernels and "name=flash_bwd" in kernels
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(llama, "_latent_qkv", joined_latent_qkv)
+        text = lowered()
+    assert joined_heads(text) and repeated_key(text)
+
+
+# sha256 of the jaxpr text (object addresses dropped) of the toy train
+# step's gradient of the dense, sparse and EVA cells' constructions, read
+# on the commit before the split form was added (e89fb74): the latent path
+# is the only one the split form changes.
+OTHER_CELLS = {
+    "dense": "ea9aa52a26ac2650060468c13d13a5b3454355f0840183aed4261bd6c8f673da",
+    "sparse": "d9d290c35057b91b3d38a0dfecb7289bfc68b00616b5524a1237e3a8571d9b77",
+    "eva": "709d72a88bbc9872a05130456f2fbbad68b0cb22f764747f47a78af1b7310053",
+}
+
+
+def other_cells_toy_step(cell: str) -> str:
+    """The jaxpr of the toy train step's gradient of ``cell`` as its
+    pipeline builds the configuration (the rehearsal sizes of
+    ``chipbench/configs``) and its attention callables (kernels
+    interpreted), object addresses dropped."""
+    import json
+    import os
+    import re
+    from petastorm_tpu.ops.eva_attn import make_eva_attention
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def sizes(name):
+        with open(os.path.join(root, "chipbench", "configs",
+                               f"{name}.json")) as f:
+            c = json.load(f)
+        return {**c, **c["rehearsal"]}
+
+    if cell == "dense":
+        c = sizes("mistral7b-v03-d2")
+        cfg = llama.LlamaConfig(
+            vocab=c["vocab_size"], dim=c["hidden_size"],
+            n_layers=c["num_hidden_layers"], n_heads=c["num_attention_heads"],
+            n_kv_heads=c["num_key_value_heads"],
+            hidden=c["intermediate_size"], rope_theta=c["rope_theta"],
+            norm_eps=c["rms_norm_eps"])
+        kw = dict(attn_fn=make_flash_attention(block_q=32, block_k=64))
+    elif cell == "sparse":
+        from chipbench.pipelines.token_moe_decoder import llama_config
+        cfg = llama_config(sizes("smallthinker21b-tp4-d4"))
+        kw = dict(attn_fn=make_flash_attention(block_q=32, block_k=64),
+                  window_attn_fn=make_flash_attention(
+                      window=cfg.sliding_window, block_q=32, block_k=64))
+    else:
+        from chipbench.pipelines.byte_eva_decoder import llama_config
+        cfg = llama_config(sizes("evabyte-6.5b-d4"))
+        kw = dict(eva_attn_fn=make_eva_attention(cfg.eva_window,
+                                                 cfg.eva_chunk))
+    params = jax.eval_shape(lambda key: llama.init_params(key, cfg),
+                            jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((1, 128), jnp.int32)
+    loss = partial(llama.loss_fn, cfg=cfg, shift="roll", xent_chunk=64,
+                   remat_layers=True, **kw)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda p, t: loss(p, {"tokens": t})))(params, tokens))
+    return re.sub(r" at 0x[0-9a-f]+", "", text)
+
+
+@pytest.mark.parametrize("cell", sorted(OTHER_CELLS))
+def test_the_other_cells_toy_steps_are_what_they_were(cell):
+    import hashlib
+    with jax.default_matmul_precision(None):    # as the cells run
+        text = other_cells_toy_step(cell)
+    assert hashlib.sha256(text.encode()).hexdigest() == OTHER_CELLS[cell]
