@@ -42,6 +42,7 @@ UNSCOPED = "unscoped"
 IDENTITY = 1e-3       # the rows' sum may miss the busy time by this share
 JOINED_SHARE = 0.5    # of the busy time whose instruction the text holds
 KERNEL = 'custom_call_target="tpu_custom_call"'
+# The scopes around an attention call where the program lists none.
 ATTENTION = ("attn_full", "attn_window", "attn_eva")
 
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([^\s=]+) = ")
@@ -58,6 +59,14 @@ def vocabulary():
     except ImportError:
         return None
     return device_scopes
+
+
+def attention_scopes() -> tuple:
+    """The scopes around an attention call: the program's
+    ``device_scopes.ATTENTION_SCOPES`` where it lists them (a new mixer's
+    scope then joins them by an edit of the program alone), else
+    :data:`ATTENTION`."""
+    return tuple(getattr(vocabulary(), "ATTENTION_SCOPES", ATTENTION))
 
 
 def instructions(text: str) -> dict:
@@ -258,10 +267,10 @@ def optimizer_ms_per_step(run):
 
 
 def attn_glue_ms_per_step(run):
-    """The attention scopes less their Pallas calls (``eva_prep`` is a
-    scope of its own inside ``attn_eva``, so it is not in them):
-    transposes, pads, row sums."""
-    return ms_per_step(run, scopes=ATTENTION, kernels=False)
+    """The attention scopes (:func:`attention_scopes`) less their Pallas
+    calls (``eva_prep`` is a scope of its own inside ``attn_eva``, so it is
+    not in them): transposes, pads, row sums."""
+    return ms_per_step(run, scopes=attention_scopes(), kernels=False)
 
 
 def moe_rows_ms_per_step(run):

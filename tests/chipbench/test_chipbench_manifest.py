@@ -16,20 +16,34 @@ with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
 CELLS = {c["name"]: c for c in BENCH["workloads"]}
 METRICS = BENCH["end_to_end"] + BENCH["per_layer"]
 
+# Each ``check_*`` takes the manifest (and the root its files lie under) as
+# arguments: the tests below call them on ``BENCHMARK.json``, and
+# ``test_chipbench_room.py`` on copies with a cell and a metric added.
 
-def reporting(metric: dict) -> set:
-    return set(metric.get("workloads", CELLS))
+
+def cells_of(bench: dict) -> dict:
+    return {c["name"]: c for c in bench["workloads"]}
+
+
+def reporting(bench: dict, metric: dict) -> set:
+    return set(metric.get("workloads", cells_of(bench)))
+
+
+def check_top_level_keys_and_limits(bench: dict, root: str = ROOT):
+    cells = cells_of(bench)
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    assert bench["paths"] == ["chipbench", "tests/chipbench"]
+    assert 1 <= bench["run_seconds"] <= 51
+    assert len(json.dumps(bench, indent=2)) <= 64 * 1024
+    four = [c for c in cells.values() if c["chips"] == 4]
+    assert len(four) <= max(1, len(cells) // 4)
+    assert all(c["chips"] in (1, 4) for c in cells.values())
 
 
 def test_top_level_keys_and_limits():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
-                          "workloads", "end_to_end", "per_layer"}
-    assert BENCH["paths"] == ["chipbench", "tests/chipbench"]
-    assert 1 <= BENCH["run_seconds"] <= 51
+    check_top_level_keys_and_limits(BENCH)
     assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 * 1024
-    four = [c for c in CELLS.values() if c["chips"] == 4]
-    assert len(four) <= max(1, len(CELLS) // 4)
-    assert all(c["chips"] in (1, 4) for c in CELLS.values())
 
 
 def names_a_width(key: str) -> bool:
@@ -49,11 +63,10 @@ def test_reduced_may_name_a_sliced_vocabulary_and_no_width(key, width):
     assert names_a_width(key) is width
 
 
-@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda e: e["name"])
-def test_config_file_loads_and_states_its_cut(entry):
+def check_config_entry(bench: dict, entry: dict, root: str = ROOT):
     assert set(entry) == {"name", "source", "file", "reduced", "why"}
     assert entry["file"].startswith("chipbench/configs/")
-    with open(os.path.join(ROOT, entry["file"])) as f:
+    with open(os.path.join(root, entry["file"])) as f:
         config = json.load(f)
     assert config["name"] == entry["name"]
     assert config["source"] == entry["source"]
@@ -63,37 +76,51 @@ def test_config_file_loads_and_states_its_cut(entry):
         assert not names_a_width(key)
     assert config["guarantees"] and config["assumed"]
     assert os.path.exists(os.path.join(
-        ROOT, "chipbench", "pipelines", config["pipeline"] + ".py"))
-    assert any(c["config"] == entry["name"] for c in CELLS.values())
+        root, "chipbench", "pipelines", config["pipeline"] + ".py"))
+    assert any(c["config"] == entry["name"] for c in bench["workloads"])
+
+
+@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda e: e["name"])
+def test_config_file_loads_and_states_its_cut(entry):
+    check_config_entry(BENCH, entry)
+
+
+def check_cell(bench: dict, cell: dict, root: str = ROOT):
+    assert set(cell) == {"name", "config", "traffic", "chips", "why"}
+    assert NAME.match(cell["name"]) and NAME.match(cell["traffic"])
+    assert len(cell["why"]) <= 200 and "\n" not in cell["why"]
+    assert cell["config"] in {c["name"] for c in bench["configs"]}
+    for sub, ext in (("traffic", cell["traffic"]), ("limits", cell["name"])):
+        with open(os.path.join(root, "chipbench", sub, ext + ".json")) as f:
+            assert isinstance(json.load(f), dict)
+    mine = [m for m in bench["end_to_end"]
+            if cell["name"] in reporting(bench, m)]
+    assert {"setup_s"} < {m["name"] for m in mine}
+    assert any(cell["name"] in reporting(bench, m)
+               for m in bench["per_layer"])
 
 
 @pytest.mark.parametrize("cell", CELLS.values(), ids=lambda c: c["name"])
 def test_cell_resolves_by_name(cell):
-    assert set(cell) == {"name", "config", "traffic", "chips", "why"}
-    assert NAME.match(cell["name"]) and NAME.match(cell["traffic"])
-    assert len(cell["why"]) <= 200 and "\n" not in cell["why"]
-    assert cell["config"] in {c["name"] for c in BENCH["configs"]}
-    for sub, ext in (("traffic", cell["traffic"]), ("limits", cell["name"])):
-        with open(os.path.join(ROOT, "chipbench", sub, ext + ".json")) as f:
-            assert isinstance(json.load(f), dict)
-    mine = [m for m in BENCH["end_to_end"] if cell["name"] in reporting(m)]
-    assert {"setup_s"} < {m["name"] for m in mine}
-    assert any(cell["name"] in reporting(m) for m in BENCH["per_layer"])
+    check_cell(BENCH, cell)
 
 
-def test_cells_are_distinct_pairs():
-    pairs = [(c["config"], c["traffic"]) for c in CELLS.values()]
+def check_cells_are_distinct_pairs(bench: dict, root: str = ROOT):
+    pairs = [(c["config"], c["traffic"]) for c in bench["workloads"]]
     assert len(set(pairs)) == len(pairs)
 
 
-@pytest.mark.parametrize("metric", METRICS, ids=lambda m: m["name"])
-def test_metric_entry(metric):
+def test_cells_are_distinct_pairs():
+    check_cells_are_distinct_pairs(BENCH)
+
+
+def check_metric_entry(bench: dict, metric: dict, root: str = ROOT):
     assert NAME.match(metric["name"]) and UNIT.match(metric["unit"])
     assert metric["better"] in ("lower", "higher")
     assert metric["source"] in ("device_trace", "program_span",
                                 "program_counter", "host_clock")
-    assert reporting(metric) <= set(CELLS)
-    end_to_end = metric in BENCH["end_to_end"]
+    assert reporting(bench, metric) <= set(cells_of(bench))
+    end_to_end = metric in bench["end_to_end"]
     allowed = {"name", "unit", "better", "source", "workloads"} | (
         {"bound"} if end_to_end else {"layer", "moves"})
     assert set(metric) <= allowed
@@ -102,27 +129,47 @@ def test_metric_entry(metric):
         assert 0.01 <= metric["bound"] <= 0.1
 
 
-@pytest.mark.parametrize("metric", BENCH["per_layer"], ids=lambda m: m["name"])
-def test_per_layer_metric_has_reader_and_moves_what_its_cells_report(metric):
+@pytest.mark.parametrize("metric", METRICS, ids=lambda m: m["name"])
+def test_metric_entry(metric):
+    check_metric_entry(BENCH, metric)
+
+
+def check_per_layer_metric(bench: dict, metric: dict, root: str = ROOT):
     from chipbench.run import layer_metric_reader
     assert callable(layer_metric_reader(metric["name"]))
-    moved = next(m for m in BENCH["end_to_end"] if m["name"] == metric["moves"])
+    moved = next(m for m in bench["end_to_end"]
+                 if m["name"] == metric["moves"])
     assert metric["workloads"], "an explicit list, so a new cell edits nothing"
-    assert set(metric["workloads"]) <= reporting(moved)
+    assert set(metric["workloads"]) <= reporting(bench, moved)
     if "roofline" in metric["name"] or "mfu" in metric["name"]:
         assert metric["unit"] == "%"
 
 
-def test_every_cell_reports_a_whole_step_share_of_peak():
-    for cell in CELLS:
+@pytest.mark.parametrize("metric", BENCH["per_layer"], ids=lambda m: m["name"])
+def test_per_layer_metric_has_reader_and_moves_what_its_cells_report(metric):
+    check_per_layer_metric(BENCH, metric)
+
+
+def check_every_cell_reports_a_whole_step_share_of_peak(bench: dict,
+                                                        root: str = ROOT):
+    for cell in cells_of(bench):
         assert any("mfu" in re.split(r"[_.]", m["name"])
-                   and cell in m["workloads"] for m in BENCH["per_layer"])
+                   and cell in m["workloads"] for m in bench["per_layer"])
+
+
+def test_every_cell_reports_a_whole_step_share_of_peak():
+    check_every_cell_reports_a_whole_step_share_of_peak(BENCH)
+
+
+def check_names_are_unique(bench: dict, root: str = ROOT):
+    for group in (bench["end_to_end"] + bench["per_layer"],
+                  bench["workloads"], bench["configs"]):
+        names = [g["name"] for g in group]
+        assert len(set(names)) == len(names)
 
 
 def test_names_are_unique():
-    for group in (METRICS, BENCH["workloads"], BENCH["configs"]):
-        names = [g["name"] for g in group]
-        assert len(set(names)) == len(names)
+    check_names_are_unique(BENCH)
 
 
 def test_files_under_paths_have_plain_names():
@@ -133,3 +180,21 @@ def test_files_under_paths_have_plain_names():
             for name in files:
                 assert re.match(r"^[A-Za-z0-9_.\-]+$", name), name
 
+
+def checks(bench: dict) -> list:
+    """Every check above as ``(name, check, arguments)``, over every entry
+    it takes: what holds of ``BENCHMARK.json`` has to hold of a copy with
+    an entry added."""
+    out = [(f.__name__, f, ()) for f in (
+        check_top_level_keys_and_limits, check_cells_are_distinct_pairs,
+        check_every_cell_reports_a_whole_step_share_of_peak,
+        check_names_are_unique)]
+    out += [(f"check_config_entry[{e['name']}]", check_config_entry, (e,))
+            for e in bench["configs"]]
+    out += [(f"check_cell[{c['name']}]", check_cell, (c,))
+            for c in bench["workloads"]]
+    out += [(f"check_metric_entry[{m['name']}]", check_metric_entry, (m,))
+            for m in bench["end_to_end"] + bench["per_layer"]]
+    out += [(f"check_per_layer_metric[{m['name']}]", check_per_layer_metric,
+             (m,)) for m in bench["per_layer"]]
+    return out

@@ -227,3 +227,31 @@ def test_each_pipeline_expects_families_not_kernel_names(pipeline, cell,
     assert trace_reduce.roles_missing(job.expected_kernels, today) == 0
     assert trace_reduce.roles_missing(job.expected_kernels, set()) == \
         2 * len(families)
+
+
+def manifest_cells() -> list:
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return [c["name"] for c in json.load(f)["workloads"]]
+
+
+@pytest.mark.parametrize("cell", manifest_cells())
+def test_every_cells_pipeline_expects_families(cell, tmp_path):
+    """The families check of the case above, over every cell of the
+    manifest: a new cell's pipeline is held to it without an edit here."""
+    import importlib
+
+    import jax
+
+    from chipbench import run
+    _, _, config, traffic = run.load_cell(cell, rehearsal=True)
+    job = importlib.import_module(
+        f"chipbench.pipelines.{config['pipeline']}").Job(
+            config, traffic, jax.devices()[:1], 17, str(tmp_path / "store"))
+    families = job.expected_kernels
+    assert isinstance(families, tuple)
+    assert not any(f.endswith(("_fwd", "_bwd")) or "_bwd_" in f
+                   for f in families), families
+    today = {f"{f}_{k}" for f in families
+             for k in ("fwd", "bwd_dq", "bwd_dkv")}
+    assert trace_reduce.roles_missing(families, today) == 0
+    assert trace_reduce.roles_missing(families, set()) == 2 * len(families)
